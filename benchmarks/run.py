@@ -43,6 +43,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     t0 = time.time()
     from benchmarks import (
         accuracy,

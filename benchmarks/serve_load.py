@@ -54,18 +54,11 @@ PAPER_SIZES = (784, 128, 10)
 
 
 def random_folded(sizes, seed=0, cmax=40, bias_cells=64):
-    rng = np.random.default_rng(seed)
-    layers = []
-    for i in range(len(sizes) - 1):
-        n_in, n_out = sizes[i], sizes[i + 1]
-        c = bnn.parity_adjust_c(
-            rng.integers(-cmax, cmax + 1, n_out), n_in, bias_cells
-        )
-        layers.append(bnn.FoldedLayer(
-            weights_pm1=rng.choice([-1, 1], (n_out, n_in)).astype(np.int8),
-            c=c,
-        ))
-    return layers
+    """A random deployed net with fold-style parity-adjusted C_j."""
+    return bnn.random_folded(
+        bnn.MLPConfig(layer_sizes=tuple(sizes), bias_cells=bias_cells),
+        seed, cmax,
+    )
 
 
 def measure_raw(pipe, batch: int, duration_s: float, seed=1) -> dict:

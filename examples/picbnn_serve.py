@@ -20,10 +20,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 from repro.models import model as M
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(0)
     cfg_votes = configs.get_config("musicgen-medium+smoke+cam-head")
     cfg_exact = configs.get_config("musicgen-medium+smoke+cam-head-exact")

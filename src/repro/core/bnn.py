@@ -166,6 +166,27 @@ def parity_adjust_c(c: np.ndarray, n_in: int, bias_cells: int) -> np.ndarray:
     return np.where((c + n_in) % 2 == 0, c - np.sign(c).astype(c.dtype), c)
 
 
+def random_folded(cfg: MLPConfig, seed: int = 0,
+                  cmax: int = 40) -> list[FoldedLayer]:
+    """An untrained deployed MLP: random ±1 weights, fold-style C_j.
+
+    The MLP twin of `convnet.random_folded_cnn`, for bit-exactness
+    checks and benchmarks that need shapes and semantics, not accuracy.
+    """
+    rng = np.random.default_rng(seed)
+    layers = []
+    sizes = cfg.layer_sizes
+    for n_in, n_out in zip(sizes[:-1], sizes[1:]):
+        c = parity_adjust_c(
+            rng.integers(-cmax, cmax + 1, n_out), n_in, cfg.bias_cells
+        )
+        layers.append(FoldedLayer(
+            weights_pm1=rng.choice([-1, 1], (n_out, n_in)).astype(np.int8),
+            c=c,
+        ))
+    return layers
+
+
 def fold(params: Params, cfg: MLPConfig) -> list[FoldedLayer]:
     """Collapse trained BN into integer C_j per neuron (Eq. 3). Numpy-side."""
     folded = []

@@ -63,8 +63,7 @@ SCHEMA = "picbnn-deployment/v1"
 
 #: compile_pipeline options a Deployment may carry (everything except
 #: the model/physics inputs, which are first-class Deployment fields)
-COMPILE_OPTIONS = ("impl", "bq", "chunk", "min_bucket", "max_bucket",
-                   "interpret", "donate")
+COMPILE_OPTIONS = ("impl", "bq", "min_bucket", "max_bucket", "donate")
 
 
 def _np_unpack_bits(words: np.ndarray, n_bits: int) -> np.ndarray:
@@ -321,8 +320,10 @@ def deploy(
         `pipeline.compile_pipeline`; explicit arguments win over
         config-derived defaults.
     compile_options : forwarded to `compile_pipeline` at (lazy) compile
-        time — one of `deploy.COMPILE_OPTIONS` (impl, bq, chunk,
-        min_bucket, max_bucket, interpret, donate).
+        time — one of `deploy.COMPILE_OPTIONS` (impl, bq, min_bucket,
+        max_bucket, donate).  How a kernel runs (compiled on TPU, the
+        Pallas interpreter elsewhere) is the backend's choice, not an
+        option.
 
     >>> d = deploy(bnn.fold(params, cfg), config=cfg, noise=SILICON)
     >>> d.run(x, InferenceSpec(noise="per_request"), keys=keys)

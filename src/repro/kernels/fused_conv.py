@@ -6,31 +6,32 @@ workloads (the dominant related-work axis — XNORBIN, ChewBaccaNN).  ONE
 `pallas_call` per batch block executes
 
     per conv layer:   im2col folded into the packed layout — each of the
-                      k*k taps is a strided slice of the VMEM-resident
-                      channel-packed feature map, XNOR-popcount
-                      accumulated against the filter rows' tap words
-                      (no [B*OH*OW, k*k*C] patch matrix ever exists)
+                      k*k taps of an output row is one strided sublane
+                      load of the VMEM-resident channel-packed feature
+                      map, XNOR-popcount accumulated against the filter's
+                      tap word (no [B*OH*OW, k*k*C] patch matrix exists)
                       -> + C_o integer bias add -> sign
-                      -> in-register channel repack to uint32 words
-    flatten:          NHWC word concatenation (per-position alignment,
-                      DESIGN.md §10) + bias drive words when the head
-                      is direct
+                      -> shift-or channel repack into uint32-pattern words
+    flatten:          free — the FC stage reads the last feature tile in
+                      place, with its first weight rows permuted to match
     per FC layer:     the fused_mlp hidden-layer step (packed matvec +
                       C + sign + repack)
     head:             fused multi-threshold CAM vote (33 compares
                       against one Hamming distance)
 
 Only the channel-packed input feature map enters and only the int32
-vote counts leave; every intermediate — per-tap XOR temporaries,
-pre-sign integers, repacked feature maps — is VMEM/register resident.
+vote counts leave; every intermediate is VMEM/register resident.
 
 Layout conventions (DESIGN.md §10):
-  * feature maps are channel-packed NHWC: [B, H, W, Cw] uint32, channel
-    bits little-endian within each pixel's words, zero-padded to the
-    word boundary per pixel;
+  * the XLA twin and the host side see channel-packed NHWC maps
+    [B, H, W, Cw] uint32, channel bits little-endian within each pixel's
+    words, zero-padded to the word boundary per pixel;
+  * inside the kernel the batch is the lane axis: word w of pixel (y, x)
+    of batch lane b sits at [w * H + y, x, b] (int32, same bits), so a
+    tap is a strided load along x and every XOR operand of a channel is
+    one scalar weight word from SMEM;
   * filter rows are tap-major: [c_out, k*k*Cw] with word
-    (dy*k + dx)*Cw + w holding tap (dy, dx)'s channel word w — exactly
-    the order the strided-slice patch gather produces;
+    (dy*k + dx)*Cw + w holding tap (dy, dx)'s channel word w;
   * the flatten keeps the per-position word padding, so the first FC
     layer's rows must be packed with `pack_fc_rows_positionwise`
     (a plain `pack_bits` when c_out % 32 == 0 — the configs' choice).
@@ -40,7 +41,7 @@ Layout conventions (DESIGN.md §10):
 Correctness bar (tests/test_conv.py): bit-exact against the unpacked
 ±1 oracle `kernels.ref.conv_votes_ref` on multiple input sizes.
 
-Silicon mode: identical contract to fused_mlp — an optional [B, C, P]
+Silicon mode: identical contract to fused_mlp — an optional [P, B, C]
 float32 `thr_samples` operand (from `physics.SearchPhysics.sample`)
 replaces the shared thresholds in the head compare; the kernel itself
 stays deterministic.
@@ -56,15 +57,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import binarize
-from repro.kernels.binary_gemm import _pad_axis
-from repro.kernels.fused_mlp import (
-    _LayerMeta,
-    _hd_block,
-    _pad_words,
-    _repack,
-)
+from repro.kernels import fused_mlp
 
 WORD = 32
 
@@ -112,8 +108,8 @@ def pack_conv_rows(layer) -> jax.Array:
 
     Each filter's bits are packed per tap along the channel axis (same
     per-pixel word padding as the feature map), then taps concatenate
-    in (dy, dx) scan order — the order `_conv_layer_packed`'s strided
-    slices visit them.
+    in (dy, dx) scan order — the order both the kernel's tap loads and
+    `conv_hd_packed`'s strided slices visit them.
     """
     bits = (np.asarray(layer.weights_pm1) > 0).astype(np.uint8)
     c_out, k = layer.c_out, layer.k
@@ -156,10 +152,10 @@ def conv_hd_packed(x, w, m: ConvMeta):
     x: [B, S, S, Cw] uint32; w: [c_out, k*k*Cw] tap-major rows.
     Returns [B, O, O, c_out] int32.  The im2col never materializes: tap
     (dy, dx) is a strided slice of the feature map, XNOR-popcount-
-    accumulated against the filters' tap words.  Pure jnp on values —
-    shared by the Pallas kernel body, the XLA twin, and the unpacked
-    layer-by-layer benchmark baseline, so the tap geometry cannot
-    drift between them.
+    accumulated against the filters' tap words.  Plain jnp — the XLA
+    twin's conv step and the unpacked layer-by-layer benchmark baseline
+    share it; the Pallas kernel's own tap loop is held to it bit for bit
+    by tests/test_conv.py.
     """
     b = x.shape[0]
     hd = jnp.zeros((b, m.out_side, m.out_side, m.c_out), jnp.int32)
@@ -185,9 +181,8 @@ def conv_hd_packed(x, w, m: ConvMeta):
 def _conv_layer_packed(x, w, c, m: ConvMeta):
     """One packed-domain conv layer: [B, S, S, Cw] -> [B, O, O, Cw_out].
 
-    Pure jnp on values — the SAME function is the Pallas kernel body's
-    layer step (on VMEM-loaded blocks) and the XLA twin's (on arrays);
-    the two implementations cannot drift apart.
+    Plain jnp: the XLA twin's layer step and the oracle the kernel's
+    `_conv_layer` is tested against.
     """
     b = x.shape[0]
     hd = conv_hd_packed(x, w, m)
@@ -206,7 +201,7 @@ def _conv_layer_packed(x, w, c, m: ConvMeta):
 
 
 def conv_stage_packed(x, conv_ws, conv_cs, metas, bias_words=None):
-    """Run the conv stack + flatten in the packed domain (shared math).
+    """Run the conv stack + flatten in the packed domain (the XLA twin).
 
     x: [B, S, S, Cw0] uint32.  Returns the flattened packed query
     [B, n_pos * Cw_f (+ bias words)] feeding the FC stage; appends the
@@ -224,72 +219,97 @@ def conv_stage_packed(x, conv_ws, conv_cs, metas, bias_words=None):
     return q
 
 
-def _make_kernel(conv_metas, mlp_metas, head_kw: int, bias_cells: int,
-                 chunk: int, noisy: bool, has_bias_ref: bool):
-    """Fused conv+MLP+vote kernel body for a static layer stack.
+def feature_rows(m: ConvMeta) -> int:
+    """Sublanes per spatial row of a conv output tile (side padded to 8)."""
+    return -(-m.out_side // fused_mlp.SUB) * fused_mlp.SUB
 
-    Ref order: x, (conv_w, conv_c)*, (fc_w, fc_c)*, [bias_words,]
-    head, thr, out — the bias-drive words operand is present only on
-    the head-direct path.  The FC/head tail is the fused_mlp step (same
-    helpers); `noisy` swaps the shared [P] thresholds for a [bq, C, P]
-    sample block.
+
+def _conv_layer(in_ref, w_ref, c_ref, out_ref, m: ConvMeta):
+    """One packed conv layer on batch-on-lanes feature tiles.
+
+    in_ref : [cw_in * side, >= side, L] int32 — word w of pixel (y, x) of
+             lane b at [w * side + y, x, b]
+    out_ref: [cw_out * O, feature_rows(m), L], same layout; the padded
+             columns x >= O are zeroed (the FC stage reads whole tiles)
+    w_ref  : SMEM int32 `pack_conv_rows` words, [c_out, k*k*cw_in] flat
+    Tap (dy, dx) of output row oy is one strided sublane load; each
+    channel's XOR operand is a scalar weight word.
     """
+    lanes = in_ref.shape[-1]
+    o = m.out_side
+    n_taps = m.k * m.k * m.cw_in
+    for wo in range(m.cw_out):
+        def row(oy, carry, wo=wo):
+            taps = [
+                in_ref[w * m.side + oy * m.stride + dy,
+                       pl.ds(dx, o, stride=m.stride), :]
+                for dy in range(m.k) for dx in range(m.k)
+                for w in range(m.cw_in)
+            ]  # tap order == pack_conv_rows word order
+
+            def channel(i, acc):
+                ch = wo * WORD + i
+                hd = jnp.zeros((o, lanes), jnp.int32)
+                for t, tap in enumerate(taps):
+                    hd = hd + jax.lax.population_count(
+                        tap ^ w_ref[ch * n_taps + t]
+                    )
+                y = m.n_bits - 2 * hd + c_ref[ch]  # Eq. (3) pre-sign
+                return acc | jnp.left_shift((y >= 0).astype(jnp.int32), i)
+
+            out_ref[wo * o + oy, pl.ds(0, o), :] = jax.lax.fori_loop(
+                0, min(WORD, m.c_out - wo * WORD), channel,
+                jnp.zeros((o, lanes), jnp.int32),
+            )
+            return carry
+
+        jax.lax.fori_loop(0, o, row, 0)
+    pad = out_ref.shape[1] - o
+    if pad:
+        out_ref[:, pl.ds(o, pad), :] = jnp.zeros(
+            (out_ref.shape[0], pad, lanes), jnp.int32
+        )
+
+
+def flatten_rows(rows_packed: jax.Array, m: ConvMeta) -> jax.Array:
+    """Position-wise packed FC rows -> SMEM-flat rows in the kernel's order.
+
+    `pack_fc_rows_positionwise` orders words (position, channel word);
+    the kernel's last conv tile holds word w of position (oy, ox) at
+    [w * O + oy, ox].  Returns [N * cw * O * feature_rows(m)] int32 with
+    zero words at the padded columns.
+    """
+    n, o, cw = rows_packed.shape[0], m.out_side, m.cw_out
+    w = fused_mlp.to_int32(rows_packed).reshape(n, o, o, cw)
+    w = jnp.pad(w.transpose(0, 3, 1, 2),
+                ((0, 0), (0, 0), (0, 0), (0, feature_rows(m) - o)))
+    return w.reshape(-1)
+
+
+def _make_kernel(conv_metas, fc_metas, n_classes: int, noisy: bool):
+    """Fused conv+FC+vote kernel body for a static layer stack.
+
+    Ref order: x, (w, c) per conv layer, (w, c) per FC hidden layer,
+    head_w, head_offset, thr, out, then scratch: one feature tile per
+    conv layer, one word tile per FC hidden layer, the head distances.
+    The FC/head tail is the fused_mlp step (same helpers).
+    """
+    n_conv, n_fc = len(conv_metas), len(fc_metas)
 
     def kernel(*refs):
-        x_ref = refs[0]
-        out_ref = refs[-1]
-        thr_ref = refs[-2]
-        head_ref = refs[-3]
-
-        x = x_ref[...]  # [bq, S, S, Cw0] channel-packed input
-        bq = x.shape[0]
-        conv_w = [refs[1 + 2 * i][...] for i in range(len(conv_metas))]
-        conv_c = [refs[2 + 2 * i][...] for i in range(len(conv_metas))]
-        idx = 1 + 2 * len(conv_metas)
-        # conv stack + flatten (+ bias drive words on the head-direct
-        # path): the SAME shared lowering the XLA twin executes
-        q = conv_stage_packed(
-            x, conv_w, conv_c, conv_metas,
-            refs[-4][...] if has_bias_ref else None,
-        )
-        target_kw = mlp_metas[0].kw if mlp_metas else head_kw
-        if q.shape[1] < target_kw:
-            q = jnp.concatenate(
-                [q, jnp.zeros((bq, target_kw - q.shape[1]), jnp.uint32)],
-                axis=-1,
-            )
-        for i, m in enumerate(mlp_metas):
-            w = refs[idx][...]
-            c = refs[idx + 1][...]
-            idx += 2
-            hd = _hd_block(q, w, chunk)
-            y = (m.n_bits - 2 * hd) + c[None, :]
-            bits = (y >= 0).astype(jnp.uint32)
-            if i + 1 < len(mlp_metas):
-                tail_kw, tail_bias = mlp_metas[i + 1].kw, 0
-            else:
-                tail_kw, tail_bias = head_kw, bias_cells
-            parts = [bits]
-            if tail_bias:
-                parts.append(jnp.ones((bq, tail_bias), jnp.uint32))
-            pad = tail_kw * WORD - m.n_out - tail_bias
-            if pad:
-                parts.append(jnp.zeros((bq, pad), jnp.uint32))
-            q = _repack(
-                jnp.concatenate(parts, axis=-1) if len(parts) > 1 else bits,
-                tail_kw,
-            )
-        head = head_ref[...]
-        hd = _hd_block(q, head, chunk)
-        if noisy:
-            thr = thr_ref[...]  # [bq, C, P] sampled thresholds
-            votes = (hd[:, :, None].astype(jnp.float32) <= thr).astype(
-                jnp.int32
-            )
-        else:
-            thr = thr_ref[...]  # [P] shared tolerances
-            votes = (hd[:, :, None] <= thr[None, None, :]).astype(jnp.int32)
-        out_ref[...] = votes.sum(-1)
+        q_ref = refs[0]
+        idx = 1
+        for m in conv_metas:
+            nxt = refs[5 + 2 * (n_conv + n_fc) + idx // 2]
+            _conv_layer(q_ref, refs[idx], refs[idx + 1], nxt, m)
+            q_ref, idx = nxt, idx + 2
+        for m in fc_metas:
+            nxt = refs[5 + 2 * (n_conv + n_fc) + idx // 2]
+            fused_mlp.fc_layer(q_ref, refs[idx], refs[idx + 1], nxt, m)
+            q_ref, idx = nxt, idx + 2
+        head_w, head_off, thr_ref, out_ref = refs[idx: idx + 4]
+        fused_mlp.head_votes(q_ref, head_w, head_off, thr_ref, refs[-1],
+                             out_ref, n_classes, noisy)
 
     return kernel
 
@@ -297,7 +317,7 @@ def _make_kernel(conv_metas, mlp_metas, head_kw: int, bias_cells: int,
 @functools.partial(
     jax.jit,
     static_argnames=("conv_metas", "layer_n_bits", "bias_cells", "bq",
-                     "chunk", "interpret", "head_direct"),
+                     "interpret", "head_direct"),
 )
 def fused_conv_votes(
     x_packed: jax.Array,
@@ -311,8 +331,7 @@ def fused_conv_votes(
     thresholds: jax.Array,
     *,
     bias_cells: int,
-    bq: int = 64,
-    chunk: int = 4,
+    bq: int | None = None,
     interpret: bool = False,
     head_direct: bool = False,
     thr_samples: jax.Array | None = None,
@@ -327,17 +346,16 @@ def fused_conv_votes(
     conv_metas  : static `conv_metas_for` chain (shapes/strides)
     layer_ws    : FC-stage packed rows; the FIRST must be
                   `pack_fc_rows_positionwise` (flatten alignment)
-    layer_cs / layer_n_bits / head_rows / thresholds / bias_cells /
-    thr_samples : exactly as in `fused_mlp.fused_mlp_votes`
+    layer_cs / layer_n_bits / head_rows / thresholds / bias_cells / bq /
+    interpret / thr_samples : exactly as in `fused_mlp.fused_mlp_votes`
     head_direct : True when there are no FC hidden layers — the flatten
                   (word-aligned: last conv c_out % 32 == 0) feeds the
-                  head straight, with bias drive words appended in the
-                  packed domain
+                  head straight; its bias searchlines become a per-class
+                  constant distance
     returns     : [B, C] int32 vote counts (== ref.conv_votes_ref)
 
-    bq defaults lower than fused_mlp's (64 vs 256): the per-tap XOR
-    temporary is [bq, O, O, c_out, Cw] — the VMEM budget is derived in
-    DESIGN.md §10.
+    The input block is the whole image per batch lane: VMEM holds
+    2 x Cw0*S*ceil8(S)*bq words (double-buffered) — DESIGN.md §10.
     """
     if len(conv_ws) != len(conv_cs) or len(conv_ws) != len(conv_metas):
         raise ValueError("conv operand/meta length mismatch")
@@ -345,84 +363,79 @@ def fused_conv_votes(
         raise ValueError("fc operand length mismatch")
     if not conv_metas:
         raise ValueError("no conv layers — use fused_mlp.fused_mlp_votes")
-    m0 = conv_metas[0]
+    m0, mf = conv_metas[0], conv_metas[-1]
     if x_packed.shape[1:] != (m0.side, m0.side, m0.cw_in):
         raise ValueError(
             f"x_packed shape {x_packed.shape} does not match the first "
             f"conv layer's [B, {m0.side}, {m0.side}, {m0.cw_in}]"
         )
-    bias_words = None
     if head_direct:
         if layer_ws:
             raise ValueError("head_direct=True with FC hidden layers")
-        if conv_metas[-1].c_out % WORD:
+        if mf.c_out % WORD:
             raise ValueError(
                 "conv -> head-direct needs a word-aligned flatten: last "
-                f"conv c_out {conv_metas[-1].c_out} % 32 != 0"
+                f"conv c_out {mf.c_out} % 32 != 0"
             )
-        bias_words = bias_drive_words(bias_cells)
     elif not layer_ws:
         raise ValueError("no FC layers and head_direct=False")
 
-    x, b0 = _pad_axis(x_packed, 0, bq)
-    head = _pad_words(head_rows, chunk)
-    n_classes = head.shape[0]
-    if jnp.issubdtype(thresholds.dtype, jnp.floating):
-        thr = thresholds.astype(jnp.float32)
-    else:
-        thr = thresholds.astype(jnp.int32)
-
+    bq = fused_mlp.block_rows(bq, interpret)
+    b, side = x_packed.shape[0], m0.side
+    bp = fused_mlp.pad_batch(b, bq)
+    x = jnp.pad(fused_mlp.to_int32(x_packed),
+                ((0, bp - b), (0, 0), (0, 0), (0, 0)))
+    x = x.transpose(3, 1, 2, 0).reshape(m0.cw_in * side, side, bp)
     operands = [x]
-    specs = [pl.BlockSpec((bq,) + x.shape[1:],
-                          lambda i: (i, 0, 0, 0))]
+    specs = [pl.BlockSpec((x.shape[0], side, bq), lambda i: (0, 0, i))]
+    scratch = []
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    for w, c, m in zip(conv_ws, conv_cs, conv_metas):
+        operands += [fused_mlp.to_int32(w).reshape(-1),
+                     jnp.asarray(c, jnp.int32)]
+        specs += [smem, smem]
+        scratch.append(pltpu.VMEM(
+            (m.cw_out * m.out_side, feature_rows(m), bq), jnp.int32
+        ))
 
-    def _whole(shape):
-        zeros = (0,) * len(shape)
-        return pl.BlockSpec(shape, lambda i, z=zeros: z)
-
-    for w, c in zip(conv_ws, conv_cs):
-        operands += [w, c.astype(jnp.int32)]
-        specs += [_whole(w.shape), _whole(c.shape)]
-    mlp_metas = []
-    for w, c, n_bits in zip(layer_ws, layer_cs, layer_n_bits):
-        w = _pad_words(w, chunk)
-        mlp_metas.append(
-            _LayerMeta(n_bits=n_bits, n_out=w.shape[0], kw=w.shape[1])
-        )
-        operands += [w, c.astype(jnp.int32)]
-        specs += [_whole(w.shape), _whole(c.shape)]
-    if bias_words is not None:
-        bw = jnp.asarray(bias_words, jnp.uint32)
-        operands.append(bw)
-        specs.append(_whole(bw.shape))
-    noisy = thr_samples is not None
-    if noisy:
-        if thr_samples.shape[1:] != (n_classes, thr.shape[0]):
-            raise ValueError(
-                f"thr_samples shape {thr_samples.shape} != "
-                f"[B, {n_classes}, {thr.shape[0]}]"
-            )
-        ts, _ = _pad_axis(thr_samples.astype(jnp.float32), 0, bq)
-        operands += [head, ts]
-        specs += [
-            _whole(head.shape),
-            pl.BlockSpec((bq, n_classes, ts.shape[-1]),
-                         lambda i: (i, 0, 0)),
-        ]
-    else:
-        operands += [head, thr]
-        specs += [_whole(head.shape), _whole(thr.shape)]
-
-    kernel = _make_kernel(
-        tuple(conv_metas), tuple(mlp_metas), head.shape[1], bias_cells,
-        chunk, noisy, bias_words is not None,
+    n_feat = mf.out_side * mf.out_side * mf.c_out
+    fc_metas = []
+    for i, (w, c, n_bits) in enumerate(zip(layer_ws, layer_cs,
+                                           layer_n_bits)):
+        m = fused_mlp._LayerMeta(n_bits=int(n_bits), n_out=int(w.shape[0]))
+        fc_metas.append(m)
+        operands += [flatten_rows(w, mf) if i == 0
+                     else fused_mlp.unit_words(w),
+                     jnp.asarray(c, jnp.int32)]
+        specs += [smem, smem]
+        scratch.append(pltpu.VMEM(
+            (fused_mlp.word_rows(binarize.packed_width(m.n_out)),
+             fused_mlp.SUB, bq),
+            jnp.int32,
+        ))
+        n_feat = m.n_out
+    rows, offset = fused_mlp.split_head(head_rows, n_feat, bias_cells)
+    head_w = (flatten_rows(rows, mf) if head_direct
+              else fused_mlp.unit_words(rows))
+    head_ops, head_specs, n_groups = fused_mlp.head_operands(
+        head_w, offset, thresholds, thr_samples, bp, bq
     )
+    scratch.append(pltpu.VMEM((n_groups, fused_mlp.SUB, bq), jnp.int32))
+
     out = pl.pallas_call(
-        kernel,
-        grid=(x.shape[0] // bq,),
-        in_specs=specs,
-        out_specs=pl.BlockSpec((bq, n_classes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((x.shape[0], n_classes), jnp.int32),
+        _make_kernel(tuple(conv_metas), tuple(fc_metas),
+                     head_rows.shape[0], thr_samples is not None),
+        grid=(bp // bq,),
+        in_specs=specs + head_specs,
+        out_specs=pl.BlockSpec((n_groups, fused_mlp.SUB, bq),
+                               lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_groups, fused_mlp.SUB, bp),
+                                       jnp.int32),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=interpret,
-    )(*operands)
-    return out[:b0]
+        name="fused_conv",
+    )(*operands, *head_ops)
+    return fused_mlp.votes_from_tiles(out, b, head_rows.shape[0])

@@ -50,10 +50,12 @@ oracle (tested).
 Two fused implementations, selected by `impl` (default: by backend):
 
   pallas — kernels/fused_mlp.py: one kernel launch per batch block,
-           hidden activations resident in VMEM (the TPU deployment path;
-           runs under interpret mode elsewhere, for semantics only).  The
-           noisy path feeds the kernel a precomputed [B, C, P]
-           threshold-sample operand — randomness never enters the kernel.
+           hidden activations resident in VMEM (the TPU deployment path).
+           The backend alone decides how it runs: compiled by Mosaic on
+           TPU, through the Pallas interpreter elsewhere (semantics
+           tests only).  The noisy path feeds the kernel a precomputed
+           [P, B, C] threshold-sample operand — randomness never enters
+           the kernel.
   xla    — the same packed-domain math as a single jitted XLA program:
            activations stay uint32-packed between layers and the whole
            net fuses into one executable (the portable fast path — on
@@ -160,10 +162,6 @@ def _head_hd_xla(x_packed, layer_ws, layer_cs, layer_n_bits, head_rows,
             ones = jnp.ones((bits.shape[0], bias_cells), jnp.uint8)
             bits = jnp.concatenate([bits, ones], axis=-1)
         q = binarize.pack_bits(bits)
-        # align packed width with the next operand's (zero pad words)
-        kw_next = (head_rows if i + 1 == n_layers else layer_ws[i + 1]).shape[1]
-        if q.shape[1] < kw_next:
-            q = jnp.pad(q, ((0, 0), (0, kw_next - q.shape[1])))
     return binarize.hamming_packed(q[:, None, :], head_rows)
 
 
@@ -580,10 +578,8 @@ def compile_pipeline(
     *,
     impl: str | None = None,
     bq: int | None = None,
-    chunk: int = 4,
     min_bucket: int = 64,
     max_bucket: int | None = None,
-    interpret: bool | None = None,
     noise: NoiseModel | None = None,
     params=None,
     donate: bool = False,
@@ -599,13 +595,14 @@ def compile_pipeline(
               RAW [0,1] pixels [B, image_side**2] (the binary input
               encoding runs inside the jitted pack step).
     ens_cfg : Algorithm-1 config (thresholds / bias cells); default paper's.
-    impl    : "pallas" | "xla" | None (auto: pallas on TPU, xla elsewhere —
-              the Pallas kernel only *executes* off-TPU in interpret mode,
-              which is for semantics tests, not speed).
-    bq      : Pallas batch-block size; default 256 for MLP graphs, 64
-              for conv graphs (the conv kernel's per-tap XOR temporary
-              scales the VMEM working set ~4x — DESIGN.md §10 derives
-              both budgets).
+    impl    : "pallas" | "xla" | None (auto: pallas on TPU, xla elsewhere).
+              The backend alone decides how the kernel runs: compiled by
+              Mosaic on TPU, through the Pallas interpreter elsewhere
+              (semantics tests, not speed).
+    bq      : Pallas batch rows per kernel block; default 128, one vreg
+              of lanes (the batch is the kernels' lane axis — DESIGN.md
+              §4 and §10 derive the VMEM budgets).  Compiled blocks need
+              a multiple of 128; interpret mode takes any size.
     noise   : optional NoiseModel — enables the silicon-mode specs
               (noise="batch"/"per_request", Monte-Carlo, noisy
               cumulative) by building a SearchPhysics bundle from the
@@ -641,8 +638,7 @@ def compile_pipeline(
         impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown pipeline impl {impl!r}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = jax.default_backend() != "tpu"
 
     rest = list(folded)
     conv_layers: list[FoldedConvLayer] = []
@@ -657,10 +653,6 @@ def compile_pipeline(
     if not conv_layers and (image_side is not None
                             or image_encoding is not None):
         raise ValueError("image_side/image_encoding are conv-only options")
-    if bq is None:
-        # the conv kernel's [bq, O, O, c_out, Cw] per-tap temporary is
-        # ~4x the MLP kernel's working set per batch row (DESIGN.md §10)
-        bq = 64 if conv_layers else 256
 
     hidden, out_layer = list(rest[:-1]), rest[-1]
     head = build_head(out_layer, ens_cfg)
@@ -737,11 +729,6 @@ def compile_pipeline(
     # buffer worth donating (weights live in the closures)
     donate_kw = {"donate_argnums": (0,)} if donate else {}
 
-    # chunk-padded operands for the XLA-twin math (also backs the
-    # Monte-Carlo / cumulative / per-request paths of a pallas pipeline)
-    ws = tuple(fused_mlp._pad_words(w, chunk) for w in layer_ws)
-    hr = fused_mlp._pad_words(head_rows, chunk)
-
     if conv_layers:
         bias_words = (fused_conv.bias_drive_words(head.bias_cells)
                       if head_direct else None)
@@ -757,12 +744,9 @@ def compile_pipeline(
             return x_packed
 
     def _hd_xla(x_packed):
-        q = _front(x_packed)
-        kw0 = (ws[0] if ws else hr).shape[1]
-        if q.shape[1] < kw0:
-            q = jnp.pad(q, ((0, 0), (0, kw0 - q.shape[1])))
         return _head_hd_xla(
-            q, ws, layer_cs, layer_n_bits, hr, head.bias_cells
+            _front(x_packed), layer_ws, layer_cs, layer_n_bits, head_rows,
+            head.bias_cells,
         )
 
     # the two kernel-eligible vote producers (single [B, C] result block)
@@ -772,7 +756,7 @@ def compile_pipeline(
                 x_packed.reshape(-1, image_side, image_side, cw0),
                 conv_ws, conv_cs, conv_metas,
                 layer_ws, layer_cs, layer_n_bits, head_rows, thresholds,
-                bias_cells=head.bias_cells, bq=bq, chunk=chunk,
+                bias_cells=head.bias_cells, bq=bq,
                 interpret=interpret, head_direct=head_direct,
                 thr_samples=thr_samples,
             )
@@ -781,7 +765,7 @@ def compile_pipeline(
             return fused_mlp.fused_mlp_votes(
                 x_packed, layer_ws, layer_cs, layer_n_bits,
                 head_rows, thresholds,
-                bias_cells=head.bias_cells, bq=bq, chunk=chunk,
+                bias_cells=head.bias_cells, bq=bq,
                 interpret=interpret, thr_samples=thr_samples,
             )
     else:
@@ -802,9 +786,7 @@ def compile_pipeline(
             t = phys.sample(
                 key, batch_shape=(x_packed.shape[0],), n_rows=n_classes
             )  # [P, B, C]
-            return _kernel_votes(
-                x_packed, thr_samples=jnp.moveaxis(t, 0, -1)  # [B, C, P]
-            )
+            return _kernel_votes(x_packed, thr_samples=t)
         hd = _hd_xla(x_packed).astype(jnp.float32)  # [B, C]
         t = phys.sample(key, batch_shape=(hd.shape[0],), n_rows=n_classes)
         return (hd[None] <= t).astype(jnp.int32).sum(0)

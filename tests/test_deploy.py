@@ -115,6 +115,16 @@ def test_deploy_rejects_unknown_options_and_dict_without_config():
     assert "impl" in COMPILE_OPTIONS
 
 
+@pytest.mark.parametrize("option", ["interpret", "chunk"])
+def test_deploy_rejects_backend_decided_options(option):
+    """How a kernel runs is the backend's choice: a manifest cannot carry
+    interpret mode (or the old word-chunk tile) onto a TPU."""
+    folded = _random_folded((64, 4), seed=1, bias_cells=32)
+    assert option not in COMPILE_OPTIONS
+    with pytest.raises(ValueError, match="unknown compile options"):
+        deploy(folded, **{option: True})
+
+
 # ---------------------------------------------------------------------------
 # save / load round trips (the acceptance bar)
 # ---------------------------------------------------------------------------
