@@ -42,11 +42,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ensemble
+from repro.core import bnn, ensemble
 from repro.core.device_model import SILICON, NoiseModel
 from repro.deploy import deploy
 from repro.spec import VOTES, InferenceSpec
-from benchmarks.e2e_throughput import PAPER_SIZES, random_folded
+
+PAPER_SIZES = (784, 128, 10)
+
+
+def random_folded(sizes, seed=0, cmax=40, bias_cells=64):
+    """A random deployed net with fold-style parity-adjusted C_j."""
+    return bnn.random_folded(
+        bnn.MLPConfig(layer_sizes=tuple(sizes), bias_cells=bias_cells),
+        seed, cmax,
+    )
 
 
 def _mc_spec(n_mc: int) -> InferenceSpec:

@@ -1,21 +1,17 @@
-"""Benchmark runner: one section per paper table/figure + framework
-benchmarks.  ``python -m benchmarks.run [--fast] [--json out.json]``
-prints CSV rows and optionally writes the same results machine-readable.
+"""Runner for the paper's figure and table scripts.
+``python -m benchmarks.run [--fast] [--json out.json]`` prints CSV rows
+and optionally writes the same results machine-readable.  Speed on the
+chip is measured by the on-chip benchmark (`bench/`, `BENCHMARK.json`),
+not here.
 
 Sections:
   fig5     — accuracy vs output-layer executions (paper Fig. 5)
   table2   — silicon throughput/power model (paper Table II)
-  kern     — Pallas kernel microbench + TPU memory-roofline derivations
   roofline — the 40-cell dry-run roofline table (§Roofline source)
-  e2e      — fused-pipeline vs layer-by-layer end-to-end throughput
-  conv     — end-to-end binary CNN: fused conv pipeline vs unpacked
-             layer-by-layer + accuracy-vs-passes on 28x28/64x64
   noise    — silicon-noise robustness curves + fused-MC vs faithful speedup
-  serve    — classification serving engine under closed/open-loop load
 
 JSON schema (picbnn-bench/v1): {"schema", "meta": {...}, "sections":
-{name: [row, ...]}} where each row is the section's CSV tuple as a list
-(the e2e section emits measurement dicts instead of CSV tuples).
+{name: [row, ...]}} where each row is the section's CSV tuple as a list.
 """
 
 from __future__ import annotations
@@ -37,7 +33,7 @@ def main(argv=None):
                     help="reduced sizes (CI-friendly)")
     ap.add_argument("--only", default="",
                     help="comma-separated subset: "
-                         "fig5,table2,kern,roofline,e2e,conv,noise,serve")
+                         "fig5,table2,roofline,noise")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write results as JSON (sections -> rows)")
     args = ap.parse_args(argv)
@@ -47,46 +43,19 @@ def main(argv=None):
 
     use_compile_cache()
     t0 = time.time()
-    from benchmarks import (
-        accuracy,
-        conv_throughput,
-        e2e_throughput,
-        kernels_bench,
-        noise_robustness,
-        roofline_table,
-        serve_load,
-        table2,
-    )
+    from benchmarks import accuracy, noise_robustness, roofline_table, table2
 
     sections: dict[str, list] = {}
     if only is None or "table2" in only:
         sections["table2"] = _rows_jsonable(table2.main())
-    if only is None or "kern" in only:
-        sections["kern"] = _rows_jsonable(kernels_bench.main(fast=args.fast))
     if only is None or "roofline" in only:
         sections["roofline"] = _rows_jsonable(roofline_table.main())
-    if only is None or "e2e" in only:
-        # rows only — the committed BENCH_e2e.json trajectory file is
-        # written solely by `python -m benchmarks.e2e_throughput`
-        sections["e2e"] = _rows_jsonable(
-            e2e_throughput.main(fast=args.fast, write_json=False)
-        )
-    if only is None or "conv" in only:
-        # dict rows — the committed BENCH_conv.json trajectory file is
-        # written solely by `python -m benchmarks.conv_throughput`
-        sections["conv"] = conv_throughput.main(fast=args.fast,
-                                                write_json=False)
     if only is None or "noise" in only:
         # rows only — the committed BENCH_noise.json trajectory file is
         # written solely by `python -m benchmarks.noise_robustness`
         sections["noise"] = _rows_jsonable(
             noise_robustness.main(fast=args.fast, write_json=False)
         )
-    if only is None or "serve" in only:
-        # dict rows — the committed BENCH_serve.json trajectory file is
-        # written solely by `python -m benchmarks.serve_load`
-        sections["serve"] = serve_load.main(fast=args.fast,
-                                            write_json=False)
     if only is None or "fig5" in only:
         sections["fig5"] = _rows_jsonable(accuracy.main(fast=args.fast))
     elapsed = time.time() - t0

@@ -40,6 +40,7 @@ API_MODULES = [
     "repro.deploy",
     "repro.serve.picbnn",
     "repro.serve.scheduler",
+    "repro.obs",
     "repro.core.physics",
     "repro.core.binarize",
     "repro.core.bnn",

@@ -13,6 +13,6 @@ mkdir -p "$(dirname "$OUT")"
 python -m pytest -q
 python scripts/check_docs.py
 python scripts/check_deprecated.py
-python -m benchmarks.run --fast --only kern,table2,conv,noise,serve --json "$OUT"
+python -m benchmarks.run --fast --only table2,noise --json "$OUT"
 
 echo "smoke OK -> $OUT"

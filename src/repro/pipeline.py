@@ -59,9 +59,9 @@ Two fused implementations, selected by `impl` (default: by backend):
   xla    — the same packed-domain math as a single jitted XLA program:
            activations stay uint32-packed between layers and the whole
            net fuses into one executable (the portable fast path — on
-           CPU this is what beats the layer-by-layer unpacked flow; see
-           benchmarks/e2e_throughput.py).  The noisy path broadcasts the
-           sampled [P, B, C] thresholds against the one HD computation.
+           CPU this is what beats the layer-by-layer unpacked flow).  The
+           noisy path broadcasts the sampled [P, B, C] thresholds against
+           the one HD computation.
 
 Monte-Carlo, cumulative, and per-request specs always use the XLA-twin
 math (per-pass/per-sample outputs do not fit the kernel's single [B, C]
@@ -86,20 +86,30 @@ sizes compiles O(log B) program variants instead of one per size.
 Persistable deployments (`repro.deploy.Deployment`) bundle the folded
 layers + encoding + configs this function takes, and rebuild the same
 pipeline from disk — see deploy.py.
+
+Observability (`repro.obs`): each `run` is a `picbnn.run` profiler span
+with the pipeline's call number (`call=`), enclosing `picbnn.stage`
+(host->device copy, counted in `stage.*`), `picbnn.pack`, `picbnn.pad`
+(padded batches only) and `picbnn.vote`, each the dispatch of one
+program.  The programs carry fixed names: `picbnn_pack`, and one
+`InferenceSpec.program_name` per spec (`picbnn_votes_off`, ...), so a
+device trace names them `jit_picbnn_*`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import time
 import warnings
-from typing import Callable, Optional, Sequence
+from typing import Callable, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import binarize
 from repro.core.bnn import FoldedLayer
 from repro.core.convnet import FoldedConvLayer
@@ -140,6 +150,15 @@ def bucket_grid(max_batch: int, min_bucket: int = 64) -> tuple[int, ...]:
     while out[-1] < max_batch:
         out.append(out[-1] * 2)
     return tuple(out)
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """`fn` under the name `jax.jit` gives its program (`jit_<name>`)."""
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 def _head_hd_xla(x_packed, layer_ws, layer_cs, layer_n_bits, head_rows,
@@ -205,6 +224,8 @@ class CompiledPipeline:
     _pack_fn: Callable  # jitted ±1 [B, n_in] -> packed
     max_bucket: Optional[int] = None  # serving cap on the bucket grid
     _programs: dict = dataclasses.field(default_factory=dict, repr=False)
+    _calls: Iterator[int] = dataclasses.field(
+        default_factory=itertools.count, repr=False)  # span `call=` ids
 
     # ------------------------------------------------------------------
     # the generic compiled-request API
@@ -245,20 +266,30 @@ class CompiledPipeline:
         Returns int32 votes/predictions shaped per the spec (see
         repro/spec.py's shape table), trimmed to the logical batch.
         """
-        return self.run_packed(self._pack_input(x), spec, key=key, keys=keys)
+        call = next(self._calls)
+        with obs.span("picbnn.run", call=call):
+            return self.run_packed(self._pack_input(x, call), spec, key=key,
+                                   keys=keys, call=call)
 
     def run_packed(self, x_packed: jax.Array, spec: InferenceSpec, *,
                    key: Optional[jax.Array] = None,
-                   keys: Optional[jax.Array] = None) -> jax.Array:
+                   keys: Optional[jax.Array] = None,
+                   call: Optional[int] = None) -> jax.Array:
         """`run` for an already-packed input batch [B, Kw0].
 
         Conv pipelines: Kw0 = side*side*Cw0, the row-flattened channel-
         packed encoded image the jitted pack step emits (`_pack_input`).
         This is the ONE place bucket padding, key-shape validation, and
-        result trimming happen, for every spec.
+        result trimming happen, for every spec.  `call` is the span id
+        of the `run` this continues; None opens a `picbnn.run` of its own.
         """
+        if call is None:
+            call = next(self._calls)
+            with obs.span("picbnn.run", call=call):
+                return self.run_packed(x_packed, spec, key=key, keys=keys,
+                                       call=call)
         prog = self.program(spec)  # physics capability check happens here
-        x_packed, b = self._bucketed(x_packed)
+        x_packed, b = self._bucketed(x_packed, call)
         if spec.needs_keys:
             if key is not None:
                 raise ValueError(
@@ -270,7 +301,9 @@ class CompiledPipeline:
                     f"{spec.describe()} needs per-request keys= "
                     "([B, 2] raw uint32 PRNG keys)"
                 )
-            out = prog(x_packed, self._each_keys(keys, b, x_packed.shape[0]))
+            keys = self._each_keys(keys, b, x_packed.shape[0], call)
+            with obs.span("picbnn.vote", call=call):
+                out = prog(x_packed, keys)
         elif spec.needs_key:
             if keys is not None:
                 raise ValueError(
@@ -282,29 +315,34 @@ class CompiledPipeline:
                     f"{spec.describe()} needs an explicit key= (each call "
                     "is one silicon realization)"
                 )
-            out = prog(x_packed, key)
+            with obs.span("picbnn.vote", call=call):
+                out = prog(x_packed, key)
         else:
             if key is not None or keys is not None:
                 raise ValueError(
                     f'{spec.describe()} is deterministic (noise="off"): '
                     "it accepts neither key= nor keys="
                 )
-            out = prog(x_packed)
+            with obs.span("picbnn.vote", call=call):
+                out = prog(x_packed)
         return self._trim(out, b, spec.batch_axis)
 
     # ------------------------------------------------------------------
     # shared glue (bucketing / packing / trimming / key shapes)
     # ------------------------------------------------------------------
-    def _pack_input(self, x_pm1: jax.Array) -> jax.Array:
+    def _pack_input(self, x_pm1: jax.Array, call: int) -> jax.Array:
         # one jitted dispatch: the eager op-by-op pack costs ~5x the whole
         # fused vote program in host dispatch overhead (serving hot path)
-        return self._pack_fn(jnp.asarray(x_pm1))
+        x = obs.stage(x_pm1, call=call)
+        with obs.span("picbnn.pack", call=call):
+            return self._pack_fn(x)
 
-    def _bucketed(self, x_packed: jax.Array):
+    def _bucketed(self, x_packed: jax.Array, call: int):
         b = x_packed.shape[0]
         bp = next_bucket(b, self.min_bucket, self.max_bucket)
         if bp != b:
-            x_packed = jnp.pad(x_packed, ((0, bp - b), (0, 0)))
+            with obs.span("picbnn.pad", call=call):
+                x_packed = jnp.pad(x_packed, ((0, bp - b), (0, 0)))
         return x_packed, b
 
     @staticmethod
@@ -315,8 +353,8 @@ class CompiledPipeline:
             return out
         return out[:b] if axis == 0 else out[:, :b]
 
-    def _each_keys(self, keys, b: int, bp: int) -> jax.Array:
-        keys = jnp.asarray(keys)
+    def _each_keys(self, keys, b: int, bp: int, call: int) -> jax.Array:
+        keys = obs.stage(keys, rows=0, call=call)
         if keys.ndim != 2 or keys.shape[0] != b:
             raise ValueError(
                 f"keys must be [B, key_width] raw uint32 PRNG keys with "
@@ -324,7 +362,8 @@ class CompiledPipeline:
                 "jax.random.PRNGKey / jax.random.split outputs"
             )
         if bp != b:  # pad rows get (valid) zero keys; results are sliced
-            keys = jnp.pad(keys, ((0, bp - b), (0, 0)))
+            with obs.span("picbnn.pad", call=call):
+                keys = jnp.pad(keys, ((0, bp - b), (0, 0)))
         return keys
 
     def buckets_for(self, max_batch: int) -> tuple[int, ...]:
@@ -711,15 +750,14 @@ def compile_pipeline(
             words = binarize.pack_bits(enc.encode_bits(img))
             return words.reshape(words.shape[0], side * side * cw0)
 
-        pack_fn = jax.jit(_pack_conv)
+        pack = _pack_conv
     elif hidden:
-        pack_fn = jax.jit(binarize.pack_pm1)
+        pack = binarize.pack_pm1
     else:
         from repro.core.cam import query_with_bias
 
-        pack_fn = jax.jit(
-            functools.partial(query_with_bias, bias_cells=head.bias_cells)
-        )
+        pack = functools.partial(query_with_bias, bias_cells=head.bias_cells)
+    pack_fn = jax.jit(_named(pack, "picbnn_pack"))
 
     phys = None
     if noise is not None:
@@ -869,7 +907,7 @@ def compile_pipeline(
                 def fn(x_packed, rng):
                     return jnp.argmax(base(x_packed, rng), axis=-1)
 
-        return jax.jit(fn, **donate_kw)
+        return jax.jit(_named(fn, spec.program_name), **donate_kw)
 
     if conv_layers:
         n_in = int(image_side) ** 2  # raw [0,1] pixels in, encode inside

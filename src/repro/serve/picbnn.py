@@ -34,12 +34,21 @@ Architecture (DESIGN.md §9):
       caps a GIL-bound serving loop.
   dispatch thread: drains one lane batch (a list of lot spans),
       assembles it into a bucket-sized staging buffer with vectorized
-      copies, stages to the next device round-robin (`jax.device_put`)
+      copies, stages to the next device round-robin (`obs.stage`)
       and issues the jitted pipeline call.  jax dispatch is async, so
       while the device crunches batch N the dispatch thread is already
       assembling and staging batch N+1 (depth bounded by `max_inflight`).
   completion thread: blocks on device->host readback in dispatch order,
       publishes per-batch results, records metrics.
+
+Profiling a live server (`repro.obs`; spans show in a `jax.profiler`
+trace, counters in `obs.counters()`): each micro-batch is a `batch=` id
+on the dispatch thread's `serve.assemble`, `picbnn.stage` (the
+host->device copy, counted in `stage.bytes` / `stage.rows`),
+`serve.enqueue` (the pipeline's own `picbnn.*` spans inside) and
+`serve.backpressure` (the wait on `max_inflight`), and on the completion
+thread's `serve.readback`.  Collector passes show as `py.gc` spans and
+in `gc.pause_ns`.
 
 Batches dispatch into the pipeline's power-of-two bucket grid at exactly
 bucket-shaped operands, so a server warms O(log max_batch) program
@@ -69,6 +78,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import threading
 import time
 from pathlib import Path
@@ -77,6 +87,7 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import mapping
 from repro.deploy import Deployment
 from repro.pipeline import CompiledPipeline, next_bucket
@@ -144,10 +155,10 @@ class _Slab:
 
 class _Batch:
     __slots__ = ("model_id", "n", "bucket", "device", "t_dispatch", "t_done",
-                 "t_submits", "votes", "preds", "error", "event")
+                 "t_submits", "votes", "preds", "error", "event", "bid")
 
     def __init__(self, model_id: str, n: int, bucket: int, device: int,
-                 t_dispatch: float, t_submits: np.ndarray):
+                 t_dispatch: float, t_submits: np.ndarray, bid: int = -1):
         self.model_id = model_id
         self.n = n
         self.bucket = bucket
@@ -158,6 +169,7 @@ class _Batch:
         self.votes: Optional[np.ndarray] = None
         self.preds: Optional[np.ndarray] = None
         self.error: Optional[BaseException] = None
+        self.bid = bid  # the `batch=` id of its spans
         self.event = threading.Event()
 
 
@@ -389,6 +401,7 @@ class PicBnnServer:
         self._uid = 0
         self._uid_lock = threading.Lock()
         self._next_dev = 0
+        self._batch_ids = itertools.count()
         self._started = False
         self._closed = False
         self._dispatch_t: Optional[threading.Thread] = None
@@ -528,6 +541,7 @@ class PicBnnServer:
                     f"max_bucket {m.pipe.max_bucket}"
                 )
         self._started = True
+        obs.watch_gc()
         self._dispatch_t = threading.Thread(
             target=self._dispatch_loop, name="picbnn-dispatch", daemon=True
         )
@@ -676,6 +690,7 @@ class PicBnnServer:
 
     def _dispatch(self, m: _Model, spans) -> None:
         t_dispatch = time.perf_counter()
+        bid = next(self._batch_ids)
         n = sum(s.n for s in spans)
         pipe = m.pipe
         bucket = next_bucket(n, pipe.min_bucket, pipe.max_bucket)
@@ -685,19 +700,20 @@ class PicBnnServer:
         # staging array would re-specialize the program per distinct n —
         # a fresh compile mid-traffic); pad rows are zeros (valid
         # ±1-domain garbage, dropped at readback)
-        x = np.zeros((bucket, pipe.n_in), np.float32)
-        keys = np.zeros((bucket, 2), np.uint32) if m.silicon else None
-        t_subs = np.empty(n)
-        placed = []
-        pos = 0
-        for s in spans:
-            k, slab = s.n, s.lot
-            x[pos:pos + k] = slab.x[s.lo:s.hi]
-            if m.silicon:
-                keys[pos:pos + k] = slab.keys[s.lo:s.hi]
-            t_subs[pos:pos + k] = slab.t_submit
-            placed.append((slab, s.lo, pos, k))
-            pos += k
+        with obs.span("serve.assemble", batch=bid):
+            x = np.zeros((bucket, pipe.n_in), np.float32)
+            keys = np.zeros((bucket, 2), np.uint32) if m.silicon else None
+            t_subs = np.empty(n)
+            placed = []
+            pos = 0
+            for s in spans:
+                k, slab = s.n, s.lot
+                x[pos:pos + k] = slab.x[s.lo:s.hi]
+                if m.silicon:
+                    keys[pos:pos + k] = slab.keys[s.lo:s.hi]
+                t_subs[pos:pos + k] = slab.t_submit
+                placed.append((slab, s.lo, pos, k))
+                pos += k
         if self.fanout == "spmd":
             dev_idx = -1
             target = self._batch_sharding
@@ -705,21 +721,21 @@ class PicBnnServer:
             dev_idx = self._next_dev
             self._next_dev = (self._next_dev + 1) % len(self.devices)
             target = self.devices[dev_idx]
-        xd = jax.device_put(x, target)
-        if m.silicon:
-            kd = jax.device_put(keys, target)
+        # the logical rows are counted, not the bucket's padded ones
+        xd = obs.stage(x, target, rows=n, batch=bid)
+        kd = obs.stage(keys, target, rows=0, batch=bid) if m.silicon else None
+        with obs.span("serve.enqueue", batch=bid):
             votes = pipe.run(xd, m.spec, keys=kd)
-        else:
-            votes = pipe.run(xd, m.spec)
         # jax dispatch is async: `votes` is a device future; hand it to
         # the completion thread and go assemble/stage the next batch
-        batch = _Batch(m.model_id, n, bucket, dev_idx, t_dispatch, t_subs)
+        batch = _Batch(m.model_id, n, bucket, dev_idx, t_dispatch, t_subs,
+                       bid)
         for slab, lo, bpos, k in placed:
             slab.spans.append((batch, lo, bpos, k))
             slab.placed += k
         with self._dispatch_cv:
             self._dispatch_cv.notify_all()
-        with self._inflight_cond:
+        with self._inflight_cond, obs.span("serve.backpressure", batch=bid):
             while len(self._inflight) >= self.policy.max_inflight:
                 self._inflight_cond.wait()
             self._inflight.append((batch, votes))
@@ -736,7 +752,8 @@ class PicBnnServer:
                 break
             batch, votes = item
             try:
-                votes_np = np.asarray(votes)[:batch.n]  # sync + drop pad
+                with obs.span("serve.readback", batch=batch.bid):
+                    votes_np = np.asarray(votes)[:batch.n]  # sync + drop pad
                 batch.votes = votes_np
                 batch.preds = votes_np.argmax(-1)
             except BaseException as e:

@@ -167,6 +167,20 @@ class InferenceSpec:
             parts.append("cumulative")
         return "spec(" + ",".join(parts) + ")"
 
+    @property
+    def program_name(self) -> str:
+        """Name of the spec's compiled program (`picbnn_votes_off`,
+        `picbnn_votes_per_request_mc16_sum`, ...): a device trace shows
+        it as `jit_<name>`."""
+        parts = ["picbnn_votes", self.noise]
+        if self.mc_samples is not None:
+            parts.append(f"mc{self.mc_samples}")
+        if self.reduction != "none":
+            parts.append(self.reduction)
+        if self.cumulative:
+            parts.append("cumulative")
+        return "_".join(parts)
+
 
 #: common request shapes, by name (also the shims' targets)
 VOTES = InferenceSpec()
