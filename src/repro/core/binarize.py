@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +115,55 @@ def unpack_bits(words, n_bits: int):
 def pack_pm1(values):
     """±1 values -> packed uint32 words."""
     return pack_bits(to_bits(values))
+
+
+# Host packing splits a batch into at most _HOST_PACK_THREADS contiguous
+# chunks, one compare and one `packbits` each, the first on the calling
+# thread.  NumPy releases the GIL inside both loops; finer blocks lose
+# more to handing the GIL between threads than they gain in cache, and
+# four streams already take the memory bandwidth of a TPU v5e host
+# (timings in PERF.md).
+_HOST_PACK_THREADS = min(4, os.cpu_count() or 1)
+_HOST_CHUNK_BYTES = 1 << 20  # the least input a chunk is given
+
+
+@functools.cache
+def _host_pack_pool() -> ThreadPoolExecutor:
+    return ThreadPoolExecutor(max_workers=_HOST_PACK_THREADS - 1,
+                              thread_name_prefix="pack_pm1_host")
+
+
+def pack_pm1_host(values) -> np.ndarray:
+    """`pack_pm1` of a host [B, n] array, on the host: uint32 [B, kw].
+
+    Bit-equal to `pack_pm1` on the device: `values` is first cast to the
+    dtype JAX would stage it as (`canonicalize_dtype`: float64 ->
+    float32, int64 -> int32), so a float64 1e-50 packs as 0 there as here.
+    0, -0 and NaN give bit 0; rows pad to whole words with 0 bits.
+    """
+    x = np.asarray(values)
+    dtype = jax.dtypes.canonicalize_dtype(x.dtype)
+    b, n = x.shape
+    nbytes = -(-n // 8)
+    out = np.empty((b, packed_width(n)), "<u4")
+    view = out.view(np.uint8)
+
+    def pack_rows(lo: int, hi: int) -> None:
+        view[lo:hi, :nbytes] = np.packbits(
+            x[lo:hi].astype(dtype, copy=False) > 0, axis=-1,
+            bitorder="little")
+        view[lo:hi, nbytes:] = 0
+
+    least = max(1, _HOST_CHUNK_BYTES // max(1, n * dtype.itemsize))
+    chunk = max(least, -(-b // _HOST_PACK_THREADS))
+    futures = [_host_pack_pool().submit(pack_rows, lo, min(lo + chunk, b))
+               for lo in range(chunk, b, chunk)]
+    try:
+        pack_rows(0, min(chunk, b))
+    finally:
+        for f in futures:
+            f.result()
+    return out
 
 
 def hamming_packed(a, b):

@@ -14,8 +14,10 @@ running it costs the inactive-trace check alone.  There is no switch.
 Counters are process-wide running totals that never reset; a reader
 takes the difference of two `counters()` snapshots.  The program counts
 what it stages to the device (`stage.bytes`, `stage.rows`,
-`stage.calls`, `stage.ns`) and, once `watch_gc()` has run, the
-interpreter's collector (`gc.collections`, `gc.pause_ns`).
+`stage.calls`, `stage.ns`), the host packs of ±1 batches
+(`pack.host_calls`, `pack.host_rows`, `pack.host_ns`) and, once
+`watch_gc()` has run, the interpreter's collector (`gc.collections`,
+`gc.pause_ns`).
 """
 
 from __future__ import annotations

@@ -87,11 +87,22 @@ Persistable deployments (`repro.deploy.Deployment`) bundle the folded
 layers + encoding + configs this function takes, and rebuild the same
 pipeline from disk — see deploy.py.
 
+Where the input is packed: a host array given to an MLP with hidden
+layers is packed on the host (`binarize.pack_pm1_host`, bit-equal to
+the device pack), so only its uint32 words cross to the device, 1/32 of
+the float32 bytes.  A `jax.Array` (already on the device, as the server
+stages its batches), a conv pipeline's raw pixels and a head-only
+pipeline's input are staged as they are and packed by the `picbnn_pack`
+program on the device.
+
 Observability (`repro.obs`): each `run` is a `picbnn.run` profiler span
-with the pipeline's call number (`call=`), enclosing `picbnn.stage`
-(host->device copy, counted in `stage.*`), `picbnn.pack`, `picbnn.pad`
-(padded batches only) and `picbnn.vote`, each the dispatch of one
-program.  The programs carry fixed names: `picbnn_pack`, and one
+with the pipeline's call number (`call=`).  For a host MLP input it
+encloses `picbnn.host_pack` (counted in `pack.host_*`), `picbnn.stage`
+(host->device copy of the words, counted in `stage.*`), `picbnn.pad`
+(padded batches only) and `picbnn.vote`; for the other inputs
+`picbnn.stage` (host arrays only), `picbnn.pack`, `picbnn.pad` and
+`picbnn.vote`.  `picbnn.pack` and `picbnn.vote` are each the dispatch
+of one program.  The programs carry fixed names: `picbnn_pack`, and one
 `InferenceSpec.program_name` per spec (`picbnn_votes_off`, ...), so a
 device trace names them `jit_picbnn_*`.
 """
@@ -222,6 +233,9 @@ class CompiledPipeline:
     physics: Optional[SearchPhysics]  # None <=> compiled without noise=
     _program_factory: Callable  # InferenceSpec -> jitted program
     _pack_fn: Callable  # jitted ±1 [B, n_in] -> packed
+    # the same pack for host arrays, on the host (MLPs with hidden layers:
+    # `binarize.pack_pm1_host`); None packs every input on the device
+    _host_pack: Optional[Callable] = None
     max_bucket: Optional[int] = None  # serving cap on the bucket grid
     _programs: dict = dataclasses.field(default_factory=dict, repr=False)
     _calls: Iterator[int] = dataclasses.field(
@@ -257,7 +271,11 @@ class CompiledPipeline:
 
         x    : [B, n_in] — ±1 activations for MLP pipelines, RAW [0,1]
                pixels for conv pipelines (the binary input encoding and
-               channel packing run inside the jitted pack step).
+               channel packing run inside the jitted pack step).  A host
+               array given to an MLP with hidden layers is packed on the
+               host and its uint32 words are staged; a `jax.Array`, and
+               every input of conv and head-only pipelines, is packed on
+               the device.
         spec : what to run (`repro.spec.InferenceSpec`).
         key  : batch-level PRNG key — required iff spec.noise=="batch".
         keys : per-request raw uint32 [B, 2] PRNG keys — required iff
@@ -331,6 +349,17 @@ class CompiledPipeline:
     # shared glue (bucketing / packing / trimming / key shapes)
     # ------------------------------------------------------------------
     def _pack_input(self, x_pm1: jax.Array, call: int) -> jax.Array:
+        if self._host_pack is not None and not isinstance(x_pm1, jax.Array):
+            # a host ±1 batch keeps one bit of each value: pack it here
+            # and stage 1/32 of the float32 bytes
+            rows = len(x_pm1)
+            t0 = time.perf_counter_ns()
+            with obs.span("picbnn.host_pack", call=call, rows=rows):
+                words = self._host_pack(x_pm1)
+            obs.count("pack.host_ns", time.perf_counter_ns() - t0)
+            obs.count("pack.host_calls")
+            obs.count("pack.host_rows", rows)
+            return obs.stage(words, call=call)
         # one jitted dispatch: the eager op-by-op pack costs ~5x the whole
         # fused vote program in host dispatch overhead (serving hot path)
         x = obs.stage(x_pm1, call=call)
@@ -706,7 +735,7 @@ def compile_pipeline(
     head_rows = head.cam.rows_packed
     thresholds = head.thresholds
 
-    conv_metas = conv_ws = conv_cs = None
+    conv_metas = conv_ws = conv_cs = host_pack = None
     head_direct = False
     if conv_layers:
         enc = image_encoding or binarize.InputEncoding(
@@ -752,7 +781,7 @@ def compile_pipeline(
 
         pack = _pack_conv
     elif hidden:
-        pack = binarize.pack_pm1
+        pack, host_pack = binarize.pack_pm1, binarize.pack_pm1_host
     else:
         from repro.core.cam import query_with_bias
 
@@ -925,5 +954,6 @@ def compile_pipeline(
         physics=phys,
         _program_factory=make_program,
         _pack_fn=pack_fn,
+        _host_pack=host_pack,
         max_bucket=max_bucket,
     )

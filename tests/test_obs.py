@@ -55,18 +55,32 @@ def _delta(before, after):
             if after[k] != before.get(k, 0)}
 
 
-def test_run_spans_carry_the_call_and_nest(pipe, tmp_path):
-    x = _rows(13)  # pads to the 16-row bucket
+def _run_children(pipe, x, tmp_path):
+    """The spans one `run` of `x` writes inside its `picbnn.run`."""
     jax.block_until_ready(pipe.run(x, InferenceSpec()))  # compiled
     spans = _traced(tmp_path, lambda: jax.block_until_ready(
         pipe.run(x, InferenceSpec())))
     (run,) = [s for s in spans if s[0] == "picbnn.run"]
     children = [s for s in spans if s[0] != "picbnn.run"]
-    assert [s[0] for s in children] == [
-        "picbnn.stage", "picbnn.pack", "picbnn.pad", "picbnn.vote"]
     assert all(s[3]["call"] == run[3]["call"] for s in children)
     assert all(run[1] <= s[1] and s[2] <= run[2] for s in children)
-    assert children[0][3]["rows"] == 13
+    return children
+
+
+def test_run_spans_carry_the_call_and_nest(pipe, tmp_path):
+    # a host batch packs on the host; the packed words are staged
+    children = _run_children(pipe, _rows(13), tmp_path)  # 16-row bucket
+    assert [s[0] for s in children] == [
+        "picbnn.host_pack", "picbnn.stage", "picbnn.pad", "picbnn.vote"]
+    assert children[0][3]["rows"] == children[1][3]["rows"] == 13
+    assert children[1][3]["bytes"] == 13 * 4 * 3  # 96 bits: 3 words a row
+
+
+def test_run_spans_of_a_device_array_keep_the_device_pack(pipe, tmp_path):
+    # already on the device: nothing staged, the pack program packs it
+    children = _run_children(pipe, jax.device_put(_rows(13)), tmp_path)
+    assert [s[0] for s in children] == [
+        "picbnn.pack", "picbnn.pad", "picbnn.vote"]
 
 
 def test_run_packed_opens_its_own_run_span(pipe, tmp_path):
