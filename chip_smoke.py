@@ -9,7 +9,8 @@ Drives the main path through the entry points a user calls —
 (`configs/paper_mlp.py`, `configs/paper_cnn.py`), with weights made from
 `--seed`.  Each phase prints one line and checks its results exactly:
 
-  * the fused Pallas kernels against the float32 oracles, evaluated on
+  * the fused Pallas kernel (MLPs) and the int8 MXU program (CNNs)
+    against the float32 oracles, evaluated on
     the host CPU backend (`bnn.folded_forward_exact` +
     `ensemble.votes_fused` for the MLPs, `kernels.ref.conv_votes_ref`
     for the CNNs), on 1, 64 and 257 rows;
@@ -98,7 +99,7 @@ def _oracle(cfg, folded, head, x) -> np.ndarray:
     with jax.default_device(jax.devices("cpu")[0]):
         if isinstance(cfg, CNNConfig):
             votes = ref.conv_votes_ref(folded, head, x, cfg.encoding,
-                                       cfg.side)
+                                       cfg.side, cfg.channels)
         else:
             y = bnn.folded_forward_exact(folded[:-1], jnp.asarray(x))
             votes = ensemble.votes_fused(head, jnp.where(y >= 0, 1.0, -1.0))
@@ -106,13 +107,18 @@ def _oracle(cfg, folded, head, x) -> np.ndarray:
 
 
 def _assert_kernel(pipe, spec, x, **keys) -> None:
-    """Refuse to go on unless the program runs the fused Pallas kernel."""
+    """Refuse to go on unless the program runs the chip's path: the
+    fused Pallas kernel for an MLP, int8 convolutions for a CNN."""
     import jax
 
     if pipe.impl != "pallas":
         raise SystemExit(f"pipeline impl is {pipe.impl!r}, expected pallas")
     text = jax.jit(lambda v: pipe.run(v, spec, **keys)).lower(x).as_text()
-    if "tpu_custom_call" not in text:
+    if pipe.weight_operands:
+        if "stablehlo.convolution" not in text or "xi8>" not in text:
+            raise SystemExit(f"{spec.describe()} lowered without int8 "
+                             "convolutions")
+    elif "tpu_custom_call" not in text:
         raise SystemExit(
             f"{spec.describe()} lowered without a Mosaic kernel: the "
             "program would run the XLA twin or the interpreter"
@@ -186,7 +192,9 @@ def pipelines_phase(seed: int) -> None:
             n_noise += _equal(f"{name} rows={n} batch noise vs xla twin",
                               got, ref_votes)
         perturbed = int((np.asarray(got) != want[:n]).any(-1).sum())
-        print(f"[pipeline] {name}: pallas kernel, bit-exact vs oracle "
+        path = ("int8 MXU program" if pipe.weight_operands
+                else "pallas kernel")
+        print(f"[pipeline] {name}: {path}, bit-exact vs oracle "
               f"{n_exact}/{n_exact} rows, batch noise == xla twin "
               f"{n_noise}/{n_noise} rows ({perturbed} of {n} rows moved "
               "by noise); compile s (pack + program) by rows: noiseless "

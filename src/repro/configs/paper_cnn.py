@@ -14,11 +14,20 @@ the same synthetic stand-in datasets the MLP workload uses
       thermometer-4 input -> 3x3x32 s2 conv -> 3x3x32 s2 conv
       -> flatten 7200 -> FC 128 -> CAM head (20 rows, 33-pass vote)
 
-Downsampling is stride-2 VALID convs (no pooling — pooling would need a
-majority unit outside the binary-matching machinery).  Conv channel
-counts are multiples of 32 so the conv->FC flatten is word-aligned
-(DESIGN.md §10); the head row (128 + 64 bias cells) lands on the macro's
-1024x128 logical bank configuration, same as the paper MLPs.
+Downsampling in these two is stride-2 VALID convs; the head row (128 +
+64 bias cells) lands on the macro's 1024x128 logical bank configuration,
+same as the paper MLPs.
+
+  CIFAR-10 ConvNet (32x32x3, 10 classes; BinaryNet, Courbariaux et al.
+  2016, arXiv:1602.02830, after BinaryConnect arXiv:1511.00363 Sec. 3.3):
+      thermometer-8 per RGB channel (24 input channels)
+      -> (2x128C3)-MP2-(2x256C3)-MP2-(2x512C3)-MP2 -> flatten 8192
+      -> FC 1024 -> FC 1024 -> CAM head (10 rows, 33-pass vote)
+  3x3 convs with zero padding 1 ("same"); each 2x2/2 max-pool sits
+  between its conv and the batch norm, so after the fold it is the OR
+  (BN scale > 0) or the AND (< 0) of the window's sign bits.  The
+  thermometer input and the CAM head replace BinaryNet's real-valued
+  first-layer input and its 10 float output units.
 
 `build_cnn_pipeline` is the one-call deployment path used by the
 benchmarks, the serving registry, and the tests.
@@ -48,6 +57,18 @@ HG_CNN = CNNConfig(
     bias_cells=64,
 )
 
+CIFAR10_CONVNET = CNNConfig(
+    side=32,
+    channels=3,
+    encoding=InputEncoding("thermometer", 8),
+    conv=(ConvSpec(3, 128, 1, "same"), ConvSpec(3, 128, 1, "same", 2),
+          ConvSpec(3, 256, 1, "same"), ConvSpec(3, 256, 1, "same", 2),
+          ConvSpec(3, 512, 1, "same"), ConvSpec(3, 512, 1, "same", 2)),
+    hidden=(1024, 1024),
+    n_classes=10,
+    bias_cells=64,
+)
+
 CNN_ENSEMBLE = EnsembleConfig(
     thresholds=PAPER_THRESHOLDS, bias_cells=64, mode="fused"
 )
@@ -57,8 +78,7 @@ def deploy_cnn(cfg: CNNConfig, model, *, noise=None, **kw):
     """Build the `deploy.Deployment` artifact for an end-to-end CNN.
 
     Thin wrapper over `deploy.deploy` that threads the config's image
-    geometry, binary input encoding, and bias cells (the conv-aware bq
-    default — 64, DESIGN.md §10 — comes from compile_pipeline itself).
+    geometry and channels, binary input encoding, and bias cells.
     `model` is `convnet.fold_cnn` (trained), a trained params dict
     (folded here), or `convnet.random_folded_cnn` (weight-agnostic
     benchmarks/tests) output.  `.pipeline()` compiles lazily;

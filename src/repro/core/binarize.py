@@ -292,7 +292,10 @@ class InputEncoding:
 
     `encode_bits` maps [..., H, W] (or any shape) intensities to
     [..., width] {0,1} bits; `encode_pm1` maps to the ±1 domain the
-    float oracles consume.  Both are deterministic and jit-safe.
+    float oracles consume.  `encode_image_bits` / `encode_image_pm1`
+    take [..., H, W, C] images and give [..., H, W, C * width] channels,
+    channel-major: channel c's code bit t is input channel c * width + t.
+    All are deterministic and jit-safe.
     """
 
     kind: str = "thermometer"
@@ -317,6 +320,15 @@ class InputEncoding:
     def encode_pm1(self, x01, dtype=jnp.float32):
         """[0,1] intensities [...] -> ±1 values [..., width]."""
         return from_bits(self.encode_bits(x01), dtype)
+
+    def encode_image_bits(self, img01):
+        """[0,1] images [..., H, W, C] -> {0,1} uint8 [..., H, W, C*width]."""
+        bits = self.encode_bits(img01)
+        return bits.reshape(*bits.shape[:-2], -1)
+
+    def encode_image_pm1(self, img01, dtype=jnp.float32):
+        """[0,1] images [..., H, W, C] -> ±1 values [..., H, W, C*width]."""
+        return from_bits(self.encode_image_bits(img01), dtype)
 
 
 def random_pm1(key, shape, dtype=jnp.float32):

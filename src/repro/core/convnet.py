@@ -1,28 +1,31 @@
 """End-to-end-binary CNN: training (sign-STE conv + batch-norm) and
-deployment folding for the packed-domain conv pipeline.
+deployment folding for the conv pipeline.
 
 The paper's central claim is *end-to-end* binarization: typical binary
 CNNs keep the input layer in full precision, PiC-BNN binarizes
 everything.  This module carries the conv analogue of `core/bnn.py`:
 
-  * the INPUT layer is binary too — raw [0,1] pixels pass through a
-    `binarize.InputEncoding` (thermometer by default) into `width`
-    binary channels before the first conv;
+  * the INPUT layer is binary too — each channel of the raw [0,1]
+    pixels passes through a `binarize.InputEncoding` (thermometer by
+    default) into `width` binary channels before the first conv;
   * conv layers train with latent real weights + sign-STE + per-channel
     batch norm, exactly the BinaryConnect recipe `bnn.py` uses for FC
     layers;
   * `fold_cnn` collapses each conv BN into an integer constant C_o
-    (Eq. 3 per output channel) and emits `FoldedConvLayer` rows the
-    packed-domain kernel (`kernels/fused_conv.py`) consumes, followed by
-    folded FC layers for the MLP head — one flat list that
-    `pipeline.compile_pipeline` compiles end to end.
+    (Eq. 3 per output channel) and emits `FoldedConvLayer` rows that
+    `kernels/fused_conv.py` runs, followed by folded FC layers for the
+    MLP head — one flat list that `pipeline.compile_pipeline` compiles
+    end to end.
 
-Spatial semantics: VALID convolutions with integer stride (downsampling
-is stride-2 convs, no pooling — pooling would need a majority/OR unit
-outside the binary-matching machinery, stride-2 conv reuses it).
-Deployment-side layout conventions (channel-packed NHWC words, per-
-position word alignment at the flatten) are owned by
-`kernels/fused_conv.py` and documented in DESIGN.md §10.
+Spatial semantics: k x k convolutions with integer stride, VALID or
+SAME zero padding (a pad position contributes 0 to the dot, exactly as
+a zero-padded ±1 conv), optionally followed by a p x p / p max-pool.
+The pool sits between the conv and the batch norm, as in BinaryNet
+(Courbariaux et al. 2016), so it needs no majority unit: after the fold
+the pooled bit of a channel whose BN scale is positive is the OR of the
+window's sign bits, and the AND for a negative scale, whose row the fold
+negates (`FoldedConvLayer.pool_sign`).  Layouts are documented in
+DESIGN.md §10.
 """
 
 from __future__ import annotations
@@ -40,38 +43,79 @@ from repro.core.binarize import InputEncoding, sign_ste
 Params = dict[str, Any]
 
 
+PADDINGS = ("valid", "same")
+
+
+def conv_pads(side: int, k: int, stride: int, padding: str) -> tuple:
+    """(low, high) zero padding of each spatial axis: none for "valid";
+    for "same" the output side is ceil(side / stride) and the padding is
+    split as XLA's SAME splits it (the odd one on the high side)."""
+    if padding not in PADDINGS:
+        raise ValueError(f"padding {padding!r} not in {PADDINGS}")
+    if padding == "valid":
+        return 0, 0
+    out = -(-side // stride)
+    total = max((out - 1) * stride + k - side, 0)
+    return total // 2, total - total // 2
+
+
+def conv_out_side(side: int, k: int, stride: int, padding: str,
+                  pool: int) -> tuple[int, int]:
+    """(conv output side, side after the pool) of a square `side` input."""
+    lo, hi = conv_pads(side, k, stride, padding)
+    if side + lo + hi < k:
+        raise ValueError(f"input side {side} < kernel {k}")
+    conv = (side + lo + hi - k) // stride + 1
+    if conv < pool:
+        raise ValueError(f"conv output side {conv} < pool {pool}")
+    return conv, conv // pool
+
+
 @dataclasses.dataclass(frozen=True)
 class ConvSpec:
-    """One binary conv layer: k x k window, c_out filters, VALID, stride."""
+    """One binary conv layer: k x k window, c_out filters, stride,
+    "valid" or "same" zero padding, then a pool x pool / pool max-pool
+    (pool 1: none)."""
 
     k: int
     c_out: int
     stride: int = 1
+    padding: str = "valid"
+    pool: int = 1
 
     def __post_init__(self):
-        if self.k < 1 or self.c_out < 1 or self.stride < 1:
+        if (self.k < 1 or self.c_out < 1 or self.stride < 1
+                or self.pool < 1 or self.padding not in PADDINGS):
             raise ValueError(f"bad ConvSpec {self}")
 
+    def conv_side(self, side: int) -> int:
+        """Conv output side (before the pool) for a square `side` input."""
+        return conv_out_side(side, self.k, self.stride, self.padding,
+                             self.pool)[0]
+
     def out_side(self, side: int) -> int:
-        """VALID output side for a square `side` input."""
-        if side < self.k:
-            raise ValueError(f"input side {side} < kernel {self.k}")
-        return (side - self.k) // self.stride + 1
+        """Output side after the pool for a square `side` input."""
+        return conv_out_side(side, self.k, self.stride, self.padding,
+                             self.pool)[1]
 
 
 @dataclasses.dataclass(frozen=True)
 class CNNConfig:
     """End-to-end-binary CNN hyperparameters.
 
-    side      : square input image side (n_in = side * side raw pixels)
-    encoding  : binary input layer ([0,1] pixel -> `encoding.width`
-                binary channels; the paper's end-to-end claim)
-    conv      : conv stack (VALID, strided)
+    side      : square input image side
+    channels  : input channels per pixel (n_in = side * side * channels
+                raw pixels, HWC order)
+    encoding  : binary input layer (each [0,1] channel value ->
+                `encoding.width` binary channels; the paper's end-to-end
+                claim)
+    conv      : conv stack (`ConvSpec`: padding, stride, pool)
     hidden    : FC widths between the flatten and the output layer
     n_classes : output classes (the CAM ensemble head rows)
     """
 
     side: int = 28
+    channels: int = 1
     encoding: InputEncoding = InputEncoding("thermometer", 8)
     conv: Sequence[ConvSpec] = (ConvSpec(3, 32, 2), ConvSpec(3, 32, 2))
     hidden: Sequence[int] = (128,)
@@ -83,7 +127,7 @@ class CNNConfig:
     @property
     def n_in(self) -> int:
         """Raw pixel count the pipeline/serving layer sees."""
-        return self.side * self.side
+        return self.side * self.side * self.channels
 
     def feature_sides(self) -> list[int]:
         """Feature-map side after the input and after each conv layer."""
@@ -94,7 +138,8 @@ class CNNConfig:
 
     def feature_channels(self) -> list[int]:
         """Channel count entering each conv layer (+ the final one)."""
-        return [self.encoding.width] + [s.c_out for s in self.conv]
+        return ([self.encoding.width * self.channels]
+                + [s.c_out for s in self.conv])
 
     @property
     def flat_features(self) -> int:
@@ -112,16 +157,44 @@ class FoldedConvLayer:
     """Deployment form of one binary conv layer (Eq. 3 per channel).
 
     weights_pm1 : [c_out, k, k, c_in] ±1 filters (one CAM row per output
-                  channel; row bits ordered tap-major (dy, dx, c) to
-                  match the packed patch layout — DESIGN.md §10)
+                  channel; row bits ordered tap-major (dy, dx, c),
+                  the CAM row of one patch)
     c           : [c_out] integer BN constants, parity-adjusted so
                   sign(dot + C) has no dead zone (bnn.parity_adjust_c)
-    stride      : spatial stride (VALID padding always)
+    stride      : spatial stride
+    padding     : "valid" or "same" (zero padding; pads add 0 to a dot)
+    pool        : max-pool window and stride (1: no pool)
+    pool_sign   : [c_out] ±1, the sign s of each channel's folded BN
+                  scale (None: all +1).  The fold negates the rows of
+                  s = -1 channels, so a pooled channel's bit is the OR of
+                  its window's sign(dot + C) bits for s = +1 and the AND
+                  for s = -1: sign(s * maxpool(y) + C) with y the conv
+                  output of the unnegated rows.  Unused without a pool.
     """
 
     weights_pm1: np.ndarray
     c: np.ndarray
     stride: int = 1
+    padding: str = "valid"
+    pool: int = 1
+    pool_sign: np.ndarray | None = None
+
+    def __post_init__(self):
+        if self.padding not in PADDINGS or self.pool < 1:
+            raise ValueError(f"bad padding {self.padding!r} / pool "
+                             f"{self.pool}")
+        if self.pool_sign is not None and (
+                np.shape(self.pool_sign) != (self.c_out,)
+                or not np.isin(self.pool_sign, (-1, 1)).all()):
+            raise ValueError("pool_sign must be [c_out] of ±1")
+
+    @property
+    def pool_or(self) -> np.ndarray:
+        """[c_out] bool: True where the pool ORs its sign bits (s = +1),
+        False where it ANDs them (s = -1)."""
+        if self.pool_sign is None:
+            return np.ones(self.c_out, bool)
+        return np.asarray(self.pool_sign) > 0
 
     @property
     def c_out(self) -> int:
@@ -148,7 +221,7 @@ def init_cnn_params(key: jax.Array, cfg: CNNConfig,
                     dtype=jnp.float32) -> Params:
     """Glorot latent conv filters + FC weights, identity batch norm."""
     params: Params = {"conv": [], "fc": []}
-    c_in = cfg.encoding.width
+    c_in = cfg.feature_channels()[0]
     for spec in cfg.conv:
         key, sub = jax.random.split(key)
         fan_in = spec.k * spec.k * c_in
@@ -199,27 +272,33 @@ def _bn(y, layer, eps, momentum, train: bool, axes):
 
 def cnn_forward(params: Params, x01: jax.Array, cfg: CNNConfig, *,
                 train: bool = False):
-    """Forward pass on raw [0,1] pixels [B, side*side].
+    """Forward pass on raw [0,1] pixels [B, side*side*channels] (HWC).
 
     The input layer is BINARY: pixels pass through `cfg.encoding` into
     ±1 channels before the first conv — no full-precision input layer
-    anywhere.  Returns (logits, new_params) like `bnn.forward`: full-
-    precision post-BN logits of the output layer (training criterion
-    only; deployment replaces them with Algorithm-1 votes) and
-    BN-stat-updated params when `train=True`.
+    anywhere.  Each conv is followed by its max-pool (if any), then
+    batch norm and sign (BinaryNet's order).  Returns (logits,
+    new_params) like `bnn.forward`: full-precision post-BN logits of the
+    output layer (training criterion only; deployment replaces them with
+    Algorithm-1 votes) and BN-stat-updated params when `train=True`.
     """
     b = x01.shape[0]
-    h = cfg.encoding.encode_pm1(
-        jnp.asarray(x01).reshape(b, cfg.side, cfg.side)
-    )  # [B, H, W, E] ±1 — the binary input layer
+    h = cfg.encoding.encode_image_pm1(
+        jnp.asarray(x01).reshape(b, cfg.side, cfg.side, cfg.channels)
+    )  # [B, H, W, C*E] ±1 — the binary input layer
     new_conv = []
     for layer, spec in zip(params["conv"], cfg.conv):
         wb = sign_ste(layer["w"])  # [k, k, c_in, c_out] ±1
+        pads = conv_pads(h.shape[1], spec.k, spec.stride, spec.padding)
         y = jax.lax.conv_general_dilated(
             h, wb, window_strides=(spec.stride, spec.stride),
-            padding="VALID",
+            padding=(pads, pads),
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
         )
+        if spec.pool > 1:
+            win = (1, spec.pool, spec.pool, 1)
+            y = jax.lax.reduce_window(y, -jnp.inf, jax.lax.max, win, win,
+                                      "VALID")
         y, stats = _bn(y, layer, cfg.bn_eps, cfg.bn_momentum, train,
                        axes=(0, 1, 2))
         new_conv.append({**layer, **stats})
@@ -252,7 +331,9 @@ def _fold_bn(w_rows: np.ndarray, layer, eps: float, n_bits: int,
 
     Same algebra as `bnn.fold`: flip rows where gamma < 0, then
     C = round(beta*sigma/|gamma| - mu'), parity-adjusted against the
-    dot width so sign(dot + C) never hits the dead zone.
+    dot width so sign(dot + C) never hits the dead zone.  Also returns
+    the sign of each gamma (+1 for gamma >= 0): the flipped rows' pool
+    polarity.
     """
     gamma = np.asarray(layer["gamma"], np.float64)
     beta = np.asarray(layer["beta"], np.float64)
@@ -265,7 +346,7 @@ def _fold_bn(w_rows: np.ndarray, layer, eps: float, n_bits: int,
     thresh = np.where(flip, -thresh, thresh)
     c = parity_adjust_c(np.round(-thresh).astype(np.int64), n_bits,
                         bias_cells)
-    return w_rows.astype(np.int8), c
+    return w_rows.astype(np.int8), c, np.where(flip, -1, 1).astype(np.int8)
 
 
 def fold_cnn(params: Params, cfg: CNNConfig) -> list:
@@ -274,9 +355,11 @@ def fold_cnn(params: Params, cfg: CNNConfig) -> list:
     Returns [FoldedConvLayer, ..., FoldedLayer, ...] — the conv stack
     followed by the MLP stage, the flat graph
     `pipeline.compile_pipeline` accepts.  Conv filters are emitted as
-    CAM rows [c_out, k, k, c_in] (tap-major bit order); the first FC
-    layer's n_in is `cfg.flat_features` in NHWC flatten order, matching
-    the training-time reshape bit for bit.
+    CAM rows [c_out, k, k, c_in] (tap-major bit order), with the layer's
+    padding, pool and, for a pooled layer, each channel's pool polarity
+    (the BN fold commutes with the max-pool: it is monotone in the
+    pooled value); the first FC layer's n_in is `cfg.flat_features` in
+    NHWC flatten order, matching the training-time reshape bit for bit.
     """
     folded: list = []
     for layer, spec in zip(params["conv"], cfg.conv):
@@ -285,13 +368,15 @@ def fold_cnn(params: Params, cfg: CNNConfig) -> list:
         # [k, k, c_in, c_out] -> rows [c_out, k, k, c_in]
         w = np.transpose(w, (3, 0, 1, 2))
         n_bits = spec.k * spec.k * w.shape[3]
-        w, c = _fold_bn(w, layer, cfg.bn_eps, n_bits, cfg.bias_cells)
-        folded.append(FoldedConvLayer(weights_pm1=w, c=c,
-                                      stride=spec.stride))
+        w, c, s = _fold_bn(w, layer, cfg.bn_eps, n_bits, cfg.bias_cells)
+        folded.append(FoldedConvLayer(
+            weights_pm1=w, c=c, stride=spec.stride, padding=spec.padding,
+            pool=spec.pool, pool_sign=s if spec.pool > 1 else None))
     for layer in params["fc"]:
         w = np.asarray(jnp.sign(layer["w"]))
         w = np.where(w == 0, 1.0, w).T  # [out, in]
-        w, c = _fold_bn(w, layer, cfg.bn_eps, w.shape[1], cfg.bias_cells)
+        w, c, _ = _fold_bn(w, layer, cfg.bn_eps, w.shape[1],
+                           cfg.bias_cells)
         folded.append(FoldedLayer(weights_pm1=w, c=c))
     return folded
 
@@ -309,7 +394,7 @@ def train_cnn(
 ) -> Params:
     """Adam on latent weights with [-1, 1] latent clipping.
 
-    `train_x` is RAW [0,1] pixels [N, side*side] — the binary input
+    `train_x` is RAW [0,1] pixels [N, n_in] — the binary input
     encoding happens inside the forward pass (the whole point of the
     end-to-end-binary workload).  Same BinaryConnect recipe as
     `bnn.train_mlp`; BN running stats ride back through the loss aux.
@@ -384,7 +469,8 @@ def cnn_inference_cost(cfg: CNNConfig, n_output_passes: int = 33):
 
     Each conv layer maps its filters onto a CAM tile plan
     (`mapping.plan_layer` with row width k*k*c_in + bias cells) and is
-    searched once per output position; FC layers query once; the output
+    searched once per conv output position (before any pool); FC layers
+    query once; the output
     layer sweeps `n_output_passes` thresholds.  This is what the serving
     registry reports as the silicon-equivalent throughput for CNN
     models (`PicBnnServer.register(silicon_cost=...)`).
@@ -394,11 +480,11 @@ def cnn_inference_cost(cfg: CNNConfig, n_output_passes: int = 33):
     sides = cfg.feature_sides()
     chans = cfg.feature_channels()
     plans, queries = [], []
-    for spec, c_in, s_out in zip(cfg.conv, chans[:-1], sides[1:]):
+    for spec, c_in, s_in in zip(cfg.conv, chans[:-1], sides[:-1]):
         plans.append(mapping.plan_layer(
             spec.c_out, spec.k * spec.k * c_in, cfg.bias_cells
         ))
-        queries.append(s_out * s_out)
+        queries.append(spec.conv_side(s_in) ** 2)
     sizes = cfg.fc_sizes
     for n_in, n_out in zip(sizes[:-1], sizes[1:]):
         plans.append(mapping.plan_layer(n_out, n_in, cfg.bias_cells))
@@ -413,12 +499,14 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
 
     The shape-and-semantics twin of the benchmarks' `random_folded` MLP
     helper: random ±1 filters/weights with valid dead-zone-free
-    constants, for bit-exactness tests and throughput benchmarks that
-    don't need a trained model.
+    constants (and, for pooled layers, random ±1 pool polarities, drawn
+    after everything else so unpooled nets keep their draws), for
+    bit-exactness tests and throughput benchmarks that don't need a
+    trained model.
     """
     rng = np.random.default_rng(seed)
     folded: list = []
-    c_in = cfg.encoding.width
+    c_in = cfg.feature_channels()[0]
     for spec in cfg.conv:
         n_bits = spec.k * spec.k * c_in
         c = parity_adjust_c(
@@ -431,6 +519,8 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
             ).astype(np.int8),
             c=c,
             stride=spec.stride,
+            padding=spec.padding,
+            pool=spec.pool,
         ))
         c_in = spec.c_out
     sizes = cfg.fc_sizes
@@ -445,4 +535,9 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
             ).astype(np.int8),
             c=c,
         ))
+    for i, spec in enumerate(cfg.conv):
+        if spec.pool > 1:
+            folded[i] = dataclasses.replace(
+                folded[i], pool_sign=rng.choice([-1, 1], spec.c_out)
+                .astype(np.int8))
     return folded

@@ -103,6 +103,7 @@ class Deployment:
     params: Optional[AnalogParams] = None
     image_side: Optional[int] = None
     image_encoding: Optional[InputEncoding] = None
+    image_channels: Optional[int] = None  # conv: pixel channels (HWC)
     compile_options: dict = dataclasses.field(default_factory=dict)
     _pipe: Optional[_pipeline.CompiledPipeline] = dataclasses.field(
         default=None, repr=False, compare=False
@@ -152,6 +153,7 @@ class Deployment:
             if self.image_side is not None:
                 kw["image_side"] = self.image_side
                 kw["image_encoding"] = self.image_encoding
+                kw["image_channels"] = self.image_channels
             self._pipe = _pipeline.compile_pipeline(
                 list(self.folded), self.ens_cfg,
                 noise=self.noise, params=self.params, **kw
@@ -187,6 +189,10 @@ class Deployment:
                     "kind": "conv",
                     "shape": list(layer.weights_pm1.shape),
                     "stride": int(layer.stride),
+                    "padding": layer.padding,
+                    "pool": int(layer.pool),
+                    "pool_sign": (None if layer.pool_sign is None else
+                                  [int(v) for v in layer.pool_sign]),
                 })
             else:
                 layers_meta.append({
@@ -216,6 +222,7 @@ class Deployment:
             "analog_params": (None if self.params is None
                               else dataclasses.asdict(self.params)),
             "image_side": self.image_side,
+            "image_channels": self.image_channels,
             "image_encoding": (None if self.image_encoding is None else {
                 "kind": self.image_encoding.kind,
                 "width": int(self.image_encoding.width),
@@ -263,8 +270,13 @@ class Deployment:
             w = _unpack_rows(np.asarray(leaf["w"]), lm["shape"])
             c = np.asarray(leaf["c"], np.int64)
             if lm["kind"] == "conv":
+                sign = lm.get("pool_sign")
                 folded.append(FoldedConvLayer(
-                    weights_pm1=w, c=c, stride=int(lm["stride"])
+                    weights_pm1=w, c=c, stride=int(lm["stride"]),
+                    padding=lm.get("padding", "valid"),
+                    pool=int(lm.get("pool", 1)),
+                    pool_sign=(None if sign is None
+                               else np.asarray(sign, np.int8)),
                 ))
             else:
                 folded.append(FoldedLayer(weights_pm1=w, c=c))
@@ -284,6 +296,7 @@ class Deployment:
             params=(None if mf["analog_params"] is None
                     else AnalogParams(**mf["analog_params"])),
             image_side=mf["image_side"],
+            image_channels=mf.get("image_channels"),
             image_encoding=(None if enc is None
                             else InputEncoding(enc["kind"], enc["width"])),
             compile_options=dict(mf["compile_options"]),
@@ -304,6 +317,7 @@ def deploy(
     params: Optional[AnalogParams] = None,
     image_side: Optional[int] = None,
     image_encoding: Optional[InputEncoding] = None,
+    image_channels: Optional[int] = None,
     **compile_options,
 ) -> Deployment:
     """Build a `Deployment` from a model — MLP and CNN configs alike.
@@ -315,10 +329,11 @@ def deploy(
         (`bnn.fold` for `MLPConfig`, `convnet.fold_cnn` for `CNNConfig`).
     config : optional `MLPConfig` | `CNNConfig`; supplies the defaults a
         hand-rolled call would restate — `bias_cells` for the ensemble
-        config, and (CNN) the image side + binary input encoding.
-    ens_cfg / noise / params / image_side / image_encoding : as
-        `pipeline.compile_pipeline`; explicit arguments win over
-        config-derived defaults.
+        config, and (CNN) the image side, channels and binary input
+        encoding.
+    ens_cfg / noise / params / image_side / image_encoding /
+    image_channels : as `pipeline.compile_pipeline`; explicit arguments
+        win over config-derived defaults.
     compile_options : forwarded to `compile_pipeline` at (lazy) compile
         time — one of `deploy.COMPILE_OPTIONS` (impl, bq, min_bucket,
         max_bucket, donate).  How a kernel runs (compiled on TPU, the
@@ -346,6 +361,8 @@ def deploy(
         image_side = config.side if image_side is None else image_side
         image_encoding = (config.encoding if image_encoding is None
                           else image_encoding)
+        image_channels = (config.channels if image_channels is None
+                          else image_channels)
     if ens_cfg is None:
         bias = getattr(config, "bias_cells", None)
         ens_cfg = (EnsembleConfig(bias_cells=bias) if bias is not None
@@ -357,5 +374,6 @@ def deploy(
         params=params,
         image_side=image_side,
         image_encoding=image_encoding,
+        image_channels=image_channels,
         compile_options=compile_options,
     )

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def binary_gemm_hd_ref(x_packed, w_packed) -> jax.Array:
@@ -28,49 +29,73 @@ def bitlinear_ref(x, w, n_bits: int | None = None) -> jax.Array:
     return jnp.asarray(x, jnp.float32) @ jnp.asarray(w, jnp.float32)
 
 
-def binary_conv2d_ref(x_pm1, w_pm1, stride: int = 1) -> jax.Array:
-    """±1-domain VALID conv oracle: the unpacked ground truth.
+def binary_conv2d_ref(x_pm1, w_pm1, stride: int = 1,
+                      padding: str = "valid") -> jax.Array:
+    """±1-domain conv oracle: the unpacked ground truth.
 
     x_pm1: [B, H, W, C] ±1 activations;  w_pm1: [O, K, K, C] ±1 filters
     (CAM-row layout, `convnet.FoldedConvLayer.weights_pm1`).  Returns
     float32 [B, OH, OW, O] dot products — each output position is the
-    XNOR-popcount dot of its K*K*C patch against every filter row
-    (== n_bits - 2*HD in the packed domain).
+    XNOR-popcount dot of its K*K*C patch against every filter row, with
+    zeros at "same" padding positions.
     """
+    from repro.core.convnet import conv_pads
+
     x = jnp.asarray(x_pm1, jnp.float32)
     w = jnp.asarray(w_pm1, jnp.float32)
+    pads = conv_pads(x.shape[1], w.shape[1], stride, padding)
     # conv_general_dilated computes a true convolution-as-correlation
     # with HWIO kernels, so transpose the row layout [O,K,K,C]->[K,K,C,O]
     return jax.lax.conv_general_dilated(
         x, jnp.transpose(w, (1, 2, 3, 0)),
-        window_strides=(stride, stride), padding="VALID",
+        window_strides=(stride, stride), padding=(pads, pads),
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
-def conv_votes_ref(folded, head, x01, encoding, side: int) -> jax.Array:
+def conv_layer_ref(h, layer) -> jax.Array:
+    """One `FoldedConvLayer` on ±1 maps, in BinaryNet's form.
+
+    The fold negated the rows of channels whose BN scale sign s is -1;
+    this undoes that, max-pools the conv output y of the original rows
+    and applies sign(s * maxpool(y) + C) — not the OR/AND of sign bits
+    the deployed path computes.
+    """
+    s = np.where(layer.pool_or, 1.0, -1.0).astype(np.float32)
+    w = np.asarray(layer.weights_pm1, np.float32) * s[:, None, None, None]
+    y = binary_conv2d_ref(h, w, layer.stride, layer.padding)
+    if layer.pool > 1:
+        win = (1, layer.pool, layer.pool, 1)
+        y = jax.lax.reduce_window(y, -jnp.inf, jax.lax.max, win, win,
+                                  "VALID")
+    return jnp.where(s * y + jnp.asarray(layer.c, jnp.float32) >= 0,
+                     1.0, -1.0)
+
+
+def conv_votes_ref(folded, head, x01, encoding, side: int,
+                   channels: int = 1) -> jax.Array:
     """Unpacked end-to-end-binary CNN oracle: raw pixels -> vote counts.
 
     The ground truth for `kernels/fused_conv.py` and the conv pipeline:
-    encode [0,1] pixels [B, side*side] through the binary input layer,
-    run every FoldedConvLayer as sign(conv + C) in ±1 floats, flatten
-    NHWC, run the folded FC hidden layers as sign(Wx + C), and vote the
-    head with `ensemble.votes_fused`.  Bit-exactness of the packed
-    fused path against this oracle is asserted in tests/test_conv.py.
+    encode [0,1] pixels [B, side*side*channels] (HWC) through the binary
+    input layer, run every FoldedConvLayer with `conv_layer_ref` in ±1
+    floats, flatten NHWC, run the folded FC hidden layers as
+    sign(Wx + C), and vote the head with `ensemble.votes_fused`.
+    Bit-exactness of the deployed path against this oracle is asserted
+    in tests/test_conv.py and tests/test_conv_cifar.py.
     """
     from repro.core.convnet import FoldedConvLayer
     from repro.core.ensemble import votes_fused
 
     b = jnp.asarray(x01).shape[0]
-    h = encoding.encode_pm1(
-        jnp.asarray(x01).reshape(b, side, side)
+    h = encoding.encode_image_pm1(
+        jnp.asarray(x01).reshape(b, side, side, channels)
     )
     flat = None
     for layer in folded[:-1]:
         if isinstance(layer, FoldedConvLayer):
-            y = binary_conv2d_ref(h, layer.weights_pm1, layer.stride)
-            h = jnp.where(y + jnp.asarray(layer.c, jnp.float32) >= 0,
-                          1.0, -1.0)
+            h = conv_layer_ref(h, layer)
         else:
             if flat is None:
                 h, flat = h.reshape(b, -1), True

@@ -71,13 +71,14 @@ scheduling choice.
 Convolutional graphs: `folded` may start with a prefix of
 `convnet.FoldedConvLayer` (a deployed end-to-end-binary CNN, e.g.
 `convnet.fold_cnn` output).  The pipeline then takes RAW [0,1] pixels
-[B, side*side]: the binary input layer (`image_encoding`, thermometer by
-default) and the channel packing run inside the jitted `_pack_fn`, the
-conv stack executes in the packed domain (`kernels/fused_conv.py` on the
-pallas path, the same shared math as one XLA program otherwise), and the
-flatten feeds the FC stage — so every spec works identically for conv
-and MLP deployments.  Bit-exactness bar: the unpacked oracle
-`kernels.ref.conv_votes_ref` (tests/test_conv.py).
+[B, side*side*channels] (HWC): the binary input layer
+(`image_encoding`, thermometer by default) and the channel packing run
+inside the jitted `_pack_fn`, and the whole net, conv stack, FC layers
+and head distances, runs as ±1 int8 products on the MXU
+(`kernels/fused_conv.py`) on every backend, so every spec works
+identically for conv and MLP deployments.  Its weights are arguments of
+the programs, not jit constants.  Bit-exactness bar: the unpacked
+oracle `kernels.ref.conv_votes_ref` (tests/test_conv.py).
 
 Batch-size bucketing: inputs are zero-padded up to the next bucket
 (powers of two, floor `min_bucket`) so a serving loop with ragged batch
@@ -102,7 +103,10 @@ encloses `picbnn.host_pack` (counted in `pack.host_*`), `picbnn.stage`
 (padded batches only) and `picbnn.vote`; for the other inputs
 `picbnn.stage` (host arrays only), `picbnn.pack`, `picbnn.pad` and
 `picbnn.vote`.  `picbnn.pack` and `picbnn.vote` are each the dispatch
-of one program.  The programs carry fixed names: `picbnn_pack`, and one
+of one program.  Each vote dispatch adds the weight bytes its program
+reads from HBM, as the program lays them out (once per call), to the
+counter `kernel.weight_bytes`, and the rows it answers to
+`kernel.rows`.  The programs carry fixed names: `picbnn_pack`, and one
 `InferenceSpec.program_name` per spec (`picbnn_votes_off`, ...), so a
 device trace names them `jit_picbnn_*`.
 """
@@ -165,8 +169,8 @@ def bucket_grid(max_batch: int, min_bucket: int = 64) -> tuple[int, ...]:
 
 def _named(fn: Callable, name: str) -> Callable:
     """`fn` under the name `jax.jit` gives its program (`jit_<name>`)."""
-    def named(*args):
-        return fn(*args)
+    def named(*args, **kw):
+        return fn(*args, **kw)
 
     named.__name__ = named.__qualname__ = name
     return named
@@ -237,6 +241,10 @@ class CompiledPipeline:
     # `binarize.pack_pm1_host`); None packs every input on the device
     _host_pack: Optional[Callable] = None
     max_bucket: Optional[int] = None  # serving cap on the bucket grid
+    # weight operands every program takes as `ops=` (conv graphs; MLP
+    # programs hold theirs as constants), and the bytes a call reads
+    weight_operands: tuple = ()
+    weight_bytes: int = 0
     _programs: dict = dataclasses.field(default_factory=dict, repr=False)
     _calls: Iterator[int] = dataclasses.field(
         default_factory=itertools.count, repr=False)  # span `call=` ids
@@ -249,7 +257,8 @@ class CompiledPipeline:
 
         Signature depends on the spec's noise axis: `f(x_packed)` for
         "off", `f(x_packed, key)` for "batch", `f(x_packed, keys)` for
-        "per_request" — `run_packed` dispatches accordingly.  Callers
+        "per_request", each with the weight operands as `ops=` —
+        `run_packed` dispatches accordingly.  Callers
         normally never touch this; it exists so warmup and tests can
         assert cache identity.
         """
@@ -270,10 +279,11 @@ class CompiledPipeline:
         """Execute one declarative inference request on a raw batch.
 
         x    : [B, n_in] — ±1 activations for MLP pipelines, RAW [0,1]
-               pixels for conv pipelines (the binary input encoding and
-               channel packing run inside the jitted pack step).  A host
-               array given to an MLP with hidden layers is packed on the
-               host and its uint32 words are staged; a `jax.Array`, and
+               pixels [B, side*side*channels] (HWC) for conv pipelines
+               (the binary input encoding and channel packing run inside
+               the jitted pack step).  A host array given to an MLP with
+               hidden layers is packed on the host and its uint32 words
+               are staged; a `jax.Array`, and
                every input of conv and head-only pipelines, is packed on
                the device.
         spec : what to run (`repro.spec.InferenceSpec`).
@@ -296,7 +306,8 @@ class CompiledPipeline:
         """`run` for an already-packed input batch [B, Kw0].
 
         Conv pipelines: Kw0 = side*side*Cw0, the row-flattened channel-
-        packed encoded image the jitted pack step emits (`_pack_input`).
+        packed encoded image the jitted pack step emits (`_pack_input`),
+        Cw0 the words of one pixel's channels * encoding width bits.
         This is the ONE place bucket padding, key-shape validation, and
         result trimming happen, for every spec.  `call` is the span id
         of the `run` this continues; None opens a `picbnn.run` of its own.
@@ -308,6 +319,7 @@ class CompiledPipeline:
                                        call=call)
         prog = self.program(spec)  # physics capability check happens here
         x_packed, b = self._bucketed(x_packed, call)
+        ops = self.weight_operands
         if spec.needs_keys:
             if key is not None:
                 raise ValueError(
@@ -321,7 +333,7 @@ class CompiledPipeline:
                 )
             keys = self._each_keys(keys, b, x_packed.shape[0], call)
             with obs.span("picbnn.vote", call=call):
-                out = prog(x_packed, keys)
+                out = prog(x_packed, keys, ops=ops)
         elif spec.needs_key:
             if keys is not None:
                 raise ValueError(
@@ -334,7 +346,7 @@ class CompiledPipeline:
                     "is one silicon realization)"
                 )
             with obs.span("picbnn.vote", call=call):
-                out = prog(x_packed, key)
+                out = prog(x_packed, key, ops=ops)
         else:
             if key is not None or keys is not None:
                 raise ValueError(
@@ -342,7 +354,9 @@ class CompiledPipeline:
                     "it accepts neither key= nor keys="
                 )
             with obs.span("picbnn.vote", call=call):
-                out = prog(x_packed)
+                out = prog(x_packed, ops=ops)
+        obs.count("kernel.weight_bytes", self.weight_bytes)
+        obs.count("kernel.rows", b)
         return self._trim(out, b, spec.batch_axis)
 
     # ------------------------------------------------------------------
@@ -653,6 +667,7 @@ def compile_pipeline(
     donate: bool = False,
     image_side: int | None = None,
     image_encoding: binarize.InputEncoding | None = None,
+    image_channels: int | None = None,
 ) -> CompiledPipeline:
     """Compile a folded BNN + ensemble head into a fused batch classifier.
 
@@ -660,13 +675,14 @@ def compile_pipeline(
               May start with a prefix of `convnet.FoldedConvLayer`
               (`convnet.fold_cnn` output): the pipeline then runs the
               end-to-end-binary CNN workload and its input domain becomes
-              RAW [0,1] pixels [B, image_side**2] (the binary input
-              encoding runs inside the jitted pack step).
+              RAW [0,1] pixels [B, image_side**2 * image_channels] (the
+              binary input encoding runs inside the jitted pack step).
     ens_cfg : Algorithm-1 config (thresholds / bias cells); default paper's.
     impl    : "pallas" | "xla" | None (auto: pallas on TPU, xla elsewhere).
               The backend alone decides how the kernel runs: compiled by
               Mosaic on TPU, through the Pallas interpreter elsewhere
-              (semantics tests, not speed).
+              (semantics tests, not speed).  Conv graphs run one MXU
+              program under either impl.
     bq      : Pallas batch rows per kernel block; default 128, one vreg
               of lanes (the batch is the kernels' lane axis — DESIGN.md
               §4 and §10 derive the VMEM budgets).  Compiled blocks need
@@ -688,11 +704,14 @@ def compile_pipeline(
               ignore the donation.  Off by default because `run_packed`
               is public API and donation invalidates the caller's array.
     image_side : REQUIRED for conv graphs — square input image side
-              (`n_in = image_side**2` raw pixels).  Rejected for pure
-              MLP graphs.
+              (`n_in = image_side**2 * image_channels` raw pixels).
+              Rejected for pure MLP graphs.
     image_encoding : the binary input layer for conv graphs
-              (`binarize.InputEncoding`); its width must equal the first
-              conv layer's c_in.  Default: thermometer of that width.
+              (`binarize.InputEncoding`); its width times
+              `image_channels` must equal the first conv layer's c_in.
+              Default: thermometer of c_in / image_channels.
+    image_channels : input channels per pixel of a conv graph (HWC
+              rows; default 1).  Rejected for pure MLP graphs.
 
     The returned pipeline compiles lazily: `run(x, spec)` builds one
     fused program per distinct `InferenceSpec` on first use (warmup()
@@ -719,8 +738,11 @@ def compile_pipeline(
     if conv_layers and image_side is None:
         raise ValueError("conv graphs need image_side=")
     if not conv_layers and (image_side is not None
-                            or image_encoding is not None):
-        raise ValueError("image_side/image_encoding are conv-only options")
+                            or image_encoding is not None
+                            or image_channels is not None):
+        raise ValueError(
+            "image_side/image_encoding/image_channels are conv-only options")
+    channels = 1 if image_channels is None else int(image_channels)
 
     hidden, out_layer = list(rest[:-1]), rest[-1]
     head = build_head(out_layer, ens_cfg)
@@ -735,49 +757,43 @@ def compile_pipeline(
     head_rows = head.cam.rows_packed
     thresholds = head.thresholds
 
-    conv_metas = conv_ws = conv_cs = host_pack = None
-    head_direct = False
+    host_pack = None
+    operands: tuple = ()
+    weight_bytes = sum(int(a.size) * a.dtype.itemsize
+                       for a in (*layer_ws, *layer_cs, head_rows))
     if conv_layers:
+        c0 = conv_layers[0].c_in
         enc = image_encoding or binarize.InputEncoding(
-            "thermometer", conv_layers[0].c_in
+            "thermometer", c0 // channels
         )
-        if enc.width != conv_layers[0].c_in:
+        if enc.width * channels != c0:
             raise ValueError(
-                f"encoding width {enc.width} != first conv c_in "
-                f"{conv_layers[0].c_in}"
+                f"encoding width {enc.width} x {channels} channel(s) != "
+                f"first conv c_in {c0}"
             )
-        conv_metas = fused_conv.conv_metas_for(conv_layers, image_side)
-        conv_ws = tuple(fused_conv.pack_conv_rows(l) for l in conv_layers)
-        conv_cs = tuple(jnp.asarray(l.c, jnp.int32) for l in conv_layers)
+        side = int(image_side)
+        conv_metas = fused_conv.conv_metas_for(conv_layers, side)
         mf = conv_metas[-1]
-        n_pos, c_f = mf.out_side * mf.out_side, mf.c_out
+        n_feat = mf.out_side * mf.out_side * mf.c_out
         first_fc = hidden[0] if hidden else out_layer
-        if int(first_fc.n_in) != n_pos * c_f:
+        if int(first_fc.n_in) != n_feat:
             raise ValueError(
                 f"first FC layer n_in {first_fc.n_in} != flattened conv "
-                f"features {n_pos}*{c_f}"
+                f"features {mf.out_side}^2*{mf.c_out}"
             )
-        head_direct = not hidden
-        if head_direct and c_f % 32:
-            raise ValueError(
-                "conv -> head-direct needs last conv c_out % 32 == 0 "
-                f"(word-aligned flatten), got {c_f}"
-            )
-        if hidden:
-            # the flatten keeps per-position word padding — repack the
-            # first FC layer's rows with the matching alignment
-            layer_ws = (
-                fused_conv.pack_fc_rows_positionwise(
-                    (hidden[0].weights_pm1 > 0).astype(np.uint8),
-                    n_pos, c_f,
-                ),
-            ) + layer_ws[1:]
-        side, cw0 = image_side, conv_metas[0].cw_in
+        operands = (
+            tuple(fused_conv.conv_operands(l) for l in conv_layers),
+            tuple(fused_conv.fc_operands(l) for l in hidden),
+            fused_conv.head_operands(
+                head_rows, int(hidden[-1].n_out) if hidden else n_feat,
+                head.bias_cells),
+        )
+        weight_bytes = fused_conv.weight_bytes(operands)
 
         def _pack_conv(x01):
-            img = jnp.asarray(x01).reshape(-1, side, side)
-            words = binarize.pack_bits(enc.encode_bits(img))
-            return words.reshape(words.shape[0], side * side * cw0)
+            img = jnp.asarray(x01).reshape(-1, side, side, channels)
+            words = binarize.pack_bits(enc.encode_image_bits(img))
+            return words.reshape(words.shape[0], -1)
 
         pack = _pack_conv
     elif hidden:
@@ -793,41 +809,21 @@ def compile_pipeline(
         phys = SearchPhysics.for_head(head, noise, params)
 
     # donation-friendly programs: the packed input is the only per-call
-    # buffer worth donating (weights live in the closures)
+    # buffer worth donating (the weights are reused by every call)
     donate_kw = {"donate_argnums": (0,)} if donate else {}
 
     if conv_layers:
-        bias_words = (fused_conv.bias_drive_words(head.bias_cells)
-                      if head_direct else None)
-
-        def _front(x_packed):
-            # [B, S*S*Cw0] -> conv stack -> flattened packed FC query
-            x4 = x_packed.reshape(-1, image_side, image_side, cw0)
-            return fused_conv.conv_stage_packed(
-                x4, conv_ws, conv_cs, conv_metas, bias_words
-            )
+        def _hd_xla(x_packed, ops):
+            return fused_conv.net_hd(x_packed, ops, conv_metas, side)
     else:
-        def _front(x_packed):
-            return x_packed
-
-    def _hd_xla(x_packed):
-        return _head_hd_xla(
-            _front(x_packed), layer_ws, layer_cs, layer_n_bits, head_rows,
-            head.bias_cells,
-        )
-
-    # the two kernel-eligible vote producers (single [B, C] result block)
-    if impl == "pallas" and conv_layers:
-        def _kernel_votes(x_packed, thr_samples=None):
-            return fused_conv.fused_conv_votes(
-                x_packed.reshape(-1, image_side, image_side, cw0),
-                conv_ws, conv_cs, conv_metas,
-                layer_ws, layer_cs, layer_n_bits, head_rows, thresholds,
-                bias_cells=head.bias_cells, bq=bq,
-                interpret=interpret, head_direct=head_direct,
-                thr_samples=thr_samples,
+        def _hd_xla(x_packed, ops):
+            return _head_hd_xla(
+                x_packed, layer_ws, layer_cs, layer_n_bits, head_rows,
+                head.bias_cells,
             )
-    elif impl == "pallas":
+
+    # the kernel-eligible vote producer (single [B, C] result block)
+    if impl == "pallas" and not conv_layers:
         def _kernel_votes(x_packed, thr_samples=None):
             return fused_mlp.fused_mlp_votes(
                 x_packed, layer_ws, layer_cs, layer_n_bits,
@@ -838,15 +834,15 @@ def compile_pipeline(
     else:
         _kernel_votes = None
 
-    def _votes_off(x_packed):
+    def _votes_off(x_packed, ops=()):
         if _kernel_votes is not None:
             return _kernel_votes(x_packed)
-        hd = _hd_xla(x_packed)
+        hd = _hd_xla(x_packed, ops)
         return (hd[:, :, None] <= thresholds[None, None, :]).astype(
             jnp.int32
         ).sum(-1)
 
-    def _votes_batch(x_packed, key):
+    def _votes_batch(x_packed, key, ops=()):
         # one batch-shaped draw: sampled [P, B, C] thresholds against the
         # single HD computation
         if _kernel_votes is not None:
@@ -854,7 +850,7 @@ def compile_pipeline(
                 key, batch_shape=(x_packed.shape[0],), n_rows=n_classes
             )  # [P, B, C]
             return _kernel_votes(x_packed, thr_samples=t)
-        hd = _hd_xla(x_packed).astype(jnp.float32)  # [B, C]
+        hd = _hd_xla(x_packed, ops).astype(jnp.float32)  # [B, C]
         t = phys.sample(key, batch_shape=(hd.shape[0],), n_rows=n_classes)
         return (hd[None] <= t).astype(jnp.int32).sum(0)
 
@@ -872,15 +868,15 @@ def compile_pipeline(
 
         if spec.cumulative:
             if spec.noise == "off":
-                def fn(x_packed):
+                def fn(x_packed, ops=()):
                     # the exact staircase: per-pass match indicators of
                     # the deterministic compare, cumsum'd over passes
-                    hd = _hd_xla(x_packed)
+                    hd = _hd_xla(x_packed, ops)
                     per = (hd[None, :, :] <= thresholds[:, None, None])
                     return jnp.cumsum(per.astype(jnp.int32), axis=0)
             else:  # "batch"
-                def fn(x_packed, key):
-                    hd = _hd_xla(x_packed).astype(jnp.float32)
+                def fn(x_packed, key, ops=()):
+                    hd = _hd_xla(x_packed, ops).astype(jnp.float32)
                     t = phys.sample(key, (hd.shape[0],), n_classes)
                     return jnp.cumsum((hd[None] <= t).astype(jnp.int32),
                                       axis=0)
@@ -890,8 +886,8 @@ def compile_pipeline(
             if mc is None:
                 fn = _votes_batch
             else:
-                def fn(x_packed, key):
-                    hd = _hd_xla(x_packed).astype(jnp.float32)  # ONCE
+                def fn(x_packed, key, ops=()):
+                    hd = _hd_xla(x_packed, ops).astype(jnp.float32)  # ONCE
 
                     def one(k):
                         t = phys.sample(k, (hd.shape[0],), n_classes)
@@ -901,12 +897,12 @@ def compile_pipeline(
                     return out.sum(0) if spec.reduction == "sum" else out
         else:  # "per_request"
             if mc is None:
-                def fn(x_packed, keys):
-                    hd = _hd_xla(x_packed).astype(jnp.float32)  # [B, C]
+                def fn(x_packed, keys, ops=()):
+                    hd = _hd_xla(x_packed, ops).astype(jnp.float32)  # [B, C]
                     return jax.vmap(_votes_one)(hd, keys)
             elif spec.reduction == "sum":
-                def fn(x_packed, keys):
-                    hd = _hd_xla(x_packed).astype(jnp.float32)
+                def fn(x_packed, keys, ops=()):
+                    hd = _hd_xla(x_packed, ops).astype(jnp.float32)
 
                     def per_req(hd_i, k):
                         return jax.vmap(lambda ks: _votes_one(hd_i, ks))(
@@ -915,8 +911,8 @@ def compile_pipeline(
 
                     return jax.vmap(per_req)(hd, keys)  # [B, C]
             else:
-                def fn(x_packed, keys):
-                    hd = _hd_xla(x_packed).astype(jnp.float32)  # ONCE
+                def fn(x_packed, keys, ops=()):
+                    hd = _hd_xla(x_packed, ops).astype(jnp.float32)  # ONCE
 
                     def per_req(hd_i, k):
                         return jax.vmap(lambda ks: _votes_one(hd_i, ks))(
@@ -930,16 +926,16 @@ def compile_pipeline(
         if spec.reduction == "argmax":
             base = fn  # single-realization vote producer, [B, C]
             if spec.noise == "off":
-                def fn(x_packed):
-                    return jnp.argmax(base(x_packed), axis=-1)
+                def fn(x_packed, ops=()):
+                    return jnp.argmax(base(x_packed, ops), axis=-1)
             else:
-                def fn(x_packed, rng):
-                    return jnp.argmax(base(x_packed, rng), axis=-1)
+                def fn(x_packed, rng, ops=()):
+                    return jnp.argmax(base(x_packed, rng, ops), axis=-1)
 
         return jax.jit(_named(fn, spec.program_name), **donate_kw)
 
     if conv_layers:
-        n_in = int(image_side) ** 2  # raw [0,1] pixels in, encode inside
+        n_in = side * side * channels  # raw [0,1] pixels in, encode inside
     elif hidden:
         n_in = int(hidden[0].n_in)
     else:
@@ -956,4 +952,6 @@ def compile_pipeline(
         _pack_fn=pack_fn,
         _host_pack=host_pack,
         max_bucket=max_bucket,
+        weight_operands=operands,
+        weight_bytes=weight_bytes,
     )

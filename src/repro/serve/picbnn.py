@@ -421,8 +421,8 @@ class PicBnnServer:
             (`Deployment.save` output — servers register models straight
             from disk).  MLP deployments take ±1 activation requests of
             width `pipe.n_in`, conv deployments raw [0,1] pixel requests
-            of width image_side**2; the serving layer only sees [n_in]
-            request rows either way.
+            of width image_side**2 * image_channels (HWC); the serving
+            layer only sees [n_in] request rows either way.
 
         layer_sizes : optional (n_in, ..., n_classes) of a deployed MLP
             — enables the Table-II silicon-equivalent throughput in

@@ -1,8 +1,8 @@
 """End-to-end-binary CNN workload: bit-exactness vs the unpacked oracle.
 
 The correctness bar for kernels/fused_conv.py and the conv path of
-repro/pipeline.py: the packed fused flow (both impls) must be
-bit-identical to `kernels.ref.conv_votes_ref` — the ±1 float oracle that
+repro/pipeline.py: the deployed flow (one int8 program under either
+impl) must be bit-identical to `kernels.ref.conv_votes_ref` — the ±1 float oracle that
 encodes raw pixels through the binary input layer, runs every conv/FC
 layer as sign(dot + C), and votes the head — across multiple input
 sizes, strides, channel alignments, and the silicon-mode entry points.
@@ -20,8 +20,8 @@ from repro.core.device_model import NOISELESS, SILICON
 from repro.kernels import ref
 
 # Two input sizes (the acceptance bar asks for >= 2), plus a config with
-# non-word-aligned channel counts to exercise the position-wise flatten
-# packing, and a conv->head-direct net with no FC hidden layer.
+# channel counts that fill no whole word, and a conv->head-direct net
+# with no FC hidden layer.
 CONFIGS = {
     "mnist-28": CNNConfig(
         side=28, encoding=InputEncoding("thermometer", 8),
@@ -183,11 +183,19 @@ def test_compile_pipeline_conv_validation():
             folded, EnsembleConfig(), image_side=10,
             image_encoding=InputEncoding("thermometer", 5),
         )
-    # head-direct with a non-word-aligned last conv is rejected
-    bad = CNNConfig(side=10, encoding=InputEncoding("thermometer", 2),
+    # a head fed straight by a conv whose channels fill no whole word
+    # runs too: the int8 path has no word-aligned flatten to keep
+    odd = CNNConfig(side=10, encoding=InputEncoding("thermometer", 2),
                     conv=(ConvSpec(3, 24, 2),), hidden=(), n_classes=5)
-    with pytest.raises(ValueError, match="word-aligned"):
-        build_cnn_pipeline(bad, convnet.random_folded_cnn(bad, seed=2))
+    folded = convnet.random_folded_cnn(odd, seed=2)
+    pipe = build_cnn_pipeline(odd, folded)
+    x = _images(odd, 5, seed=7)
+    np.testing.assert_array_equal(np.asarray(pipe.votes(x)),
+                                  _oracle(odd, folded, pipe.head, x))
+    other_head = convnet.random_folded_cnn(CONFIGS["head-direct-10"])[-1]
+    with pytest.raises(ValueError, match="flattened conv"):
+        pipeline.compile_pipeline([folded[0], other_head], EnsembleConfig(),
+                                  image_side=10)
 
 
 def test_cnn_configs_consistent():

@@ -1,12 +1,16 @@
-"""The served Pallas kernels compile for a TPU v5e chip (no chip needed).
+"""The served programs compile for a TPU v5e chip (no chip needed).
 
 The TPU compiler is installed with jaxlib and compiles for a chip that is
-described, not attached: `fused_mlp_votes` and `fused_conv_votes` are
-lowered with `interpret=False` at the widths of the repo's four
-deployments, noiseless and with the `thr_samples` operand, and each
-compiled program must hold a Mosaic kernel (`tpu_custom_call`).  This
-catches what interpret mode cannot — unsupported lowerings, unaligned
-slices, scoped-VMEM overruns — before any chip time is spent.
+described, not attached: `fused_mlp_votes` is lowered with
+`interpret=False` at the widths of the repo's MLP deployments, noiseless
+and with the `thr_samples` operand, and each compiled program must hold
+a Mosaic kernel (`tpu_custom_call`); the CNN deployments' vote programs
+(the noiseless one and the batch-noise one, `kernels/fused_conv.py`),
+BinaryNet's CIFAR-10 ConvNet among them at its published widths and the
+benchmark's batch, must compile to int8 convolutions.  This catches
+what interpret mode cannot — unsupported lowerings, unaligned slices,
+scoped-VMEM overruns, programs past the chip's memory — before any chip
+time is spent.
 
 The topology is described inside a fixture (never at import), and the
 persistent compilation cache is off around the compiles: a described
@@ -20,19 +24,24 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.paper_cnn import HG_CNN, MNIST_CNN
+from repro.configs.paper_cnn import (CIFAR10_CONVNET, HG_CNN, MNIST_CNN,
+                                     deploy_cnn)
 from repro.configs.paper_mlp import HG_MLP, MNIST_MLP
 from repro.core import binarize, convnet
 from repro.core.convnet import CNNConfig
-from repro.kernels import fused_conv, fused_mlp
+from repro.core.device_model import SILICON
+from repro.kernels import fused_mlp
+from repro.spec import InferenceSpec
 
 DEPLOYMENTS = {
     "mnist_mlp": MNIST_MLP,
     "hg_mlp": HG_MLP,
     "mnist_cnn": MNIST_CNN,
     "hg_cnn": HG_CNN,
+    "cifar10_convnet": CIFAR10_CONVNET,
 }
 BATCH = 256  # two kernel blocks of 128 rows
+CIFAR_BATCH = 1024  # the benchmark cell's batch
 PASSES = 33
 
 
@@ -89,36 +98,21 @@ def _mlp_call(cfg, shape):
     return call, args
 
 
-def _conv_call(cfg, shape):
-    folded = convnet.random_folded_cnn(cfg)
-    conv = folded[:len(cfg.conv)]
-    metas = fused_conv.conv_metas_for(conv, cfg.side)
-    m0, mf = metas[0], metas[-1]
-    fc = cfg.fc_sizes
-    hidden = list(zip(fc[:-2], fc[1:-1]))
-    args = (
-        shape((BATCH, cfg.side, cfg.side, m0.cw_in), jnp.uint32),
-        tuple(shape((m.c_out, m.k * m.k * m.cw_in), jnp.uint32)
-              for m in metas),
-        tuple(shape((m.c_out,), jnp.int32) for m in metas),
-        tuple(shape((n_out, mf.out_side ** 2 * mf.cw_out if i == 0
-                     else binarize.packed_width(n_in)), jnp.uint32)
-              for i, (n_in, n_out) in enumerate(hidden)),
-        tuple(shape((n_out,), jnp.int32) for _, n_out in hidden),
-        shape((cfg.n_classes,
-               binarize.packed_width(fc[-2] + cfg.bias_cells)), jnp.uint32),
-        shape((PASSES,), jnp.int32),
-    )
-    n_bits = tuple(n_in for n_in, _ in hidden)
-
-    def call(x, cws, ccs, ws, cs, head, thr, thr_samples):
-        return fused_conv.fused_conv_votes(
-            x, cws, ccs, metas, ws, cs, n_bits, head, thr,
-            bias_cells=cfg.bias_cells, head_direct=not hidden,
-            thr_samples=thr_samples,
-        )
-
-    return call, args
+def _conv_program(cfg, shape, noisy):
+    """The vote program of a CNN deployment and its argument shapes."""
+    dep = deploy_cnn(cfg, convnet.random_folded_cnn(cfg),
+                     noise=SILICON if noisy else None)
+    pipe = dep.pipeline()
+    spec = InferenceSpec(noise="batch") if noisy else InferenceSpec()
+    prog = pipe.program(spec)
+    batch = CIFAR_BATCH if cfg is CIFAR10_CONVNET else BATCH
+    words = jax.eval_shape(pipe._pack_fn, jax.ShapeDtypeStruct(
+        (1, pipe.n_in), jnp.float32)).shape[1]
+    x = shape((batch, words), jnp.uint32)
+    ops = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
+                                 pipe.weight_operands)
+    args = (x, shape((2,), jnp.uint32)) if noisy else (x,)
+    return prog, args, ops
 
 
 @pytest.mark.parametrize("noisy", [False, True], ids=["noiseless", "noisy"])
@@ -129,8 +123,12 @@ def test_fused_kernel_compiles_for_v5e(name, noisy, one_chip):
     def shape(s, dtype):
         return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
 
-    build = _conv_call if isinstance(cfg, CNNConfig) else _mlp_call
-    call, args = build(cfg, shape)
+    if isinstance(cfg, CNNConfig):
+        prog, args, ops = _conv_program(cfg, shape, noisy)
+        text = prog.lower(*args, ops=ops).compile().as_text()
+        assert "convolution" in text and "s8[" in text
+        return
+    call, args = _mlp_call(cfg, shape)
     n_classes = args[-2].shape[0]
     thr_samples = (shape((PASSES, BATCH, n_classes), jnp.float32)
                    if noisy else None)
