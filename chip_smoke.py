@@ -197,7 +197,7 @@ def pipelines_phase(seed: int) -> None:
         print(f"[pipeline] {name}: {path}, bit-exact vs oracle "
               f"{n_exact}/{n_exact} rows, batch noise == xla twin "
               f"{n_noise}/{n_noise} rows ({perturbed} of {n} rows moved "
-              "by noise); compile s (pack + program) by rows: noiseless "
+              "by noise); first-call s (compile) by rows: noiseless "
               + ", ".join(f"{n}:{s:.2f}" for n, s in compile_s.items())
               + "; batch noise "
               + ", ".join(f"{n}:{s:.2f}" for n, s in noise_s.items()),

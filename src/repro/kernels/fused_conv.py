@@ -1,11 +1,13 @@
 """The deployed binary CNN as ±1 int8 products on the MXU.
 
 The conv sibling of `kernels/fused_mlp.py`: one XLA program takes a
-batch of channel-packed encoded images to the vote head's Hamming
-distances, and every binary dot in it runs on the matrix unit as an
-int8 product with int32 sums:
+batch of raw [0,1] pixel rows to the vote head's Hamming distances, and
+every binary dot in it runs on the matrix unit as an int8 product with
+int32 sums:
 
-    input:            channel-packed words -> ±1 int8 maps [B, S, S, C0]
+    input:            float32 pixels [B, S*S*C] (HWC rows) -> the
+                      deployment's `InputEncoding` -> ±1 int8 maps
+                      [B, S, S, C0] (`encode_input`)
     per conv layer:   k x k conv of the ±1 map with the layer's ±1 int8
                       filters (int32 sums), zero padding for "same" — a
                       pad is 0, so it adds nothing to any dot, exactly as
@@ -115,11 +117,15 @@ def weight_bytes(operands) -> int:
                for a in jax.tree_util.tree_leaves(operands))
 
 
-def unpack_input(x_packed, side: int, c0: int):
-    """[B, S*S*Cw0] channel-packed words -> ±1 int8 [B, S, S, c0]."""
-    words = x_packed.reshape(x_packed.shape[0], side, side, -1)
-    bits = binarize.unpack_bits(words, c0).astype(jnp.int8)
-    return 2 * bits - 1
+def encode_input(x01, side: int, channels: int,
+                 enc: binarize.InputEncoding):
+    """[B, S*S*channels] [0,1] pixels (HWC rows) -> ±1 int8 [B, S, S, C0].
+
+    `enc.encode_image_bits` of the images, so every encoding keeps the
+    one definition the oracles use (C0 = channels * enc.width).
+    """
+    img = x01.reshape(x01.shape[0], side, side, channels)
+    return 2 * enc.encode_image_bits(img).astype(jnp.int8) - 1
 
 
 def conv_layer(h, w, c, s, m: ConvMeta):
@@ -142,14 +148,16 @@ def fc_layer(h, w, c):
     return jnp.where(y + c >= 0, 1, -1).astype(jnp.int8)
 
 
-def net_hd(x_packed, operands, metas: Sequence[ConvMeta], side: int):
-    """Head Hamming distances [B, C] int32 of a packed image batch.
+def net_hd(x01, operands, metas: Sequence[ConvMeta], side: int,
+           channels: int, enc: binarize.InputEncoding):
+    """Head Hamming distances [B, C] int32 of a raw pixel batch.
 
+    x01      : [B, side*side*channels] float32 pixels in [0,1] (HWC).
     operands : (conv, fc, head) — `conv_operands` per conv layer,
                `fc_operands` per FC hidden layer, `head_operands`.
     """
     conv, fc, (head_w, offset) = operands
-    h = unpack_input(x_packed, side, metas[0].c_in)
+    h = encode_input(x01, side, channels, enc)
     for (w, c, s), m in zip(conv, metas):
         h = conv_layer(h, w, c, s, m)
     h = h.reshape(h.shape[0], -1)

@@ -71,11 +71,11 @@ scheduling choice.
 Convolutional graphs: `folded` may start with a prefix of
 `convnet.FoldedConvLayer` (a deployed end-to-end-binary CNN, e.g.
 `convnet.fold_cnn` output).  The pipeline then takes RAW [0,1] pixels
-[B, side*side*channels] (HWC): the binary input layer
-(`image_encoding`, thermometer by default) and the channel packing run
-inside the jitted `_pack_fn`, and the whole net, conv stack, FC layers
-and head distances, runs as ±1 int8 products on the MXU
-(`kernels/fused_conv.py`) on every backend, so every spec works
+[B, side*side*channels] (HWC), and each vote program takes them as they
+are staged: it runs the binary input layer (`image_encoding`,
+thermometer by default) to ±1 int8 maps itself, then the whole net,
+conv stack, FC layers and head distances, as ±1 int8 products on the
+MXU (`kernels/fused_conv.py`) on every backend, so every spec works
 identically for conv and MLP deployments.  Its weights are arguments of
 the programs, not jit constants.  Bit-exactness bar: the unpacked
 oracle `kernels.ref.conv_votes_ref` (tests/test_conv.py).
@@ -91,21 +91,24 @@ pipeline from disk — see deploy.py.
 Where the input is packed: a host array given to an MLP with hidden
 layers is packed on the host (`binarize.pack_pm1_host`, bit-equal to
 the device pack), so only its uint32 words cross to the device, 1/32 of
-the float32 bytes.  A `jax.Array` (already on the device, as the server
-stages its batches), a conv pipeline's raw pixels and a head-only
-pipeline's input are staged as they are and packed by the `picbnn_pack`
-program on the device.
+the float32 bytes.  An MLP's `jax.Array` (already on the device, as the
+server stages its batches) and a head-only pipeline's input are staged
+as they are and packed by the `picbnn_pack` program on the device.  A
+conv pipeline has no packed form: its float32 pixels are staged and the
+vote program encodes them.
 
 Observability (`repro.obs`): each `run` is a `picbnn.run` profiler span
 with the pipeline's call number (`call=`).  For a host MLP input it
 encloses `picbnn.host_pack` (counted in `pack.host_*`), `picbnn.stage`
 (host->device copy of the words, counted in `stage.*`), `picbnn.pad`
-(padded batches only) and `picbnn.vote`; for the other inputs
-`picbnn.stage` (host arrays only), `picbnn.pack`, `picbnn.pad` and
-`picbnn.vote`.  `picbnn.pack` and `picbnn.vote` are each the dispatch
-of one program.  Each vote dispatch adds the weight bytes its program
-reads from HBM, as the program lays them out (once per call), to the
-counter `kernel.weight_bytes`, and the rows it answers to
+(padded batches only) and `picbnn.vote`; for a conv pipeline
+`picbnn.stage` (host arrays only), `picbnn.pad` and `picbnn.vote`; for
+the other inputs `picbnn.stage` (host arrays only), `picbnn.pack`,
+`picbnn.pad` and `picbnn.vote`.  `picbnn.pack` and `picbnn.vote` are
+each the dispatch of one program; each `picbnn.pack` adds one to the
+counter `pack.device_calls`.  Each vote dispatch adds the weight bytes
+its program reads from HBM, as the program lays them out (once per
+call), to the counter `kernel.weight_bytes`, and the rows it answers to
 `kernel.rows`.  The programs carry fixed names: `picbnn_pack`, and one
 `InferenceSpec.program_name` per spec (`picbnn_votes_off`, ...), so a
 device trace names them `jit_picbnn_*`.
@@ -236,7 +239,9 @@ class CompiledPipeline:
     head_only: bool  # no hidden layers: input feeds the CAM head directly
     physics: Optional[SearchPhysics]  # None <=> compiled without noise=
     _program_factory: Callable  # InferenceSpec -> jitted program
-    _pack_fn: Callable  # jitted ±1 [B, n_in] -> packed
+    # the jitted `picbnn_pack` program, ±1 [B, n_in] -> packed words;
+    # None for conv graphs, whose vote program takes the pixels
+    _pack_fn: Optional[Callable]
     # the same pack for host arrays, on the host (MLPs with hidden layers:
     # `binarize.pack_pm1_host`); None packs every input on the device
     _host_pack: Optional[Callable] = None
@@ -280,12 +285,11 @@ class CompiledPipeline:
 
         x    : [B, n_in] — ±1 activations for MLP pipelines, RAW [0,1]
                pixels [B, side*side*channels] (HWC) for conv pipelines
-               (the binary input encoding and channel packing run inside
-               the jitted pack step).  A host array given to an MLP with
+               (staged as float32; the vote program runs the binary
+               input encoding).  A host array given to an MLP with
                hidden layers is packed on the host and its uint32 words
-               are staged; a `jax.Array`, and
-               every input of conv and head-only pipelines, is packed on
-               the device.
+               are staged; an MLP's `jax.Array`, and every input of a
+               head-only pipeline, is packed on the device.
         spec : what to run (`repro.spec.InferenceSpec`).
         key  : batch-level PRNG key — required iff spec.noise=="batch".
         keys : per-request raw uint32 [B, 2] PRNG keys — required iff
@@ -303,11 +307,12 @@ class CompiledPipeline:
                    key: Optional[jax.Array] = None,
                    keys: Optional[jax.Array] = None,
                    call: Optional[int] = None) -> jax.Array:
-        """`run` for an already-packed input batch [B, Kw0].
+        """`run` for an input batch already in its program's form.
 
-        Conv pipelines: Kw0 = side*side*Cw0, the row-flattened channel-
-        packed encoded image the jitted pack step emits (`_pack_input`),
-        Cw0 the words of one pixel's channels * encoding width bits.
+        MLP and head-only pipelines: the packed words [B, Kw0] that
+        `_pack_input` emits.  Conv pipelines: the raw [0,1] pixels
+        [B, side*side*channels] float32 (HWC), which the vote program
+        encodes itself — a conv pipeline has no packed form.
         This is the ONE place bucket padding, key-shape validation, and
         result trimming happen, for every spec.  `call` is the span id
         of the `run` this continues; None opens a `picbnn.run` of its own.
@@ -374,9 +379,12 @@ class CompiledPipeline:
             obs.count("pack.host_calls")
             obs.count("pack.host_rows", rows)
             return obs.stage(words, call=call)
+        x = obs.stage(x_pm1, call=call)
+        if self._pack_fn is None:  # conv: the vote program encodes pixels
+            return x
         # one jitted dispatch: the eager op-by-op pack costs ~5x the whole
         # fused vote program in host dispatch overhead (serving hot path)
-        x = obs.stage(x_pm1, call=call)
+        obs.count("pack.device_calls")
         with obs.span("picbnn.pack", call=call):
             return self._pack_fn(x)
 
@@ -535,8 +543,8 @@ class CompiledPipeline:
         batch-level silicon draw (`InferenceSpec(noise="batch")`).
 
         Input domain: ±1 activations for MLP pipelines; RAW [0,1] pixels
-        for conv pipelines (n_in = image_side**2 — the binary input
-        encoding and channel packing run inside the jitted pack step).
+        for conv pipelines (n_in = image_side**2 * image_channels — the
+        vote program runs the binary input encoding).
         With the NOISELESS model the keyed path is bit-identical to the
         noiseless one.
         """
@@ -676,7 +684,7 @@ def compile_pipeline(
               (`convnet.fold_cnn` output): the pipeline then runs the
               end-to-end-binary CNN workload and its input domain becomes
               RAW [0,1] pixels [B, image_side**2 * image_channels] (the
-              binary input encoding runs inside the jitted pack step).
+              binary input encoding runs inside the vote program).
     ens_cfg : Algorithm-1 config (thresholds / bias cells); default paper's.
     impl    : "pallas" | "xla" | None (auto: pallas on TPU, xla elsewhere).
               The backend alone decides how the kernel runs: compiled by
@@ -696,13 +704,15 @@ def compile_pipeline(
     max_bucket : optional cap on the batch-bucket grid (see next_bucket);
               serving loops set it to their max batch so warmup() closes
               the compiled-variant set.
-    donate  : donate the packed input buffer to the compiled programs
-              (donate_argnums) — the packing step produces a fresh
-              buffer per call, so a serving loop can hand it to the
-              program and save an allocation on TPU/GPU.  No effect on
-              results; backends that can't reuse the buffer (CPU) just
-              ignore the donation.  Off by default because `run_packed`
-              is public API and donation invalidates the caller's array.
+    donate  : donate the program's input buffer to the compiled programs
+              (donate_argnums) — the packing step or the staging copy
+              produces a fresh buffer per call, so a serving loop can
+              hand it to the program and save an allocation on TPU/GPU.
+              No effect on results; backends that can't reuse the
+              buffer (CPU) just ignore the donation.  Off by default
+              because `run_packed` is public API and donation
+              invalidates the caller's array (as it does a `jax.Array`
+              given to a conv pipeline's `run`, which is not copied).
     image_side : REQUIRED for conv graphs — square input image side
               (`n_in = image_side**2 * image_channels` raw pixels).
               Rejected for pure MLP graphs.
@@ -789,20 +799,14 @@ def compile_pipeline(
                 head.bias_cells),
         )
         weight_bytes = fused_conv.weight_bytes(operands)
-
-        def _pack_conv(x01):
-            img = jnp.asarray(x01).reshape(-1, side, side, channels)
-            words = binarize.pack_bits(enc.encode_image_bits(img))
-            return words.reshape(words.shape[0], -1)
-
-        pack = _pack_conv
+        pack = None  # the vote program encodes the staged pixels
     elif hidden:
         pack, host_pack = binarize.pack_pm1, binarize.pack_pm1_host
     else:
         from repro.core.cam import query_with_bias
 
         pack = functools.partial(query_with_bias, bias_cells=head.bias_cells)
-    pack_fn = jax.jit(_named(pack, "picbnn_pack"))
+    pack_fn = None if pack is None else jax.jit(_named(pack, "picbnn_pack"))
 
     phys = None
     if noise is not None:
@@ -813,8 +817,9 @@ def compile_pipeline(
     donate_kw = {"donate_argnums": (0,)} if donate else {}
 
     if conv_layers:
-        def _hd_xla(x_packed, ops):
-            return fused_conv.net_hd(x_packed, ops, conv_metas, side)
+        def _hd_xla(x01, ops):
+            return fused_conv.net_hd(x01, ops, conv_metas, side, channels,
+                                     enc)
     else:
         def _hd_xla(x_packed, ops):
             return _head_hd_xla(

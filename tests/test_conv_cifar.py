@@ -17,11 +17,11 @@ import numpy as np
 import pytest
 
 from repro.configs.paper_cnn import CIFAR10_CONVNET, deploy_cnn
-from repro.core import binarize, convnet
+from repro.core import convnet
 from repro.core.binarize import InputEncoding
 from repro.core.convnet import CNNConfig, ConvSpec
 from repro.deploy import Deployment
-from repro.kernels import ref
+from repro.kernels import fused_conv, ref
 from repro.serve.picbnn import BatchingPolicy, PicBnnServer
 from repro.spec import InferenceSpec
 
@@ -101,16 +101,19 @@ def test_rgb_thermometer_bit_order():
     np.testing.assert_array_equal(
         np.asarray(enc.encode_image_pm1(px.reshape(1, 1, 1, 3))).reshape(-1),
         2.0 * want - 1)
-    # the pipeline's pack is that order, packed little-endian per pixel
+    # the vote program's input maps are that order, as ±1 int8 per pixel
     cfg = NETS["all-border-2"]
-    dep = deploy_cnn(cfg, convnet.random_folded_cnn(cfg, seed=1))
     img = _images(cfg, 3, seed=2)
-    words = np.asarray(dep.pipeline()._pack_fn(jnp.asarray(img)))
+    maps = np.asarray(fused_conv.encode_input(
+        jnp.asarray(img), cfg.side, cfg.channels, cfg.encoding))
+    assert maps.dtype == np.int8
+    assert maps.shape == (3, cfg.side, cfg.side, 3 * cfg.encoding.width)
     code = np.asarray(cfg.encoding.encode_image_bits(
         img.reshape(3, cfg.side, cfg.side, cfg.channels)))
-    np.testing.assert_array_equal(
-        words.reshape(3, cfg.side, cfg.side, -1),
-        np.asarray(binarize.pack_bits(jnp.asarray(code))))
+    np.testing.assert_array_equal(maps, 2 * code.astype(np.int8) - 1)
+    one = np.asarray(fused_conv.encode_input(
+        jnp.asarray(px.reshape(1, 3)), 1, 3, enc)).reshape(-1)
+    np.testing.assert_array_equal(one, 2 * want - 1)
 
 
 def test_deployment_round_trip_keeps_the_new_fields(tmp_path):

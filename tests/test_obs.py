@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro import obs, pipeline
-from repro.core import bnn, ensemble
+from repro.core import bnn, convnet, ensemble
+from repro.core.binarize import InputEncoding
 from repro.serve.picbnn import BatchingPolicy, PicBnnServer
 from repro.spec import InferenceSpec
 
@@ -81,6 +82,46 @@ def test_run_spans_of_a_device_array_keep_the_device_pack(pipe, tmp_path):
     children = _run_children(pipe, jax.device_put(_rows(13)), tmp_path)
     assert [s[0] for s in children] == [
         "picbnn.pack", "picbnn.pad", "picbnn.vote"]
+
+
+@pytest.fixture(scope="module")
+def conv_pipe():
+    cfg = convnet.CNNConfig(
+        side=4, channels=3, encoding=InputEncoding("thermometer", 2),
+        conv=(convnet.ConvSpec(3, 8, 1, "same", 2),), hidden=(),
+        n_classes=3, bias_cells=32)
+    return pipeline.compile_pipeline(
+        convnet.random_folded_cnn(cfg, seed=5),
+        ensemble.EnsembleConfig(bias_cells=32), image_side=4,
+        image_channels=3, min_bucket=8)
+
+
+@pytest.mark.parametrize("rows", [13, 16], ids=["padded", "full"])
+def test_run_spans_of_a_conv_batch_stage_pixels_and_vote(conv_pipe, rows,
+                                                         tmp_path):
+    # the vote program encodes the staged float32 pixels: no pack program
+    x = np.random.default_rng(rows).random((rows, 48)).astype(np.float32)
+    children = _run_children(conv_pipe, x, tmp_path)
+    names = [s[0] for s in children]
+    want = ["picbnn.stage", "picbnn.vote"]
+    if rows == 13:  # 16-row bucket
+        want.insert(1, "picbnn.pad")
+    assert names == want
+    assert children[0][3]["bytes"] == rows * 48 * 4
+
+
+def test_device_pack_calls_count_each_pack_program_dispatch(pipe):
+    # an MLP batch already on the device takes the pack program; a host
+    # batch is packed on the host and takes none
+    xd, xh = jax.device_put(_rows(16)), _rows(16)
+    jax.block_until_ready(pipe.run(xd, InferenceSpec()))
+    before = obs.counters()
+    for _ in range(3):
+        jax.block_until_ready(pipe.run(xd, InferenceSpec()))
+    assert _delta(before, obs.counters())["pack.device_calls"] == 3
+    before = obs.counters()
+    jax.block_until_ready(pipe.run(xh, InferenceSpec()))
+    assert "pack.device_calls" not in _delta(before, obs.counters())
 
 
 def test_run_packed_opens_its_own_run_span(pipe, tmp_path):

@@ -106,9 +106,7 @@ def _conv_program(cfg, shape, noisy):
     spec = InferenceSpec(noise="batch") if noisy else InferenceSpec()
     prog = pipe.program(spec)
     batch = CIFAR_BATCH if cfg is CIFAR10_CONVNET else BATCH
-    words = jax.eval_shape(pipe._pack_fn, jax.ShapeDtypeStruct(
-        (1, pipe.n_in), jnp.float32)).shape[1]
-    x = shape((batch, words), jnp.uint32)
+    x = shape((batch, pipe.n_in), jnp.float32)  # raw pixels, encoded inside
     ops = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                  pipe.weight_operands)
     args = (x, shape((2,), jnp.uint32)) if noisy else (x,)
