@@ -7,7 +7,7 @@ sweep on synthetic drop-in datasets under three conditions:
   * noiseless compare (TPU semantics / fused kernel),
   * silicon-like PVT noise — the fused physics-threaded pipeline
     (`compile_pipeline(..., noise=SILICON)`), Monte-Carlo over seeds via
-    `cum_votes` at fused speed (the sequential `votes_faithful` loop this
+    the cumulative batch-draw spec at fused speed (the sequential `votes_faithful` loop this
     replaces is timed against it in benchmarks/noise_robustness.py),
   * the hierarchical (strictly binary) input-layer mode.
 
@@ -91,7 +91,7 @@ def run_dataset(name: str, spec, hidden: int, epochs: int, seed: int = 0,
 
     # silicon PVT noise: the SAME fused pipeline with the device physics
     # threaded through (sampled per-pass thresholds), Monte-Carlo over
-    # seeds — per-pass trajectories via cum_votes at fused speed.
+    # seeds — per-pass trajectories via the cumulative spec at fused speed.
     n_mc = 2 if epochs <= 3 else 4
     pipe_si = deploy(folded, ens_cfg=ecfg, noise=SILICON).pipeline()
     acc = {}

@@ -71,12 +71,11 @@ def main():
     # fused packed-domain pipeline (all layers + the 33-threshold vote in
     # one compiled program) compiles lazily per request spec
     dep = deploy(folded, config=cfg, ens_cfg=ecfg)
-    pipe = dep.pipeline()
     t0 = time.time()
     pred = dep.run(jnp.asarray(vxb), InferenceSpec(reduction="argmax"))
     acc = float((pred == jnp.asarray(vy)).mean())
     dt = time.time() - t0
-    print(f"  end-to-end-binary top1 [fused pipeline/{pipe.impl}]: "
+    print(f"  end-to-end-binary top1 [fused pipeline]: "
           f"{acc:.4f}  ({len(vy) / dt / 1e3:.1f}K inf/s incl. compile)")
     # silicon PVT noise: the SAME fused program family, device physics
     # threaded through — a spec field selects the draw, the LLN claim is

@@ -12,7 +12,6 @@ mkdir -p "$(dirname "$OUT")"
 
 python -m pytest -q
 python scripts/check_docs.py
-python scripts/check_deprecated.py
 python -m benchmarks.run --fast --only table2,noise --json "$OUT"
 
 echo "smoke OK -> $OUT"

@@ -89,13 +89,13 @@ def deploy_cnn(cfg: CNNConfig, model, *, noise=None, **kw):
     return deploy(model, config=cfg, noise=noise, **kw)
 
 
-def build_cnn_pipeline(cfg: CNNConfig, folded, *, impl=None, bq=None,
-                       noise=None, **kw):
+def build_cnn_pipeline(cfg: CNNConfig, folded, *, bq=None, noise=None,
+                       **kw):
     """Compile a folded CNN into the fused end-to-end pipeline.
 
     `deploy_cnn(...).pipeline()` in one call — kept as the historical
     one-call deployment path used by benchmarks and tests.
     """
-    opts = {k: v for k, v in dict(impl=impl, bq=bq, **kw).items()
+    opts = {k: v for k, v in dict(bq=bq, **kw).items()
             if v is not None}
     return deploy_cnn(cfg, folded, noise=noise, **opts).pipeline()

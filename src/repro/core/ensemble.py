@@ -223,7 +223,7 @@ def votes_fused_noisy(
     same pass/row draw structure) and bit-identical to `votes_fused` in
     the NOISELESS limit — but vectorized over passes, so Monte-Carlo
     silicon-noise evaluation runs at fused speed (the pipeline's
-    `votes_mc` builds on the same math).
+    Monte-Carlo specs build on the same math).
     """
     q = query_with_bias(x_pm1, head.bias_cells)
     hd = head.cam.search_hd(q).astype(jnp.float32)  # [..., C]
@@ -301,7 +301,7 @@ def sweep_from_votes(votes: jax.Array, n_passes: int) -> jax.Array:
     same exact HD.  Under PVT noise the indicators are independent
     Bernoulli draws and the staircase identity breaks; silicon-noise
     truncated sweeps must use the sampled path
-    (`pipeline.CompiledPipeline.cum_votes`) instead.  Callers feeding a
+    (`InferenceSpec(noise="batch", cumulative=True)`) instead.  Callers feeding a
     noisy vote total here get silently wrong per-pass counts — guard at
     the call site (see benchmarks/accuracy.py).
 

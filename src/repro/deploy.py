@@ -63,7 +63,7 @@ SCHEMA = "picbnn-deployment/v1"
 
 #: compile_pipeline options a Deployment may carry (everything except
 #: the model/physics inputs, which are first-class Deployment fields)
-COMPILE_OPTIONS = ("impl", "bq", "min_bucket", "max_bucket", "donate")
+COMPILE_OPTIONS = ("bq", "min_bucket", "max_bucket", "donate")
 
 
 def _np_unpack_bits(words: np.ndarray, n_bits: int) -> np.ndarray:
@@ -335,10 +335,10 @@ def deploy(
     image_channels : as `pipeline.compile_pipeline`; explicit arguments
         win over config-derived defaults.
     compile_options : forwarded to `compile_pipeline` at (lazy) compile
-        time — one of `deploy.COMPILE_OPTIONS` (impl, bq, min_bucket,
-        max_bucket, donate).  How a kernel runs (compiled on TPU, the
-        Pallas interpreter elsewhere) is the backend's choice, not an
-        option.
+        time — one of `deploy.COMPILE_OPTIONS` (bq, min_bucket,
+        max_bucket, donate).  Whether the Pallas kernel or the XLA twin
+        produces the votes is derived from the backend and the graph
+        (see `repro.pipeline`), not an option.
 
     >>> d = deploy(bnn.fold(params, cfg), config=cfg, noise=SILICON)
     >>> d.run(x, InferenceSpec(noise="per_request"), keys=keys)

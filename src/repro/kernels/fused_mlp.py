@@ -308,7 +308,8 @@ def fused_mlp_votes(
     bq          : batch rows per kernel block (default 128, one vreg of
                   lanes; compiled blocks need a multiple of 128)
     interpret   : run the kernel body through the Pallas interpreter
-                  (the pipeline sets it off-TPU, for semantics only)
+                  (CPU semantics tests only: the pipeline runs the
+                  kernel on the TPU alone)
     thr_samples : optional [P, B, C] float32 noise-sampled per-pass
                   thresholds (from `physics.SearchPhysics.sample`);
                   replaces `thresholds` in the head compare — the

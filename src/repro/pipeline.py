@@ -9,14 +9,10 @@ batch classifier driven by a declarative request spec
     votes = pipe.run(x_pm1, InferenceSpec())              # [B, C] int32
     pred  = pipe.run(x_pm1, InferenceSpec(reduction="argmax"))  # [B]
 
-`run(x, spec, key=..., keys=...)` is the ONE entry point: it compiles
-and caches exactly one fused program per distinct spec, and centralizes
-the batch bucketing, pad/trim, and PRNG-key shape logic that the legacy
-eight-method family (`votes`, `votes_each`, `votes_mc`, `votes_mc_each`,
-`votes_mc_each_sum`, `cum_votes`, `predict`, `predict_each`) used to
-copy-paste.  Those methods remain as thin deprecated shims over `run()`
-for one release — bit-exact equal by construction (each shim just names
-a spec) and proven so by the pre-redesign oracle tests.
+`run(x, spec, key=..., keys=...)` is the one way in (`run_packed` for
+an input already in its program's form): it compiles and caches exactly
+one fused program per distinct spec, and holds the batch bucketing,
+pad/trim, and PRNG-key shape logic for every spec.
 
 Semantics are bit-exact equal to the digital oracle
 (`bnn.folded_forward_exact` hidden layers + `ensemble.votes_fused` head);
@@ -42,31 +38,23 @@ Hamming-distance computation; `cumulative=True` exposes the per-pass
 cumulative votes [P, B, C] that noisy Fig.-5-style truncated sweeps need
 (`ensemble.sweep_from_votes` is noiseless-only — see its docstring).
 `InferenceSpec(noise="off", cumulative=True)` is the exact noiseless
-staircase, valid on ANY pipeline — the explicit form of what `cum_votes`
-used to do by silently substituting `PRNGKey(0)`.  With
-`noise=NOISELESS` every noisy spec is bit-identical to the noiseless
-oracle (tested).
+staircase, valid on ANY pipeline.  With `noise=NOISELESS` every noisy
+spec is bit-identical to the noiseless oracle (tested).
 
-Two fused implementations, selected by `impl` (default: by backend):
-
-  pallas — kernels/fused_mlp.py: one kernel launch per batch block,
-           hidden activations resident in VMEM (the TPU deployment path).
-           The backend alone decides how it runs: compiled by Mosaic on
-           TPU, through the Pallas interpreter elsewhere (semantics
-           tests only).  The noisy path feeds the kernel a precomputed
-           [P, B, C] threshold-sample operand — randomness never enters
-           the kernel.
-  xla    — the same packed-domain math as a single jitted XLA program:
-           activations stay uint32-packed between layers and the whole
-           net fuses into one executable (the portable fast path — on
-           CPU this is what beats the layer-by-layer unpacked flow).  The
-           noisy path broadcasts the sampled [P, B, C] thresholds against
-           the one HD computation.
-
-Monte-Carlo, cumulative, and per-request specs always use the XLA-twin
-math (per-pass/per-sample outputs do not fit the kernel's single [B, C]
-result block); the twins are bit-exact equal so this is a pure
-scheduling choice.
+Two producers of the Hamming distances and votes, and one rule between
+them: the Pallas kernel (`kernels/fused_mlp.py`: one launch per batch
+block, hidden activations resident in VMEM) produces the votes of the
+noise-off and batch-noise single-realization specs of a graph with no
+conv layers when `jax.default_backend() == "tpu"`.  Every other case
+runs the XLA twin: the same packed-domain math as one jitted program
+(`_head_hd_xla`; `fused_conv.net_hd` for conv graphs), whose noisy path
+broadcasts the sampled [P, B, C] thresholds against the one HD
+computation.  Monte-Carlo, cumulative and per-request outputs do not fit
+the kernel's single [B, C] result block.  The kernel is fed a
+precomputed [P, B, C] threshold-sample operand, so randomness never
+enters it, and the twins are bit-exact equal: the choice is scheduling,
+not semantics.  The pipeline never runs the kernel off the TPU; its CPU
+coverage is direct (tests/test_fused_mlp.py, interpret mode).
 
 Convolutional graphs: `folded` may start with a prefix of
 `convnet.FoldedConvLayer` (a deployed end-to-end-binary CNN, e.g.
@@ -120,7 +108,6 @@ import dataclasses
 import functools
 import itertools
 import time
-import warnings
 from typing import Callable, Iterator, Optional, Sequence
 
 import jax
@@ -135,7 +122,7 @@ from repro.core.device_model import NoiseModel
 from repro.core.ensemble import CAMEnsembleHead, EnsembleConfig, build_head
 from repro.core.physics import SearchPhysics
 from repro.kernels import fused_conv, fused_mlp
-from repro.spec import InferenceSpec, legacy_entry_spec
+from repro.spec import InferenceSpec
 
 
 def next_bucket(n: int, min_bucket: int = 64,
@@ -202,23 +189,6 @@ def _head_hd_xla(x_packed, layer_ws, layer_cs, layer_n_bits, head_rows,
     return binarize.hamming_packed(q[:, None, :], head_rows)
 
 
-_LEGACY_WARNED: set = set()
-
-
-def _warn_legacy(name: str) -> None:
-    """One DeprecationWarning per legacy entry point per process."""
-    if name in _LEGACY_WARNED:
-        return
-    _LEGACY_WARNED.add(name)
-    warnings.warn(
-        f"CompiledPipeline.{name}() is a deprecated shim over "
-        f"run(x, InferenceSpec(...)) — see repro.spec.legacy_entry_spec "
-        "and the README migration table; it will be removed next release",
-        DeprecationWarning,
-        stacklevel=3,
-    )
-
-
 @dataclasses.dataclass
 class CompiledPipeline:
     """A jitted end-to-end batch classifier for one deployed BNN.
@@ -227,14 +197,12 @@ class CompiledPipeline:
     spec)`: one fused XLA program is compiled and cached per distinct
     `InferenceSpec` (`program(spec)` is the cache), and all bucketing /
     padding / result trimming / PRNG-key validation lives in `run_packed`
-    — once, for every spec.  The legacy method family survives as
-    deprecated shims that name their spec.
+    — once, for every spec.
     """
 
     head: CAMEnsembleHead
     n_in: int
     n_classes: int
-    impl: str
     min_bucket: int
     head_only: bool  # no hidden layers: input feeds the CAM head directly
     physics: Optional[SearchPhysics]  # None <=> compiled without noise=
@@ -424,11 +392,6 @@ class CompiledPipeline:
     # ------------------------------------------------------------------
     # spec-driven warmup
     # ------------------------------------------------------------------
-    #: legacy entry names accepted by warmup(entries=) (deprecated —
-    #: pass specs= instead; see repro.spec.legacy_entry_spec)
-    WARMUP_ENTRIES = ("votes", "votes_noisy", "votes_each", "votes_mc",
-                      "votes_mc_each", "votes_mc_each_sum")
-
     def default_warmup_specs(
         self, mc_samples: Optional[int] = None
     ) -> tuple[InferenceSpec, ...]:
@@ -459,8 +422,7 @@ class CompiledPipeline:
     def warmup(self, max_batch: int, *,
                specs: Optional[Sequence[InferenceSpec]] = None,
                key: Optional[jax.Array] = None,
-               mc_samples: Optional[int] = None, device=None,
-               entries: Optional[Sequence[str]] = None
+               mc_samples: Optional[int] = None, device=None
                ) -> dict[tuple[InferenceSpec, int], float]:
         """Precompile every (spec, bucket) program a serving loop needs.
 
@@ -472,9 +434,6 @@ class CompiledPipeline:
             `default_warmup_specs(mc_samples)`.  A serving loop passes
             exactly its dispatch spec(s) — startup time is
             specs x buckets x devices XLA compiles.
-        entries : DEPRECATED legacy entry names (translated through
-            `repro.spec.legacy_entry_spec`); mutually exclusive with
-            specs.
         device  : commits the dummy operands — a device for round-robin
             fan-out, or a `jax.sharding.Sharding` for SPMD fan-out (jit
             caches key on input sharding, so warming with a different
@@ -487,19 +446,6 @@ class CompiledPipeline:
         dominated by compile time on first call, ~free when the program
         cache already holds the (spec, bucket) variant.
         """
-        if entries is not None:
-            if specs is not None:
-                raise ValueError("pass specs= or legacy entries=, not both")
-            _warn_legacy("warmup(entries=)")
-            unknown = set(entries) - set(self.WARMUP_ENTRIES)
-            if unknown:
-                raise ValueError(f"unknown warmup entries {sorted(unknown)}")
-            specs = tuple(
-                legacy_entry_spec(
-                    e, mc_samples if e.startswith("votes_mc") else None
-                )
-                for e in entries
-            )
         if specs is None:
             specs = self.default_warmup_specs(mc_samples)
         for spec in specs:  # capability check before any compile work
@@ -535,138 +481,11 @@ class CompiledPipeline:
                 times[(spec, b)] = time.perf_counter() - t0
         return times
 
-    # ------------------------------------------------------------------
-    # DEPRECATED legacy entry points — thin shims over run()
-    # ------------------------------------------------------------------
-    def votes(self, x_pm1: jax.Array, key: Optional[jax.Array] = None):
-        """DEPRECATED shim: `run(x, InferenceSpec())`, or with `key` one
-        batch-level silicon draw (`InferenceSpec(noise="batch")`).
-
-        Input domain: ±1 activations for MLP pipelines; RAW [0,1] pixels
-        for conv pipelines (n_in = image_side**2 * image_channels — the
-        vote program runs the binary input encoding).
-        With the NOISELESS model the keyed path is bit-identical to the
-        noiseless one.
-        """
-        _warn_legacy("votes")
-        if key is None:
-            return self.run(x_pm1, InferenceSpec())
-        return self.run(x_pm1, InferenceSpec(noise="batch"), key=key)
-
-    def votes_packed(self, x_packed: jax.Array,
-                     key: Optional[jax.Array] = None) -> jax.Array:
-        """DEPRECATED shim: `run_packed` with the `votes` specs."""
-        _warn_legacy("votes_packed")
-        if key is None:
-            return self.run_packed(x_packed, InferenceSpec())
-        return self.run_packed(x_packed, InferenceSpec(noise="batch"),
-                               key=key)
-
-    def votes_mc(self, x_pm1: jax.Array, key: jax.Array,
-                 n_samples: int) -> jax.Array:
-        """DEPRECATED shim: `InferenceSpec(noise="batch", mc_samples=S)`
-        -> [S, B, C] Monte-Carlo silicon votes (HD computed ONCE)."""
-        _warn_legacy("votes_mc")
-        return self.run(
-            x_pm1,
-            InferenceSpec(noise="batch", mc_samples=int(n_samples)),
-            key=key,
-        )
-
-    def votes_each(self, x_pm1: jax.Array, keys: jax.Array) -> jax.Array:
-        """DEPRECATED shim: `InferenceSpec(noise="per_request")` — one
-        batch_shape=() draw per row; invariant to batch composition (the
-        serving determinism contract; see repro/spec.py)."""
-        _warn_legacy("votes_each")
-        return self.run(x_pm1, InferenceSpec(noise="per_request"),
-                        keys=keys)
-
-    def votes_mc_each(self, x_pm1: jax.Array, keys: jax.Array,
-                      n_samples: int) -> jax.Array:
-        """DEPRECATED shim: `InferenceSpec(noise="per_request",
-        mc_samples=S)` -> [S, B, C]; sample s of request i is drawn from
-        split(keys[i], S)[s], so results are batching-invariant."""
-        _warn_legacy("votes_mc_each")
-        return self.run(
-            x_pm1,
-            InferenceSpec(noise="per_request", mc_samples=int(n_samples)),
-            keys=keys,
-        )
-
-    def votes_mc_each_sum(self, x_pm1: jax.Array, keys: jax.Array,
-                          n_samples: int) -> jax.Array:
-        """DEPRECATED shim: the per-request MC spec with
-        reduction="sum" — the MC serving aggregate, reduction fused into
-        the compiled program."""
-        _warn_legacy("votes_mc_each_sum")
-        return self.run(
-            x_pm1,
-            InferenceSpec(noise="per_request", mc_samples=int(n_samples),
-                          reduction="sum"),
-            keys=keys,
-        )
-
-    def predict_each(self, x_pm1: jax.Array, keys: jax.Array) -> jax.Array:
-        """DEPRECATED shim: `InferenceSpec(noise="per_request",
-        reduction="argmax")` — per-request-key Algorithm 1 prediction."""
-        _warn_legacy("predict_each")
-        return self.run(
-            x_pm1,
-            InferenceSpec(noise="per_request", reduction="argmax"),
-            keys=keys,
-        )
-
-    def cum_votes(self, x_pm1: jax.Array,
-                  key: Optional[jax.Array] = None) -> jax.Array:
-        """DEPRECATED shim: per-pass cumulative votes [P, B, C].
-
-        key given  -> `InferenceSpec(noise="batch", cumulative=True)`:
-            one silicon realization's staircase (the silicon-conditioned
-            replacement for `ensemble.sweep_from_votes`, which is valid
-            ONLY noiseless).
-        key=None   -> `InferenceSpec(cumulative=True)`: the exact
-            noiseless staircase (== sweep_from_votes of the fused
-            total).  This used to silently substitute `PRNGKey(0)`; it
-            is now an explicit deterministic spec, valid on any
-            pipeline.  A noise-compiled pipeline must still be given a
-            key explicitly — each call is one silicon realization.
-        """
-        _warn_legacy("cum_votes")
-        if key is None:
-            if self.physics is not None and not self.physics.is_noiseless:
-                raise ValueError(
-                    "cum_votes on a noise-compiled pipeline needs an "
-                    "explicit key (each call is one silicon realization); "
-                    "for the deterministic staircase run the explicit "
-                    'spec InferenceSpec(noise="off", cumulative=True) on '
-                    "a noiseless pipeline"
-                )
-            return self.run(x_pm1, InferenceSpec(cumulative=True))
-        return self.run(x_pm1, InferenceSpec(noise="batch", cumulative=True),
-                        key=key)
-
-    def predict(self, x_pm1: jax.Array,
-                key: Optional[jax.Array] = None) -> jax.Array:
-        """DEPRECATED shim: `InferenceSpec(reduction="argmax")` —
-        Algorithm 1 prediction (per-class majority vote -> argmax)."""
-        _warn_legacy("predict")
-        if key is None:
-            return self.run(x_pm1, InferenceSpec(reduction="argmax"))
-        return self.run(
-            x_pm1, InferenceSpec(noise="batch", reduction="argmax"), key=key
-        )
-
-    def __call__(self, x_pm1: jax.Array,
-                 key: Optional[jax.Array] = None) -> jax.Array:
-        """Sugar for the deprecated `predict` shim."""
-        return self.predict(x_pm1, key)
-
 
 def compile_pipeline(
     folded: Sequence,
     ens_cfg: EnsembleConfig | None = None,
     *,
-    impl: str | None = None,
     bq: int | None = None,
     min_bucket: int = 64,
     max_bucket: int | None = None,
@@ -686,15 +505,11 @@ def compile_pipeline(
               RAW [0,1] pixels [B, image_side**2 * image_channels] (the
               binary input encoding runs inside the vote program).
     ens_cfg : Algorithm-1 config (thresholds / bias cells); default paper's.
-    impl    : "pallas" | "xla" | None (auto: pallas on TPU, xla elsewhere).
-              The backend alone decides how the kernel runs: compiled by
-              Mosaic on TPU, through the Pallas interpreter elsewhere
-              (semantics tests, not speed).  Conv graphs run one MXU
-              program under either impl.
     bq      : Pallas batch rows per kernel block; default 128, one vreg
               of lanes (the batch is the kernels' lane axis — DESIGN.md
               §4 and §10 derive the VMEM budgets).  Compiled blocks need
-              a multiple of 128; interpret mode takes any size.
+              a multiple of 128.  Read only where the kernel runs (see
+              the module doc for the one rule that decides).
     noise   : optional NoiseModel — enables the silicon-mode specs
               (noise="batch"/"per_request", Monte-Carlo, noisy
               cumulative) by building a SearchPhysics bundle from the
@@ -731,11 +546,6 @@ def compile_pipeline(
     ens_cfg = ens_cfg or EnsembleConfig()
     if len(folded) < 1:
         raise ValueError("need at least the output layer")
-    if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl not in ("pallas", "xla"):
-        raise ValueError(f"unknown pipeline impl {impl!r}")
-    interpret = jax.default_backend() != "tpu"
 
     rest = list(folded)
     conv_layers: list[FoldedConvLayer] = []
@@ -827,14 +637,16 @@ def compile_pipeline(
                 head.bias_cells,
             )
 
-    # the kernel-eligible vote producer (single [B, C] result block)
-    if impl == "pallas" and not conv_layers:
+    # the one kernel-or-twin rule: the Pallas kernel produces the single
+    # [B, C] result block of an MLP's noise-off and batch-noise votes on
+    # the TPU; every other spec, graph and backend runs the XLA twin
+    if jax.default_backend() == "tpu" and not conv_layers:
         def _kernel_votes(x_packed, thr_samples=None):
             return fused_mlp.fused_mlp_votes(
                 x_packed, layer_ws, layer_cs, layer_n_bits,
                 head_rows, thresholds,
                 bias_cells=head.bias_cells, bq=bq,
-                interpret=interpret, thr_samples=thr_samples,
+                thr_samples=thr_samples,
             )
     else:
         _kernel_votes = None
@@ -926,7 +738,7 @@ def compile_pipeline(
 
                     return jnp.moveaxis(
                         jax.vmap(per_req)(hd, keys), 1, 0
-                    )  # [S, B, C] (votes_mc layout)
+                    )  # [S, B, C], as the batch-draw MC spec
 
         if spec.reduction == "argmax":
             base = fn  # single-realization vote producer, [B, C]
@@ -949,7 +761,6 @@ def compile_pipeline(
         head=head,
         n_in=n_in,
         n_classes=n_classes,
-        impl=impl,
         min_bucket=min_bucket,
         head_only=not hidden,
         physics=phys,

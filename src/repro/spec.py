@@ -1,15 +1,11 @@
 """Declarative inference request spec for the compiled PiC-BNN pipeline.
 
 The paper's deployment contract is ONE search primitive — Algorithm 1
-with knob-configured noise — yet the pipeline API had grown an eight-way
-method family (`votes`, `votes(key=)`, `votes_each`, `votes_mc`,
-`votes_mc_each`, `votes_mc_each_sum`, `cum_votes`, `predict*`), each
-re-implementing the same bucket/pad/trim/key glue.  :class:`InferenceSpec`
-replaces that family with a value: *what to run* is data, and
-`CompiledPipeline.run(x, spec, ...)` compiles-and-caches exactly one
-fused program per distinct spec.
+with knob-configured noise.  :class:`InferenceSpec` makes *what to run*
+a value, and `CompiledPipeline.run(x, spec, ...)` compiles-and-caches
+exactly one fused program per distinct spec.
 
-The four axes (and how the legacy family maps onto them):
+The four axes:
 
     noise      — "off":        deterministic compare (no key accepted)
                  "batch":      ONE silicon draw for the whole batch
@@ -30,12 +26,10 @@ The four axes (and how the legacy family maps onto them):
                            specs only)
     cumulative — per-pass cumulative votes [P, B, C] under one draw
                  (`noise="batch"`), or the exact noiseless staircase
-                 (`noise="off"` — the explicit, documented form of what
-                 `cum_votes` used to do by silently substituting
-                 `PRNGKey(0)` on noiseless pipelines)
+                 (`noise="off"`, valid on any pipeline)
 
 Every future axis (a new noise mode, a new reduction, a new workload)
-is a spec field — not a ninth method.
+is a spec field, not a new method.
 
 Output shapes (B = logical batch, C = classes, P = passes, S = samples):
 
@@ -147,8 +141,7 @@ class InferenceSpec:
 
         0 for [B, C] / [B] outputs; 1 when a samples or passes axis
         leads ([S, B, C] Monte-Carlo, [P, B, C] cumulative).  This is
-        what lets `run()` centralize the bucket-padding trim for every
-        spec instead of each legacy method hand-rolling it.
+        what lets `run()` hold the bucket-padding trim for every spec.
         """
         if self.cumulative:
             return 1
@@ -182,60 +175,5 @@ class InferenceSpec:
         return "_".join(parts)
 
 
-#: common request shapes, by name (also the shims' targets)
+#: the plain vote request, by name
 VOTES = InferenceSpec()
-PREDICT = InferenceSpec(reduction="argmax")
-CUM_VOTES = InferenceSpec(cumulative=True)
-
-
-def legacy_entry_spec(name: str,
-                      mc_samples: Optional[int] = None) -> InferenceSpec:
-    """The `InferenceSpec` equivalent of a legacy entry-point name.
-
-    The eight-method family collapses onto the spec axes as follows
-    (`predict`/`predict_each` are the argmax reductions of `votes` /
-    `votes_each`):
-
-        votes             -> InferenceSpec()
-        votes_noisy       -> InferenceSpec(noise="batch")        # votes(key=)
-        votes_each        -> InferenceSpec(noise="per_request")
-        votes_mc          -> InferenceSpec(noise="batch", mc_samples=S)
-        votes_mc_each     -> InferenceSpec(noise="per_request", mc_samples=S)
-        votes_mc_each_sum -> ... mc_samples=S, reduction="sum"
-        cum_votes         -> InferenceSpec(noise="batch", cumulative=True)
-        predict           -> InferenceSpec(reduction="argmax")
-        predict_each      -> InferenceSpec(noise="per_request",
-                                           reduction="argmax")
-
-    `mc_samples` is required for the `votes_mc*` names and rejected
-    otherwise.  Used by the deprecated warmup `entries=` translation and
-    documented as the migration table in README.md.
-    """
-    table = {
-        "votes": dict(),
-        "votes_noisy": dict(noise="batch"),
-        "votes_each": dict(noise="per_request"),
-        "votes_mc": dict(noise="batch", mc=True),
-        "votes_mc_each": dict(noise="per_request", mc=True),
-        "votes_mc_each_sum": dict(noise="per_request", mc=True,
-                                  reduction="sum"),
-        "cum_votes": dict(noise="batch", cumulative=True),
-        "predict": dict(reduction="argmax"),
-        "predict_each": dict(noise="per_request", reduction="argmax"),
-    }
-    entry = table.get(name)
-    if entry is None:
-        raise ValueError(
-            f"unknown legacy entry {name!r}; known: {sorted(table)}"
-        )
-    wants_mc = entry.pop("mc", False)
-    if wants_mc and mc_samples is None:
-        raise ValueError(f"legacy entry {name!r} needs mc_samples=")
-    if not wants_mc and mc_samples is not None:
-        raise ValueError(f"legacy entry {name!r} takes no mc_samples")
-    return InferenceSpec(
-        noise=entry.get("noise", "off"),
-        mc_samples=mc_samples if wants_mc else None,
-        reduction=entry.get("reduction", "none"),
-        cumulative=entry.get("cumulative", False),
-    )

@@ -2,7 +2,7 @@
 
 Without a TPU it exits non-zero before any work and prints no result
 line; and it refuses a pipeline whose program would run the XLA twin
-or the Pallas interpreter where the Mosaic kernel was expected.
+where the Mosaic kernel was expected.
 """
 
 import importlib.util
@@ -11,11 +11,13 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
 from repro.configs.paper_mlp import MNIST_MLP, deploy_mlp
 from repro.core import bnn
+from repro.core.device_model import SILICON
 from repro.spec import InferenceSpec
 
 SCRIPT = Path(__file__).resolve().parents[1] / "chip_smoke.py"
@@ -39,13 +41,14 @@ def test_exits_nonzero_without_tpu():
     assert '"ok"' not in proc.stdout
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_refuses_program_without_mosaic_kernel(impl):
-    """On the CPU the XLA twin has no kernel, and the interpreted kernel
-    lowers to plain XLA: both are refused."""
+@pytest.mark.parametrize("noise", ["off", "batch"])
+def test_refuses_program_without_mosaic_kernel(noise):
+    """On the CPU both the noise-off and the batch-noise programs of a
+    silicon MLP run the XLA twin, which has no kernel: both are refused."""
     smoke = _load_script()
     folded = bnn.random_folded(MNIST_MLP, seed=0)
-    pipe = deploy_mlp(MNIST_MLP, folded, impl=impl).pipeline()
+    pipe = deploy_mlp(MNIST_MLP, folded, noise=SILICON).pipeline()
     x = np.ones((64, MNIST_MLP.layer_sizes[0]), np.float32)
-    with pytest.raises(SystemExit):
-        smoke._assert_kernel(pipe, InferenceSpec(), x)
+    keys = {"key": jax.random.PRNGKey(0)} if noise == "batch" else {}
+    with pytest.raises(SystemExit, match="without a Mosaic kernel"):
+        smoke._assert_kernel(pipe, InferenceSpec(noise=noise), x, **keys)

@@ -1,11 +1,13 @@
 """End-to-end-binary CNN workload: bit-exactness vs the unpacked oracle.
 
 The correctness bar for kernels/fused_conv.py and the conv path of
-repro/pipeline.py: the deployed flow (one int8 program under either
-impl) must be bit-identical to `kernels.ref.conv_votes_ref` — the ±1 float oracle that
-encodes raw pixels through the binary input layer, runs every conv/FC
-layer as sign(dot + C), and votes the head — across multiple input
-sizes, strides, channel alignments, and the silicon-mode entry points.
+repro/pipeline.py: the deployed flow (one int8 program on every
+backend) must be bit-identical to `kernels.ref.conv_votes_ref` — the ±1
+float oracle that encodes raw pixels through the binary input layer,
+runs every conv/FC layer as sign(dot + C), and votes the head — across
+multiple input sizes, strides, channel alignments, both input forms (a
+host batch is staged, a device batch passes through), and the
+silicon-mode specs.
 """
 
 import jax
@@ -18,6 +20,7 @@ from repro.core.binarize import InputEncoding
 from repro.core.convnet import CNNConfig, ConvSpec
 from repro.core.device_model import NOISELESS, SILICON
 from repro.kernels import ref
+from repro.spec import InferenceSpec
 
 # Two input sizes (the acceptance bar asks for >= 2), plus a config with
 # channel counts that fill no whole word, and a conv->head-direct net
@@ -57,61 +60,86 @@ def _oracle(cfg, folded, head, x):
     )
 
 
+#: how a caller hands a batch to `run`: a host NumPy array, or a
+#: `jax.Array` already committed to the device
+INPUTS = ["host", "device"]
+
+
+def _as_input(x, form):
+    return x if form == "host" else jax.device_put(x, jax.devices()[0])
+
+
+def _votes(pipe, x, key=None):
+    """The noise-off votes, or one batch-level draw under `key`."""
+    if key is None:
+        return np.asarray(pipe.run(x, InferenceSpec()))
+    return np.asarray(pipe.run(x, InferenceSpec(noise="batch"), key=key))
+
+
 @pytest.mark.parametrize("name", sorted(CONFIGS))
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_conv_pipeline_bit_exact_vs_oracle(name, impl):
+@pytest.mark.parametrize("form", INPUTS)
+def test_conv_pipeline_bit_exact_vs_oracle(name, form):
     cfg = CONFIGS[name]
     folded = convnet.random_folded_cnn(cfg, seed=sum(map(ord, name)))
-    pipe = build_cnn_pipeline(cfg, folded, impl=impl, bq=4)
-    x = _images(cfg, 6 if cfg.side >= 64 else 11)
-    want = _oracle(cfg, folded, pipe.head, x)
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x)), want)
+    pipe = build_cnn_pipeline(cfg, folded)
+    x_np = _images(cfg, 6 if cfg.side >= 64 else 11)
+    x = _as_input(x_np, form)
+    want = _oracle(cfg, folded, pipe.head, x_np)
+    np.testing.assert_array_equal(_votes(pipe, x), want)
     np.testing.assert_array_equal(
-        np.asarray(pipe.predict(x)), want.argmax(-1)
+        np.asarray(pipe.run(x, InferenceSpec(reduction="argmax"))),
+        want.argmax(-1),
     )
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_conv_noiseless_limit_bit_exact(impl):
-    """sigma -> 0: every silicon entry point equals the oracle."""
+@pytest.mark.parametrize("form", INPUTS)
+def test_conv_noiseless_limit_bit_exact(form):
+    """sigma -> 0: every silicon spec equals the oracle."""
     cfg = CONFIGS["unaligned-12"]
     folded = convnet.random_folded_cnn(cfg, seed=3)
-    pipe = build_cnn_pipeline(cfg, folded, impl=impl, bq=4, noise=NOISELESS)
-    x = _images(cfg, 9, seed=2)
-    want = _oracle(cfg, folded, pipe.head, x)
+    pipe = build_cnn_pipeline(cfg, folded, noise=NOISELESS)
+    x_np = _images(cfg, 9, seed=2)
+    x = _as_input(x_np, form)
+    want = _oracle(cfg, folded, pipe.head, x_np)
     key = jax.random.PRNGKey(7)
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x, key)), want)
-    mc = np.asarray(pipe.votes_mc(x, key, 3))
+    np.testing.assert_array_equal(_votes(pipe, x, key), want)
+    mc = np.asarray(pipe.run(x, InferenceSpec(noise="batch", mc_samples=3),
+                             key=key))
     np.testing.assert_array_equal(mc, np.broadcast_to(want, mc.shape))
-    cum = np.asarray(pipe.cum_votes(x, key))
+    cum = np.asarray(pipe.run(x, InferenceSpec(noise="batch",
+                                               cumulative=True), key=key))
     np.testing.assert_array_equal(cum[-1], want)
     keys = jax.random.split(key, x.shape[0])
-    np.testing.assert_array_equal(np.asarray(pipe.votes_each(x, keys)), want)
+    np.testing.assert_array_equal(
+        np.asarray(pipe.run(x, InferenceSpec(noise="per_request"),
+                            keys=keys)), want)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_conv_silicon_impls_agree(impl):
-    """Same key => both impls draw identical silicon votes (sampling
-    happens outside the kernel), and the draw actually perturbs."""
+@pytest.mark.parametrize("form", INPUTS)
+def test_conv_silicon_impls_agree(form):
+    """Same key => the batch-draw votes equal the last pass of the
+    cumulative batch-draw spec (sampling happens outside the vote
+    producer), and the draw actually perturbs."""
     cfg = CONFIGS["unaligned-12"]
     folded = convnet.random_folded_cnn(cfg, seed=5)
-    pipe = build_cnn_pipeline(cfg, folded, impl=impl, bq=8, noise=SILICON)
-    x = _images(cfg, 64, seed=3)  # batch == bucket: shared sample shapes
+    pipe = build_cnn_pipeline(cfg, folded, noise=SILICON)
+    # batch == bucket: shared sample shapes
+    x = _as_input(_images(cfg, 64, seed=3), form)
     key = jax.random.PRNGKey(5)
-    got = np.asarray(pipe.votes(x, key))
-    assert (got != np.asarray(pipe.votes(x))).any()
-    ref_pipe = build_cnn_pipeline(cfg, folded, impl="xla", noise=SILICON)
-    np.testing.assert_array_equal(got, np.asarray(ref_pipe.votes(x, key)))
+    got = _votes(pipe, x, key)
+    assert (got != _votes(pipe, x)).any()
+    cum = pipe.run(x, InferenceSpec(noise="batch", cumulative=True), key=key)
+    np.testing.assert_array_equal(got, np.asarray(cum)[-1])
 
 
 def test_conv_batch_bucketing_invariance():
     cfg = CONFIGS["unaligned-12"]
     folded = convnet.random_folded_cnn(cfg, seed=9)
-    pipe = build_cnn_pipeline(cfg, folded, impl="xla", min_bucket=8)
+    pipe = build_cnn_pipeline(cfg, folded, min_bucket=8)
     x = _images(cfg, 21, seed=4)
-    full = np.asarray(pipe.votes(x))
+    full = _votes(pipe, x)
     for b in (1, 7, 8, 9, 21):
-        np.testing.assert_array_equal(np.asarray(pipe.votes(x[:b])), full[:b])
+        np.testing.assert_array_equal(_votes(pipe, x[:b]), full[:b])
 
 
 def test_fold_cnn_smoke_trained_shapes_and_parity():
@@ -135,10 +163,10 @@ def test_fold_cnn_smoke_trained_shapes_and_parity():
                   else layer.n_in)
         assert ((layer.c + n_bits) % 2 == 1).all()
         assert (np.abs(layer.c) <= cfg.bias_cells).all()
-    pipe = build_cnn_pipeline(cfg, folded, impl="xla")
+    pipe = build_cnn_pipeline(cfg, folded)
     x = _images(cfg, 5, seed=6)
     np.testing.assert_array_equal(
-        np.asarray(pipe.votes(x)), _oracle(cfg, folded, pipe.head, x)
+        _votes(pipe, x), _oracle(cfg, folded, pipe.head, x)
     )
 
 
@@ -190,7 +218,7 @@ def test_compile_pipeline_conv_validation():
     folded = convnet.random_folded_cnn(odd, seed=2)
     pipe = build_cnn_pipeline(odd, folded)
     x = _images(odd, 5, seed=7)
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x)),
+    np.testing.assert_array_equal(_votes(pipe, x),
                                   _oracle(odd, folded, pipe.head, x))
     other_head = convnet.random_folded_cnn(CONFIGS["head-direct-10"])[-1]
     with pytest.raises(ValueError, match="flattened conv"):
@@ -218,14 +246,15 @@ def test_conv_served_bit_exact():
 
     cfg = CONFIGS["unaligned-12"]
     folded = convnet.random_folded_cnn(cfg, seed=11)
-    pipe = build_cnn_pipeline(cfg, folded, impl="xla", min_bucket=8,
-                              max_bucket=32)
-    pipe_si = build_cnn_pipeline(cfg, folded, impl="xla", min_bucket=8,
-                                 max_bucket=32, noise=SILICON)
+    pipe = build_cnn_pipeline(cfg, folded, min_bucket=8, max_bucket=32)
+    pipe_si = build_cnn_pipeline(cfg, folded, min_bucket=8, max_bucket=32,
+                                 noise=SILICON)
     x = _images(cfg, 24, seed=8)
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(3), 24))
-    direct = np.asarray(pipe.predict(x))
-    direct_si = np.asarray(pipe_si.predict_each(x, keys))
+    direct = np.asarray(pipe.run(x, InferenceSpec(reduction="argmax")))
+    direct_si = np.asarray(pipe_si.run(
+        x, InferenceSpec(noise="per_request", reduction="argmax"),
+        keys=keys))
     srv = PicBnnServer(BatchingPolicy(max_batch=32, max_wait_us=200))
     srv.register("cnn", pipe)
     srv.register("cnn-si", pipe_si)

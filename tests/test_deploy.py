@@ -63,7 +63,7 @@ def _mlp_deployment(bank, noise=None, **opts):
     folded = _random_folded(sizes, seed=sum(map(ord, bank)), bias_cells=bias)
     return deploy(
         folded, ens_cfg=ensemble.EnsembleConfig(bias_cells=bias),
-        noise=noise, impl="xla", min_bucket=8, **opts
+        noise=noise, min_bucket=8, **opts
     ), sizes
 
 
@@ -82,12 +82,11 @@ def test_deploy_from_folded_and_layer_sizes():
 def test_deploy_from_trained_params_folds_here():
     cfg = bnn.MLPConfig(layer_sizes=(64, 32, 4), bias_cells=32)
     params = bnn.init_params(jax.random.PRNGKey(0), cfg)
-    dep = deploy(params, config=cfg, impl="xla", min_bucket=8)
+    dep = deploy(params, config=cfg, min_bucket=8)
     # config supplies the ensemble bias cells; fold ran inside deploy()
     assert dep.ens_cfg.bias_cells == 32
     assert dep.layer_sizes == (64, 32, 4)
-    want = deploy(bnn.fold(params, cfg), config=cfg, impl="xla",
-                  min_bucket=8)
+    want = deploy(bnn.fold(params, cfg), config=cfg, min_bucket=8)
     x = np.random.default_rng(1).choice([-1.0, 1.0], (5, 64)).astype(
         np.float32)
     np.testing.assert_array_equal(
@@ -97,7 +96,7 @@ def test_deploy_from_trained_params_folds_here():
 
 def test_deploy_cnn_config_threads_geometry():
     folded = convnet.random_folded_cnn(TINY_CNN, seed=3)
-    dep = deploy(folded, config=TINY_CNN, impl="xla", min_bucket=4)
+    dep = deploy(folded, config=TINY_CNN, min_bucket=4)
     assert dep.image_side == TINY_CNN.side
     assert dep.image_encoding == TINY_CNN.encoding
     assert dep.layer_sizes is None  # conv graphs have no MLP topology
@@ -112,13 +111,15 @@ def test_deploy_rejects_unknown_options_and_dict_without_config():
         deploy(folded, block_size=4)
     with pytest.raises(ValueError, match="config="):
         deploy({"layers": []})
-    assert "impl" in COMPILE_OPTIONS
+    assert "impl" not in COMPILE_OPTIONS
 
 
-@pytest.mark.parametrize("option", ["interpret", "chunk"])
+@pytest.mark.parametrize("option", ["interpret", "chunk", "impl"])
 def test_deploy_rejects_backend_decided_options(option):
-    """How a kernel runs is the backend's choice: a manifest cannot carry
-    interpret mode (or the old word-chunk tile) onto a TPU."""
+    """How a kernel runs, and whether the kernel or the XLA twin runs at
+    all, are derived from the backend and the graph: a manifest cannot
+    carry interpret mode, the old word-chunk tile or a forced
+    implementation onto a TPU."""
     folded = _random_folded((64, 4), seed=1, bias_cells=32)
     assert option not in COMPILE_OPTIONS
     with pytest.raises(ValueError, match="unknown compile options"):
@@ -165,8 +166,7 @@ def test_save_load_bit_exact_cnn(cfg_name, tmp_path):
     else:
         cfg = TINY_CNN
     folded = convnet.random_folded_cnn(cfg, seed=5)
-    dep = deploy(folded, config=cfg, noise=SILICON, impl="xla",
-                 min_bucket=4)
+    dep = deploy(folded, config=cfg, noise=SILICON, min_bucket=4)
     rng = np.random.default_rng(9)
     x = rng.random((6, cfg.n_in)).astype(np.float32)  # raw pixels
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(2), 6))
@@ -204,7 +204,7 @@ def test_save_load_noiseless_and_calibrated_config(tmp_path):
     sizes, bias = BANK_NETS["2048x64"], BANK_BIAS["2048x64"]
     folded = _random_folded(sizes, seed=1, bias_cells=bias)
     ec = ensemble.EnsembleConfig(bias_cells=bias, noise=SILICON)
-    dep = deploy(folded, ens_cfg=ec, impl="xla", min_bucket=8)
+    dep = deploy(folded, ens_cfg=ec, min_bucket=8)
     dep.save(tmp_path / "ecn")
     assert Deployment.load(tmp_path / "ecn").ens_cfg == ec
 

@@ -74,7 +74,7 @@ def test_host_pack_of_a_strided_slice():
                 ids=["mnist", "hg"])
 def mlp(request):
     cfg = bnn.MLPConfig(layer_sizes=request.param)
-    return deploy(bnn.random_folded(cfg, seed=1), config=cfg, impl="xla")
+    return deploy(bnn.random_folded(cfg, seed=1), config=cfg)
 
 
 @pytest.mark.parametrize("b", [100, 128], ids=["padded", "unpadded"])
@@ -98,15 +98,14 @@ def _cnn():
     cfg = CNNConfig(side=12, encoding=InputEncoding("thermometer", 3),
                     conv=(ConvSpec(3, 24, 2), ConvSpec(3, 20, 1)),
                     hidden=(48,), n_classes=7)
-    return build_cnn_pipeline(cfg, convnet.random_folded_cnn(cfg, seed=3),
-                              impl="xla")
+    return build_cnn_pipeline(cfg, convnet.random_folded_cnn(cfg, seed=3))
 
 
 def _head_only():
     cfg = bnn.MLPConfig(layer_sizes=(96, 5), bias_cells=32)
     return pipeline.compile_pipeline(
         bnn.random_folded(cfg, seed=2, cmax=10),
-        ensemble.EnsembleConfig(bias_cells=32), impl="xla")
+        ensemble.EnsembleConfig(bias_cells=32))
 
 
 def test_host_mlp_input_stages_packed_words(mlp):
@@ -125,8 +124,8 @@ def test_host_mlp_input_stages_packed_words(mlp):
 def test_other_inputs_stage_raw_rows_and_skip_the_host_pack(case):
     if case == "device_array":
         cfg = bnn.MLPConfig()
-        pipe = deploy(bnn.random_folded(cfg, seed=1), config=cfg,
-                      impl="xla").pipeline()
+        pipe = deploy(bnn.random_folded(cfg, seed=1),
+                      config=cfg).pipeline()
     else:
         pipe = _cnn() if case == "conv" else _head_only()
     x = np.full((13, pipe.n_in), 0.5 if case == "conv" else 1.0, np.float32)

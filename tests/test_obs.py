@@ -24,7 +24,7 @@ def pipe():
     folded = bnn.random_folded(
         bnn.MLPConfig(layer_sizes=SIZES, bias_cells=32), seed=3, cmax=10)
     return pipeline.compile_pipeline(
-        folded, ensemble.EnsembleConfig(bias_cells=32), impl="xla",
+        folded, ensemble.EnsembleConfig(bias_cells=32),
         min_bucket=8)
 
 

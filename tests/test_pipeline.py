@@ -3,8 +3,11 @@
 The correctness bar for kernels/fused_mlp.py and repro/pipeline.py: the
 fused end-to-end flow must be bit-identical to `bnn.folded_forward_exact`
 (hidden layers) + `ensemble.votes_fused` (head), across the three logical
-bank configurations of the silicon macro, for both implementations
-(pallas-interpret and the single-program XLA twin).
+bank configurations of the silicon macro, for both input forms: a host
+NumPy batch (packed on the host for an MLP with hidden layers) and a
+committed `jax.Array` (packed by the `picbnn_pack` program on the
+device).  Off the TPU the pipeline runs the single-program XLA twin; the
+Pallas kernel's own bit-exactness is tests/test_fused_mlp.py.
 """
 
 import jax
@@ -15,6 +18,7 @@ import pytest
 from repro import pipeline
 from repro.core import binarize, bnn, ensemble
 from repro.core.cam import pick_bank_config
+from repro.spec import InferenceSpec
 
 # Net shapes whose head rows (n_hidden + 64 bias cells) land on each of
 # the macro's three logical row widths: 256 / 128 / 64 bits.
@@ -53,9 +57,28 @@ def _oracle_votes(folded, head, x):
     return ensemble.votes_fused(head, h)
 
 
+#: how a caller hands a batch to `run`: a host NumPy array, or a
+#: `jax.Array` already committed to the device
+INPUTS = ["host", "device"]
+
+
+def _as_input(x, form):
+    x = np.asarray(x, np.float32)
+    if form == "host":
+        return x
+    return jax.device_put(x, jax.devices()[0])
+
+
+def _votes(pipe, x, key=None):
+    """The noise-off votes, or one batch-level draw under `key`."""
+    if key is None:
+        return np.asarray(pipe.run(x, InferenceSpec()))
+    return np.asarray(pipe.run(x, InferenceSpec(noise="batch"), key=key))
+
+
 @pytest.mark.parametrize("bank", sorted(BANK_NETS))
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_pipeline_bit_exact_vs_oracle(bank, impl):
+@pytest.mark.parametrize("form", INPUTS)
+def test_pipeline_bit_exact_vs_oracle(bank, form):
     sizes = BANK_NETS[bank]
     bias = BANK_BIAS[bank]
     rows, width = (int(s) for s in bank.split("x"))
@@ -64,46 +87,44 @@ def test_pipeline_bit_exact_vs_oracle(bank, impl):
 
     folded = _random_folded(sizes, seed=sum(map(ord, bank)), bias_cells=bias)
     ecfg = ensemble.EnsembleConfig(bias_cells=bias)
-    pipe = pipeline.compile_pipeline(folded, ecfg, impl=impl, bq=16)
-    x = jnp.asarray(
-        np.random.default_rng(1).choice([-1.0, 1.0], (23, sizes[0])),
-        jnp.float32,
-    )
-    want = np.asarray(_oracle_votes(folded, pipe.head, x))
-    got = np.asarray(pipe.votes(x))
+    pipe = pipeline.compile_pipeline(folded, ecfg)
+    x = np.random.default_rng(1).choice([-1.0, 1.0], (23, sizes[0]))
+    want = np.asarray(_oracle_votes(folded, pipe.head, jnp.asarray(x,
+                                                                  jnp.float32)))
+    got = _votes(pipe, _as_input(x, form))
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_pipeline_three_hidden_layers(impl):
+@pytest.mark.parametrize("form", INPUTS)
+def test_pipeline_three_hidden_layers(form):
     folded = _random_folded((120, 96, 64, 33, 7), seed=5, bias_cells=64)
     ecfg = ensemble.EnsembleConfig()
-    pipe = pipeline.compile_pipeline(folded, ecfg, impl=impl, bq=8)
-    x = jnp.asarray(
-        np.random.default_rng(2).choice([-1.0, 1.0], (11, 120)), jnp.float32
-    )
-    want = np.asarray(_oracle_votes(folded, pipe.head, x))
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x)), want)
+    pipe = pipeline.compile_pipeline(folded, ecfg)
+    x = np.random.default_rng(2).choice([-1.0, 1.0], (11, 120))
+    want = np.asarray(_oracle_votes(folded, pipe.head,
+                                    jnp.asarray(x, jnp.float32)))
+    np.testing.assert_array_equal(_votes(pipe, _as_input(x, form)), want)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_pipeline_head_only(impl):
-    """Degenerate pipeline (no hidden layers) == votes_fused on the head."""
+@pytest.mark.parametrize("form", INPUTS)
+def test_pipeline_head_only(form):
+    """Degenerate pipeline (no hidden layers) == votes_fused on the head:
+    a host batch is staged, a device batch passes through, and both are
+    packed with the bias cells on the device."""
     folded = _random_folded((128, 10), seed=9, bias_cells=64)
     ecfg = ensemble.EnsembleConfig()
-    pipe = pipeline.compile_pipeline(folded, ecfg, impl=impl, bq=16)
-    x = jnp.asarray(
-        np.random.default_rng(3).choice([-1.0, 1.0], (9, 128)), jnp.float32
-    )
-    want = np.asarray(ensemble.votes_fused(pipe.head, x))
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x)), want)
+    pipe = pipeline.compile_pipeline(folded, ecfg)
+    x = np.random.default_rng(3).choice([-1.0, 1.0], (9, 128))
+    want = np.asarray(ensemble.votes_fused(pipe.head,
+                                           jnp.asarray(x, jnp.float32)))
+    np.testing.assert_array_equal(_votes(pipe, _as_input(x, form)), want)
 
 
 def test_pipeline_matches_votes_faithful_noiseless():
     """Fused pipeline == the 33-sequential-search silicon flow (noiseless)."""
     folded = _random_folded((784, 128, 10), seed=11, bias_cells=64)
     ecfg = ensemble.EnsembleConfig()
-    pipe = pipeline.compile_pipeline(folded, ecfg, impl="xla")
+    pipe = pipeline.compile_pipeline(folded, ecfg)
     x = np.random.default_rng(4).choice([-1.0, 1.0], (17, 784))
     x = jnp.asarray(x, jnp.float32)
     # hidden flow via the digital oracle, head via the faithful sweep
@@ -114,20 +135,20 @@ def test_pipeline_matches_votes_faithful_noiseless():
         )
         h = jnp.where(y >= 0, 1.0, -1.0)
     want = np.asarray(ensemble.votes_faithful(pipe.head, h))
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x)), want)
+    np.testing.assert_array_equal(_votes(pipe, x), want)
 
 
 def test_pipeline_batch_bucketing():
     """Ragged batch sizes pad to power-of-two buckets; results unaffected."""
     folded = _random_folded((100, 48, 6), seed=13, bias_cells=64)
     pipe = pipeline.compile_pipeline(
-        folded, ensemble.EnsembleConfig(), impl="xla", min_bucket=32
+        folded, ensemble.EnsembleConfig(), min_bucket=32
     )
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.choice([-1.0, 1.0], (70, 100)), jnp.float32)
-    full = np.asarray(pipe.votes(x))
+    full = _votes(pipe, x)
     for b in (1, 31, 32, 33, 70):
-        np.testing.assert_array_equal(np.asarray(pipe.votes(x[:b])), full[:b])
+        np.testing.assert_array_equal(_votes(pipe, x[:b]), full[:b])
     assert pipeline.next_bucket(33, 32) == 64
     assert pipeline.next_bucket(32, 32) == 32
     assert pipeline.next_bucket(1, 32) == 32
@@ -188,29 +209,36 @@ def test_fold_emits_dead_zone_free_constants():
 
 
 @pytest.mark.parametrize("bank", sorted(BANK_NETS))
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_noisy_pipeline_noiseless_limit_bit_exact(bank, impl):
-    """sigma -> 0 limit: every silicon-mode entry point (votes(key=),
-    votes_mc, cum_votes) equals the PR-1 noiseless oracle bit-for-bit on
-    all three bank configurations."""
+@pytest.mark.parametrize("form", INPUTS)
+def test_noisy_pipeline_noiseless_limit_bit_exact(bank, form):
+    """sigma -> 0 limit: every silicon spec (batch and per-request draws,
+    Monte-Carlo with and without the fused sum, cumulative) equals the
+    noiseless oracle bit-for-bit on all three bank configurations."""
     from repro.core.device_model import NOISELESS
 
     sizes, bias = BANK_NETS[bank], BANK_BIAS[bank]
     folded = _random_folded(sizes, seed=sum(map(ord, bank)), bias_cells=bias)
     ecfg = ensemble.EnsembleConfig(bias_cells=bias)
-    pipe = pipeline.compile_pipeline(
-        folded, ecfg, impl=impl, bq=16, noise=NOISELESS
-    )
-    x = jnp.asarray(
-        np.random.default_rng(8).choice([-1.0, 1.0], (19, sizes[0])),
-        jnp.float32,
-    )
+    pipe = pipeline.compile_pipeline(folded, ecfg, noise=NOISELESS)
+    x_np = np.random.default_rng(8).choice([-1.0, 1.0], (19, sizes[0]))
+    x = _as_input(x_np, form)
     key = jax.random.PRNGKey(42)
-    want = np.asarray(_oracle_votes(folded, pipe.head, x))
-    np.testing.assert_array_equal(np.asarray(pipe.votes(x, key)), want)
-    mc = np.asarray(pipe.votes_mc(x, key, 3))
+    want = np.asarray(_oracle_votes(folded, pipe.head,
+                                    jnp.asarray(x_np, jnp.float32)))
+    np.testing.assert_array_equal(_votes(pipe, x, key), want)
+    keys = jax.random.split(key, x_np.shape[0])
+    np.testing.assert_array_equal(
+        np.asarray(pipe.run(x, InferenceSpec(noise="per_request"),
+                            keys=keys)), want)
+    mc = np.asarray(pipe.run(x, InferenceSpec(noise="batch", mc_samples=3),
+                             key=key))
     np.testing.assert_array_equal(mc, np.broadcast_to(want, mc.shape))
-    cum = np.asarray(pipe.cum_votes(x, key))
+    np.testing.assert_array_equal(
+        np.asarray(pipe.run(x, InferenceSpec(noise="per_request",
+                                             mc_samples=3, reduction="sum"),
+                            keys=keys)), want * 3)
+    cum = np.asarray(pipe.run(x, InferenceSpec(noise="batch",
+                                               cumulative=True), key=key))
     np.testing.assert_array_equal(cum[-1], want)
     np.testing.assert_array_equal(
         cum,
@@ -219,32 +247,33 @@ def test_noisy_pipeline_noiseless_limit_bit_exact(bank, impl):
     )
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_noisy_pipeline_impls_agree_under_silicon(impl):
-    """Same key => pallas and xla noisy twins produce identical votes
-    (the sampled thresholds are computed outside the kernel), and a
-    silicon draw actually differs from the noiseless votes."""
+@pytest.mark.parametrize("bank", sorted(BANK_NETS))
+@pytest.mark.parametrize("form", INPUTS)
+def test_noisy_pipeline_impls_agree_under_silicon(bank, form):
+    """Same key => the batch-draw votes equal the last pass of the
+    cumulative batch-draw spec (on the TPU: the Pallas kernel against
+    the XLA twin, since the thresholds are sampled outside the kernel),
+    they equal ensemble's fused noisy twin draw for draw, and a silicon
+    draw actually differs from the noiseless votes."""
     from repro.core.device_model import SILICON
 
-    folded = _random_folded((784, 128, 10), seed=23, bias_cells=64)
-    ecfg = ensemble.EnsembleConfig()
-    pipe = pipeline.compile_pipeline(
-        folded, ecfg, impl=impl, bq=16, noise=SILICON
-    )
+    sizes, bias = BANK_NETS[bank], BANK_BIAS[bank]
+    folded = _random_folded(sizes, seed=23, bias_cells=bias)
+    ecfg = ensemble.EnsembleConfig(bias_cells=bias)
+    pipe = pipeline.compile_pipeline(folded, ecfg, noise=SILICON)
     # batch == bucket so the in-program sample shape equals the logical
     # batch (the draw-for-draw comparison below needs identical shapes)
-    x = jnp.asarray(
-        np.random.default_rng(9).choice([-1.0, 1.0], (64, 784)), jnp.float32
-    )
+    x_np = np.random.default_rng(9).choice([-1.0, 1.0], (64, sizes[0]))
+    x = _as_input(x_np, form)
     key = jax.random.PRNGKey(5)
-    got = np.asarray(pipe.votes(x, key))
+    got = _votes(pipe, x, key)
     # silicon noise perturbs (vs noiseless) ...
-    assert (got != np.asarray(pipe.votes(x))).any()
-    # ... but both impls sample identically
-    ref = pipeline.compile_pipeline(folded, ecfg, impl="xla", noise=SILICON)
-    np.testing.assert_array_equal(got, np.asarray(ref.votes(x, key)))
+    assert (got != _votes(pipe, x)).any()
+    # ... and the cumulative twin's last pass is the same draw
+    cum = pipe.run(x, InferenceSpec(noise="batch", cumulative=True), key=key)
+    np.testing.assert_array_equal(got, np.asarray(cum)[-1])
     # and the noisy path is draw-for-draw equal to ensemble's fused twin
-    h = x
+    h = jnp.asarray(x_np, jnp.float32)
     for layer in folded[:-1]:
         y = h @ jnp.asarray(layer.weights_pm1.T, jnp.float32) + jnp.asarray(
             layer.c, jnp.float32
@@ -257,15 +286,15 @@ def test_noisy_pipeline_impls_agree_under_silicon(impl):
 
 def test_pipeline_without_noise_rejects_key():
     folded = _random_folded((128, 10), seed=31, bias_cells=64)
-    pipe = pipeline.compile_pipeline(folded, ensemble.EnsembleConfig(),
-                                     impl="xla")
+    pipe = pipeline.compile_pipeline(folded, ensemble.EnsembleConfig())
     x = jnp.asarray(
         np.random.default_rng(11).choice([-1.0, 1.0], (4, 128)), jnp.float32
     )
     with pytest.raises(ValueError, match="noise="):
-        pipe.votes(x, jax.random.PRNGKey(0))
+        pipe.run(x, InferenceSpec(noise="batch"), key=jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="noise="):
-        pipe.votes_mc(x, jax.random.PRNGKey(0), 2)
+        pipe.run(x, InferenceSpec(noise="batch", mc_samples=2),
+                 key=jax.random.PRNGKey(0))
 
 
 def test_sweep_from_votes_matches_accuracy_sweep_cumsum():

@@ -4,9 +4,9 @@ The correctness bar: serving is a SCHEDULING layer — it may coalesce,
 pad, reorder, and fan out however it likes, but every served result must
 be bit-exact equal to a direct CompiledPipeline call on the same input,
 noiseless and seeded-silicon, across the macro's three logical bank
-configurations.  Silicon determinism rides the per-request-key entry
-points (`votes_each` / `votes_mc_each`), whose batch-composition
-invariance is itself tested here.
+configurations.  Silicon determinism rides the per-request-key specs
+(`InferenceSpec(noise="per_request")`, with or without `mc_samples`),
+whose batch-composition invariance is itself tested here.
 """
 
 import subprocess
@@ -24,6 +24,7 @@ from repro.core import bnn, ensemble
 from repro.core.device_model import NOISELESS, SILICON
 from repro.serve.picbnn import BatchingPolicy, PicBnnServer, QueueFullError
 from repro.serve.scheduler import MicroBatcher, latency_summary
+from repro.spec import InferenceSpec
 
 # Same bank-configuration nets as tests/test_pipeline.py: head rows land
 # on each of the macro's logical row widths (256 / 128 / 64 bits).
@@ -54,7 +55,7 @@ def _make_pipe(bank, noise=None, **kw):
     sizes, bias = BANK_NETS[bank], BANK_BIAS[bank]
     folded = _random_folded(sizes, seed=sum(map(ord, bank)), bias_cells=bias)
     return pipeline.compile_pipeline(
-        folded, ensemble.EnsembleConfig(bias_cells=bias), impl="xla",
+        folded, ensemble.EnsembleConfig(bias_cells=bias),
         min_bucket=8, noise=noise, **kw
     ), sizes
 
@@ -64,30 +65,41 @@ def _images(n, n_in, seed=1):
     return rng.choice([-1.0, 1.0], (n, n_in)).astype(np.float32)
 
 
+def _votes(pipe, x):
+    """The noise-off votes of a direct pipeline call."""
+    return np.asarray(pipe.run(x, InferenceSpec()))
+
+
+def _votes_each(pipe, x, keys, mc_samples=None):
+    """One silicon draw per row from `keys[i]` ([S, B, C] with MC)."""
+    spec = InferenceSpec(noise="per_request", mc_samples=mc_samples)
+    return np.asarray(pipe.run(x, spec, keys=keys))
+
+
 # ---------------------------------------------------------------------------
 # per-request-key pipeline entries (the silicon serving contract)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("bank", sorted(BANK_NETS))
 def test_votes_each_batch_composition_invariant(bank):
-    """votes_each row i depends only on (x_i, keys_i): any batch split —
+    """A per-request row i depends only on (x_i, keys_i): any batch split —
     including single-request calls, which hit different bucket paddings —
     returns identical votes."""
     pipe, sizes = _make_pipe(bank, noise=SILICON)
     x = _images(21, sizes[0])
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(7), 21))
-    full = np.asarray(pipe.votes_each(x, keys))
+    full = _votes_each(pipe, x, keys)
     split = np.concatenate([
-        np.asarray(pipe.votes_each(x[:13], keys[:13])),
-        np.asarray(pipe.votes_each(x[13:], keys[13:])),
+        _votes_each(pipe, x[:13], keys[:13]),
+        _votes_each(pipe, x[13:], keys[13:]),
     ])
     np.testing.assert_array_equal(full, split)
     for i in (0, 11, 20):
         np.testing.assert_array_equal(
-            np.asarray(pipe.votes_each(x[i:i + 1], keys[i:i + 1]))[0],
+            _votes_each(pipe, x[i:i + 1], keys[i:i + 1])[0],
             full[i],
         )
     # a real draw, not the noiseless staircase
-    assert (full != np.asarray(pipe.votes(x))).any()
+    assert (full != _votes(pipe, x)).any()
 
 
 def test_votes_each_noiseless_limit_and_mc_identity():
@@ -95,17 +107,17 @@ def test_votes_each_noiseless_limit_and_mc_identity():
     x = _images(9, sizes[0])
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(0), 9))
     np.testing.assert_array_equal(
-        np.asarray(pipe.votes_each(x, keys)), np.asarray(pipe.votes(x))
+        _votes_each(pipe, x, keys), _votes(pipe, x)
     )
     si, _ = _make_pipe("1024x128", noise=SILICON)
-    mc = np.asarray(si.votes_mc_each(x, keys, 4))  # [S, B, C]
+    mc = _votes_each(si, x, keys, mc_samples=4)  # [S, B, C]
     assert mc.shape[0] == 4
     for s in range(4):
         for i in (0, 8):
             ks = np.asarray(jax.random.split(jnp.asarray(keys[i]), 4))[s]
             np.testing.assert_array_equal(
                 mc[s, i],
-                np.asarray(si.votes_each(x[i:i + 1], ks[None]))[0],
+                _votes_each(si, x[i:i + 1], ks[None])[0],
             )
 
 
@@ -113,10 +125,10 @@ def test_votes_each_rejects_bad_keys_and_noiseless_pipe():
     pipe, sizes = _make_pipe("2048x64")  # no noise= at all
     x = _images(3, sizes[0])
     with pytest.raises(ValueError, match="noise="):
-        pipe.votes_each(x, np.zeros((3, 2), np.uint32))
+        _votes_each(pipe, x, np.zeros((3, 2), np.uint32))
     si, _ = _make_pipe("2048x64", noise=SILICON)
     with pytest.raises(ValueError, match="keys"):
-        si.votes_each(x, np.zeros((5, 2), np.uint32))  # wrong B
+        _votes_each(si, x, np.zeros((5, 2), np.uint32))  # wrong B
 
 
 # ---------------------------------------------------------------------------
@@ -146,9 +158,9 @@ def test_warmup_covers_bucket_grid():
     for b in (1, 8, 9, 32):
         x = _images(b, sizes[0])
         keys = np.asarray(jax.random.split(jax.random.PRNGKey(b), b))
-        assert np.asarray(pipe.votes_each(x, keys)).shape == (b, sizes[-1])
+        assert _votes_each(pipe, x, keys).shape == (b, sizes[-1])
     with pytest.raises(ValueError, match="max_bucket"):
-        pipe.votes(_images(33, sizes[0]))
+        _votes(pipe, _images(33, sizes[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -283,7 +295,7 @@ def test_latency_summary_percentiles():
 def test_served_noiseless_bit_exact(bank):
     pipe, sizes = _make_pipe(bank, max_bucket=64)
     x = _images(43, sizes[0], seed=3)
-    want_votes = np.asarray(pipe.votes(x))
+    want_votes = _votes(pipe, x)
     want_pred = want_votes.argmax(-1)
     srv = PicBnnServer(BatchingPolicy(max_batch=16, max_wait_us=200.0))
     srv.register(bank, pipe, layer_sizes=sizes)
@@ -318,9 +330,9 @@ def test_submit_many_burst_bit_exact_and_split_across_batches():
         preds = gn.wait_all(timeout=60)
         votes = gs.votes_all(timeout=60)
         res = gn.results(timeout=60)
-    np.testing.assert_array_equal(preds, np.asarray(pipe.predict(x)))
-    np.testing.assert_array_equal(votes,
-                                  np.asarray(si.votes_each(x, keys)))
+    np.testing.assert_array_equal(
+        preds, np.asarray(pipe.run(x, InferenceSpec(reduction="argmax"))))
+    np.testing.assert_array_equal(votes, _votes_each(si, x, keys))
     assert len(gn) == len(res) == 41
     # burst of 41 with max_batch 16 -> split across >= 3 micro-batches
     assert len({id(r) for r in res}) == 41
@@ -337,7 +349,7 @@ def test_served_silicon_seeded_bit_exact_any_batching(bank):
     pipe, sizes = _make_pipe(bank, noise=SILICON, max_bucket=64)
     x = _images(29, sizes[0], seed=4)
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(11), len(x)))
-    want = np.asarray(pipe.votes_each(x, keys))
+    want = _votes_each(pipe, x, keys)
     for pol in (BatchingPolicy(max_batch=4, max_wait_us=100.0),
                 BatchingPolicy(max_batch=32, max_wait_us=5000.0)):
         srv = PicBnnServer(pol)
@@ -353,7 +365,7 @@ def test_served_mc_model_matches_votes_mc_each():
     pipe, sizes = _make_pipe("2048x64", noise=SILICON, max_bucket=32)
     x = _images(11, sizes[0], seed=5)
     keys = np.asarray(jax.random.split(jax.random.PRNGKey(2), len(x)))
-    want = np.asarray(pipe.votes_mc_each(x, keys, 5)).sum(0)
+    want = _votes_each(pipe, x, keys, mc_samples=5).sum(0)
     srv = PicBnnServer(BatchingPolicy(max_batch=8, max_wait_us=200.0))
     srv.register("mc", pipe, mc_samples=5)
     with srv:
@@ -381,8 +393,8 @@ def test_mixed_model_traffic_never_mixes_batches():
                 hs.append(("silicon", i,
                            srv.submit("silicon", x2[i], key=keys[i])))
         res = [(m, i, h.result(timeout=60)) for (m, i, h) in hs]
-    want1 = np.asarray(p1.votes(x1))
-    want2 = np.asarray(p2.votes_each(x2, keys))
+    want1 = _votes(p1, x1)
+    want2 = _votes_each(p2, x2, keys)
     for m, i, r in res:
         assert r.model_id == m  # a batch serves exactly one model
         np.testing.assert_array_equal(
@@ -430,7 +442,7 @@ def test_engine_submit_validation():
 def test_engine_queue_full_and_drain_on_close():
     pipe, sizes = _make_pipe("2048x64", max_bucket=32)
     x = _images(6, sizes[0], seed=8)
-    want = np.asarray(pipe.votes(x)).argmax(-1)
+    want = _votes(pipe, x).argmax(-1)
     # deadline far away + batch bigger than the stream: the batcher holds
     # everything, so admission (max_queue=4) fills deterministically
     srv = PicBnnServer(BatchingPolicy(max_batch=32, max_wait_us=30e6,
@@ -484,6 +496,7 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     from repro import pipeline
     from repro.core import bnn, ensemble
     from repro.serve.picbnn import PicBnnServer, BatchingPolicy
+    from repro.spec import InferenceSpec
 
     assert jax.device_count() == 4
     rng = np.random.default_rng(0)
@@ -497,10 +510,10 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
             weights_pm1=rng.choice([-1, 1], (n_out, n_in)).astype(np.int8),
             c=c))
     pipe = pipeline.compile_pipeline(
-        layers, ensemble.EnsembleConfig(bias_cells=bias), impl="xla",
+        layers, ensemble.EnsembleConfig(bias_cells=bias),
         min_bucket=8, max_bucket=64)
     x = rng.choice([-1.0, 1.0], (40, sizes[0])).astype(np.float32)
-    want = np.asarray(pipe.predict(x))
+    want = np.asarray(pipe.run(x, InferenceSpec(reduction="argmax")))
     for fanout in ("round_robin", "spmd"):
         srv = PicBnnServer(
             BatchingPolicy(max_batch=8, max_wait_us=200.0), fanout=fanout)

@@ -2,13 +2,13 @@
 
 Three bars:
   * spec VALIDATION — every unsupported combination is a construction-
-    time ValueError, including the previously-hidden `cum_votes`
-    noiseless default-key case (now the explicit spec
-    `InferenceSpec(noise="off", cumulative=True)`);
-  * run() SEMANTICS — bit-exact against the same digital oracles the
-    legacy eight-method family is tested against, across the macro's
-    three logical bank configurations, plus centralized key/keys
-    validation and per-spec program caching;
+    time ValueError; the noiseless staircase is the explicit spec
+    `InferenceSpec(noise="off", cumulative=True)`, never a hidden key;
+  * run() SEMANTICS — bit-exact against the digital oracles across the
+    macro's three logical bank configurations, plus centralized
+    key/keys validation and per-spec program caching (the silicon
+    specs' noiseless limit and draw-for-draw twin are in
+    tests/test_pipeline.py, across both input forms);
   * BUCKETING properties — hypothesis property tests for
     `next_bucket` / `bucket_grid` (grid membership, monotonicity,
     max_bucket caps), via the tests/_hypothesis_compat.py guard.
@@ -34,7 +34,7 @@ settings.load_profile("ci")
 from repro import pipeline
 from repro.core import bnn, ensemble
 from repro.core.device_model import NOISELESS, SILICON
-from repro.spec import InferenceSpec, legacy_entry_spec
+from repro.spec import InferenceSpec
 
 BANK_NETS = {
     "512x256": (300, 192, 12),
@@ -63,7 +63,7 @@ def _make_pipe(bank, noise=None, **kw):
     sizes, bias = BANK_NETS[bank], BANK_BIAS[bank]
     folded = _random_folded(sizes, seed=sum(map(ord, bank)), bias_cells=bias)
     pipe = pipeline.compile_pipeline(
-        folded, ensemble.EnsembleConfig(bias_cells=bias), impl="xla",
+        folded, ensemble.EnsembleConfig(bias_cells=bias),
         min_bucket=8, noise=noise, **kw
     )
     return pipe, folded, sizes
@@ -122,25 +122,6 @@ def test_spec_rejects_unsupported_combinations(bad):
         InferenceSpec(**bad)
 
 
-def test_legacy_entry_mapping():
-    assert legacy_entry_spec("votes") == InferenceSpec()
-    assert legacy_entry_spec("votes_noisy") == InferenceSpec(noise="batch")
-    assert legacy_entry_spec("votes_mc", 8) == \
-        InferenceSpec(noise="batch", mc_samples=8)
-    assert legacy_entry_spec("votes_mc_each_sum", 8) == InferenceSpec(
-        noise="per_request", mc_samples=8, reduction="sum")
-    assert legacy_entry_spec("cum_votes") == \
-        InferenceSpec(noise="batch", cumulative=True)
-    assert legacy_entry_spec("predict_each") == \
-        InferenceSpec(noise="per_request", reduction="argmax")
-    with pytest.raises(ValueError, match="mc_samples"):
-        legacy_entry_spec("votes_mc")
-    with pytest.raises(ValueError, match="no mc_samples"):
-        legacy_entry_spec("votes", 4)
-    with pytest.raises(ValueError, match="unknown legacy entry"):
-        legacy_entry_spec("votes_v2")
-
-
 # ---------------------------------------------------------------------------
 # run() semantics vs the digital oracles
 # ---------------------------------------------------------------------------
@@ -155,9 +136,7 @@ def test_run_noiseless_specs_bit_exact(bank):
         np.asarray(pipe.run(x, InferenceSpec(reduction="argmax"))),
         want.argmax(-1),
     )
-    # the EXPLICIT noiseless staircase: valid without any physics at all
-    # (this used to be cum_votes silently substituting PRNGKey(0), and
-    # only on noise=NOISELESS-compiled pipelines)
+    # the explicit noiseless staircase: valid without any physics at all
     cum = np.asarray(pipe.run(x, InferenceSpec(cumulative=True)))
     np.testing.assert_array_equal(cum[-1], want)
     np.testing.assert_array_equal(
@@ -165,62 +144,6 @@ def test_run_noiseless_specs_bit_exact(bank):
         np.asarray(ensemble.sweep_from_votes(jnp.asarray(want),
                                              cum.shape[0])),
     )
-
-
-@pytest.mark.parametrize("bank", sorted(BANK_NETS))
-def test_run_silicon_specs_noiseless_limit(bank):
-    """Every noisy spec's sigma->0 limit equals the noiseless oracle."""
-    pipe, folded, sizes = _make_pipe(bank, noise=NOISELESS)
-    x = jnp.asarray(_images(19, sizes[0], seed=8))
-    key = jax.random.PRNGKey(42)
-    keys = jnp.asarray(jax.random.split(key, x.shape[0]))
-    want = np.asarray(_oracle_votes(folded, pipe.head, x))
-    np.testing.assert_array_equal(
-        np.asarray(pipe.run(x, InferenceSpec(noise="batch"), key=key)), want
-    )
-    np.testing.assert_array_equal(
-        np.asarray(pipe.run(x, InferenceSpec(noise="per_request"),
-                            keys=keys)),
-        want,
-    )
-    mc = np.asarray(pipe.run(
-        x, InferenceSpec(noise="batch", mc_samples=3), key=key
-    ))
-    np.testing.assert_array_equal(mc, np.broadcast_to(want, mc.shape))
-    np.testing.assert_array_equal(
-        np.asarray(pipe.run(
-            x,
-            InferenceSpec(noise="per_request", mc_samples=3,
-                          reduction="sum"),
-            keys=keys,
-        )),
-        want * 3,
-    )
-    cum = np.asarray(pipe.run(
-        x, InferenceSpec(noise="batch", cumulative=True), key=key
-    ))
-    np.testing.assert_array_equal(cum[-1], want)
-
-
-def test_run_silicon_draw_matches_fused_twin():
-    """One batch draw through run() is draw-for-draw the ensemble twin."""
-    pipe, folded, sizes = _make_pipe("1024x128", noise=SILICON)
-    x = jnp.asarray(_images(16, sizes[0], seed=9))
-    key = jax.random.PRNGKey(5)
-    # batch == bucket so in-program sample shape == logical batch
-    x = jnp.pad(x, ((0, 0), (0, 0)))[:16]
-    got = np.asarray(pipe.run(x, InferenceSpec(noise="batch"), key=key))
-    h = x
-    for layer in folded[:-1]:
-        y = h @ jnp.asarray(layer.weights_pm1.T, jnp.float32) + jnp.asarray(
-            layer.c, jnp.float32
-        )
-        h = jnp.where(y >= 0, 1.0, -1.0)
-    want = np.asarray(ensemble.votes_fused_noisy(
-        head=pipe.head, x_pm1=h, key=key, physics=pipe.physics))
-    np.testing.assert_array_equal(got, want)
-    # a real draw differs from the deterministic spec
-    assert (got != np.asarray(pipe.run(x, InferenceSpec()))).any()
 
 
 def test_run_key_and_keys_validation():
@@ -252,26 +175,22 @@ def test_run_key_and_keys_validation():
 
 
 def test_cum_votes_shim_explicit_key_contract():
-    """The satellite fix: no hidden PRNGKey(0) substitution anywhere."""
-    # noisy pipeline: key=None must still fail loudly
+    """No hidden PRNGKey(0) anywhere: a silicon staircase needs its key,
+    and the noiseless staircase is an explicit deterministic spec."""
+    # silicon pipeline: the batch-draw staircase without key= fails loudly
     si, _f, sizes = _make_pipe("2048x64", noise=SILICON)
     x = _images(4, sizes[0])
-    pipeline._LEGACY_WARNED.discard("cum_votes")  # warn-once is per-process
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(ValueError, match="explicit key"):
-            si.cum_votes(x)
-    # NOISELESS-physics pipeline: key=None now routes through the
-    # explicit deterministic spec — same staircase, no fake key
-    nl, folded, _ = _make_pipe("2048x64", noise=NOISELESS)
-    want = np.asarray(nl.run(x, InferenceSpec(cumulative=True)))
-    got = np.asarray(nl.cum_votes(x))
-    np.testing.assert_array_equal(got, want)
-    # and a pipeline with NO physics at all supports the staircase too
+    with pytest.raises(ValueError, match="explicit key"):
+        si.run(x, InferenceSpec(noise="batch", cumulative=True))
+    # NOISELESS physics and no physics at all give the same noiseless
+    # staircase, whose last pass is the plain votes
+    nl, _folded, _ = _make_pipe("2048x64", noise=NOISELESS)
     plain, _f2, _s2 = _make_pipe("2048x64")
+    want = np.asarray(plain.run(x, InferenceSpec(cumulative=True)))
     np.testing.assert_array_equal(
-        np.asarray(plain.cum_votes(x)),
-        np.asarray(plain.run(x, InferenceSpec(cumulative=True))),
-    )
+        np.asarray(nl.run(x, InferenceSpec(cumulative=True))), want)
+    np.testing.assert_array_equal(
+        want[-1], np.asarray(plain.run(x, InferenceSpec())))
 
 
 def test_program_cache_one_program_per_spec():
@@ -342,21 +261,17 @@ def test_warmup_reports_per_spec_bucket_and_cache_is_free():
 
 
 def test_warmup_defaults_and_legacy_entries():
+    """Default warmup: the plain vote program on a noiseless pipeline;
+    the batch-draw and per-request programs on a silicon one."""
     pipe, _folded, sizes = _make_pipe("2048x64", max_bucket=16)
     times = pipe.warmup(16)
     assert set(times) == {(InferenceSpec(), 8), (InferenceSpec(), 16)}
     si, _f, _s = _make_pipe("2048x64", noise=SILICON, max_bucket=8)
-    pipeline._LEGACY_WARNED.discard("warmup(entries=)")
-    with pytest.warns(DeprecationWarning):
-        t2 = si.warmup(8, entries=("votes", "votes_mc"), mc_samples=2)
-    assert set(t2) == {
+    assert set(si.warmup(8)) == {
         (InferenceSpec(), 8),
-        (InferenceSpec(noise="batch", mc_samples=2), 8),
+        (InferenceSpec(noise="batch"), 8),
+        (InferenceSpec(noise="per_request"), 8),
     }
-    with pytest.raises(ValueError, match="unknown warmup entries"):
-        si.warmup(8, entries=("votes_v2",))
-    with pytest.raises(ValueError, match="not both"):
-        si.warmup(8, specs=(InferenceSpec(),), entries=("votes",))
 
 
 # ---------------------------------------------------------------------------
