@@ -15,7 +15,12 @@ int32 sums:
                       pooled layer then takes s * maxpool(s * bits) per
                       channel: the OR of the window's sign bits for
                       s = +1 and the AND for s = -1 (`FoldedConvLayer.
-                      pool_sign`)
+                      pool_sign`).  An unpooled layer makes its sign
+                      with no predicate (`sign_pm1`), so the conv's own
+                      output fusion writes the int8 map and no separate
+                      pass unpacks a bit-packed predicate; a pooled
+                      layer keeps `where(v >= 0, ...)`, whose packed
+                      predicate its pool reads at 1/8 of the bytes
     flatten:          NHWC, the order of the first FC layer's rows
     per FC layer:     ±1 int8 matmul, int32 sums, + C_j, sign
     head:             HD_j = (n_feat - h . w_j) / 2 + the bias cells'
@@ -128,24 +133,38 @@ def encode_input(x01, side: int, channels: int,
     return 2 * enc.encode_image_bits(img).astype(jnp.int8) - 1
 
 
+def sign_pm1(v):
+    """int32 -> ±1 int8, +1 where v >= 0: `where(v >= 0, 1, -1)` with no
+    predicate.
+
+    The arithmetic shift gives 0 or -1 and OR 1 makes that +1 or -1, for
+    every int32 value.  XLA stores a `v >= 0` predicate bit-packed along
+    W and, where no pool reads the bits, expands them to the int8 map in
+    a separate loop fusion: a full write and read of the map that does
+    no arithmetic.  Without a predicate the producing conv's (or dot's)
+    output fusion writes the int8 map itself.
+    """
+    return ((v >> 31) | 1).astype(jnp.int8)
+
+
 def conv_layer(h, w, c, s, m: ConvMeta):
     """One conv layer on ±1 int8 maps: conv, + C, sign, pool -> ±1 int8."""
     y = jax.lax.conv_general_dilated(
         h, w, (m.stride, m.stride), (m.pads, m.pads),
         dimension_numbers=DIMS, preferred_element_type=jnp.int32,
     )
+    if m.pool == 1:
+        return sign_pm1(y + c)
+    # the pool reads the packed predicate: 1/8 of an int8 map's bytes
     h = jnp.where(y + c >= 0, 1, -1).astype(jnp.int8)
-    if m.pool > 1:  # OR (s = +1) / AND (s = -1) of the window's bits
-        win = (1, m.pool, m.pool, 1)
-        h = s * jax.lax.reduce_window(s * h, jnp.int8(-1), jax.lax.max,
-                                      win, win, "VALID")
-    return h
+    win = (1, m.pool, m.pool, 1)  # OR (s = +1) / AND (s = -1) of the bits
+    return s * jax.lax.reduce_window(s * h, jnp.int8(-1), jax.lax.max,
+                                     win, win, "VALID")
 
 
 def fc_layer(h, w, c):
     """One FC hidden layer on ±1 int8 rows -> ±1 int8."""
-    y = jnp.dot(h, w, preferred_element_type=jnp.int32)
-    return jnp.where(y + c >= 0, 1, -1).astype(jnp.int8)
+    return sign_pm1(jnp.dot(h, w, preferred_element_type=jnp.int32) + c)
 
 
 def net_hd(x01, operands, metas: Sequence[ConvMeta], side: int,

@@ -7,7 +7,11 @@ and with the `thr_samples` operand, and each compiled program must hold
 a Mosaic kernel (`tpu_custom_call`); the CNN deployments' vote programs
 (the noiseless one and the batch-noise one, `kernels/fused_conv.py`),
 BinaryNet's CIFAR-10 ConvNet among them at its published widths and the
-benchmark's batch, must compile to int8 convolutions.  This catches
+benchmark's batch, must compile to int8 convolutions.  In the CIFAR-10
+program each unpooled conv layer's ±1 int8 map must come out of the
+conv's own output fusion, with no loop fusion that unpacks a bit-packed
+predicate into it, and each pooled layer's conv must still hand its pool
+the packed predicate.  This catches
 what interpret mode cannot — unsupported lowerings, unaligned slices,
 scoped-VMEM overruns, programs past the chip's memory — before any chip
 time is spent.
@@ -18,6 +22,7 @@ chip's programs cannot be read back here.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +48,15 @@ DEPLOYMENTS = {
 BATCH = 256  # two kernel blocks of 128 rows
 CIFAR_BATCH = 1024  # the benchmark cell's batch
 PASSES = 33
+# CIFAR-10 ConvNet at CIFAR_BATCH: the int8 maps of the unpooled convs
+# 1, 3, 5, and the predicates of the pooled convs 2, 4, 6 bit-packed
+# along W
+UNPOOLED_MAPS = ("s8[1024,32,32,128]", "s8[1024,16,16,256]",
+                 "s8[1024,8,8,512]")
+POOLED_PREDICATES = ("u32[1024,32,128]", "u16[1024,16,256]",
+                     "u8[1024,8,512]")
+FUSION = re.compile(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* fusion\(.*"
+                    r"kind=(\w+), calls=%([\w.\-]+)")
 
 
 @pytest.fixture(scope="module")
@@ -132,3 +146,38 @@ def test_fused_kernel_compiles_for_v5e(name, noisy, one_chip):
                    if noisy else None)
     compiled = jax.jit(call).lower(*args, thr_samples).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _computations(text):
+    """{name: instruction lines} of a compiled HLO module's text, the
+    entry computation under "ENTRY"."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name:
+            comps[name].append(line)
+    return comps
+
+
+def test_cifar_unpooled_convs_write_their_maps_from_the_conv(one_chip):
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    prog, args, ops = _conv_program(CIFAR10_CONVNET, shape, noisy=False)
+    comps = _computations(prog.lower(*args, ops=ops).compile().as_text())
+    fusions = [m.groups() for m in map(FUSION.match, comps["ENTRY"]) if m]
+
+    def conv_fusions_making(out):
+        made = [(kind, calls) for o, kind, calls in fusions if o == out]
+        assert made, f"no top-level fusion outputs {out}"
+        return [kind == "kOutput"
+                and any(" convolution(" in l for l in comps[calls])
+                for kind, calls in made]
+
+    for out in UNPOOLED_MAPS + POOLED_PREDICATES:
+        assert all(conv_fusions_making(out)), out
