@@ -5,9 +5,10 @@
 
 Drives the main path through the entry points a user calls —
 `deploy_mlp` / `deploy_cnn` -> `Deployment.pipeline()` -> `run(x, spec)`
--> `PicBnnServer` — at the full widths of the repo's four deployments
-(`configs/paper_mlp.py`, `configs/paper_cnn.py`), with weights made from
-`--seed`.  Each phase prints one line and checks its results exactly:
+-> `PicBnnServer` — at the full widths of the repo's five deployments
+(`configs/paper_mlp.py`, `configs/paper_cnn.py`; Bi-Real-18 carries the
+int32 residual stream), with weights made from `--seed`.  Each phase
+prints one line and checks its results exactly:
 
   * the fused Pallas kernel (MLPs) and the int8 MXU program (CNNs)
     against the float32 oracles, evaluated on
@@ -51,11 +52,12 @@ def _require_tpu():
 
 
 def _deployments():
-    from repro.configs.paper_cnn import HG_CNN, MNIST_CNN
+    from repro.configs.paper_cnn import BIREAL18_CIFAR10, HG_CNN, MNIST_CNN
     from repro.configs.paper_mlp import HG_MLP, MNIST_MLP
 
     return (("mnist_mlp", MNIST_MLP), ("hg_mlp", HG_MLP),
-            ("mnist_cnn", MNIST_CNN), ("hg_cnn", HG_CNN))
+            ("mnist_cnn", MNIST_CNN), ("hg_cnn", HG_CNN),
+            ("bireal18_cifar10", BIREAL18_CIFAR10))
 
 
 def _folded(cfg, seed: int):

@@ -29,11 +29,25 @@ same as the paper MLPs.
   thermometer input and the CAM head replace BinaryNet's real-valued
   first-layer input and its 10 float output units.
 
+  Bi-Real-18 on CIFAR-10 (32x32x3, 10 classes; Bi-Real Net, Liu et al.
+  2018, arXiv:1808.00278 Sec. 3.1, on ResNet-18's layout, He et al.
+  2015, arXiv:1512.03385 Table 1, with the CIFAR stem):
+      thermometer-8 per RGB channel -> 3x3x64 stem, which starts the
+      int32 residual stream -> four stages of four binary 3x3 convs,
+      64@32^2, 128@16^2, 256@8^2, 512@4^2, a shortcut around every conv
+      (stages 2-4 open with stride 2 and the downsample shortcut)
+      -> sign of the 4x4 sum-pool, 512 bits -> CAM head (10 rows)
+  Every conv's BN is integer, a = s * m per channel plus C (DESIGN.md
+  §10).
+
 `build_cnn_pipeline` is the one-call deployment path used by the
 benchmarks, the serving registry, and the tests.
 """
 
 from __future__ import annotations
+
+import dataclasses
+from typing import Sequence
 
 from repro.core.binarize import InputEncoding
 from repro.core.convnet import CNNConfig, ConvSpec
@@ -65,6 +79,38 @@ CIFAR10_CONVNET = CNNConfig(
           ConvSpec(3, 256, 1, "same"), ConvSpec(3, 256, 1, "same", 2),
           ConvSpec(3, 512, 1, "same"), ConvSpec(3, 512, 1, "same", 2)),
     hidden=(1024, 1024),
+    n_classes=10,
+    bias_cells=64,
+)
+
+
+def bireal_convs(widths: Sequence[int], convs_per_stage: int,
+                 global_pool: int) -> tuple[ConvSpec, ...]:
+    """Bi-Real Net's binary ResNet as a flat residual conv stack.
+
+    A 3x3 stem of widths[0] channels starts the stream; each stage then
+    has `convs_per_stage` 3x3 SAME convs of its width, each with a
+    shortcut around it (two a basic block); every stage after the first
+    opens with stride 2 and the downsample shortcut.  The last conv
+    sum-pools its `global_pool` x `global_pool` map.
+    """
+    convs = [ConvSpec(3, widths[0], 1, "same", residual=True)]
+    for i, width in enumerate(widths):
+        for j in range(convs_per_stage):
+            down = i > 0 and j == 0
+            convs.append(ConvSpec(
+                3, width, 2 if down else 1, "same", residual=True,
+                shortcut="down" if down else "identity"))
+    convs[-1] = dataclasses.replace(convs[-1], pool=global_pool)
+    return tuple(convs)
+
+
+BIREAL18_CIFAR10 = CNNConfig(
+    side=32,
+    channels=3,
+    encoding=InputEncoding("thermometer", 8),
+    conv=bireal_convs((64, 128, 256, 512), 4, 4),
+    hidden=(),
     n_classes=10,
     bias_cells=64,
 )

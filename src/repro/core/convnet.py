@@ -26,6 +26,20 @@ the pooled bit of a channel whose BN scale is positive is the OR of the
 window's sign bits, and the AND for a negative scale, whose row the fold
 negates (`FoldedConvLayer.pool_sign`).  Layouts are documented in
 DESIGN.md §10.
+
+Residual layers (Bi-Real Net, Liu et al. 2018): a `residual` conv
+writes an int32 residual stream x instead of a ±1 map,
+
+    x' = a * conv(sign(x)) + C + shortcut(x),   a = s * m per channel
+
+with s = ±1 the sign of the folded BN scale (in the rows, as above) and
+m >= 1 its integer magnitude (`FoldedConvLayer.residual`).  The
+shortcut is "none" (the layer starts the stream: a stem), "identity"
+(x itself) or "down" (Bi-Real's downsample, a * conv1x1(sign(
+sumpool_stride(x))) + C, `FoldedConvLayer.down`).  A residual layer's
+pool sums the stream (a global pool at the last layer), and whatever
+reads the stream, the next conv or the FC stage, reads its sign.
+Training (`cnn_forward`, `fold_cnn`) covers the plain chain only.
 """
 
 from __future__ import annotations
@@ -44,6 +58,9 @@ Params = dict[str, Any]
 
 
 PADDINGS = ("valid", "same")
+#: what a residual layer adds onto its conv: nothing (it starts the
+#: stream), the stream itself, or Bi-Real's downsample of it
+SHORTCUTS = ("none", "identity", "down")
 
 
 def conv_pads(side: int, k: int, stride: int, padding: str) -> tuple:
@@ -75,17 +92,22 @@ def conv_out_side(side: int, k: int, stride: int, padding: str,
 class ConvSpec:
     """One binary conv layer: k x k window, c_out filters, stride,
     "valid" or "same" zero padding, then a pool x pool / pool max-pool
-    (pool 1: none)."""
+    (pool 1: none).  A `residual` layer writes the int32 residual stream
+    (module doc) with its `shortcut` added, and its pool sums."""
 
     k: int
     c_out: int
     stride: int = 1
     padding: str = "valid"
     pool: int = 1
+    residual: bool = False
+    shortcut: str = "none"
 
     def __post_init__(self):
         if (self.k < 1 or self.c_out < 1 or self.stride < 1
-                or self.pool < 1 or self.padding not in PADDINGS):
+                or self.pool < 1 or self.padding not in PADDINGS
+                or self.shortcut not in SHORTCUTS
+                or (self.shortcut != "none" and not self.residual)):
             raise ValueError(f"bad ConvSpec {self}")
 
     def conv_side(self, side: int) -> int:
@@ -170,6 +192,17 @@ class FoldedConvLayer:
                   its window's sign(dot + C) bits for s = +1 and the AND
                   for s = -1: sign(s * maxpool(y) + C) with y the conv
                   output of the unnegated rows.  Unused without a pool.
+    residual    : [c_out] integer magnitudes m >= 1 of the folded BN
+                  scale (None: a plain layer, sign(dot + C)).  Set, the
+                  layer writes the residual stream: m * dot + C + the
+                  shortcut, dot taken with the rows (which hold s), and
+                  its pool sums the stream; no pool_sign.
+    shortcut    : "none", "identity" or "down" (`SHORTCUTS`; only
+                  "none" without `residual`)
+    down        : the "down" shortcut's 1 x 1 residual conv (c_in ->
+                  c_out, stride 1, shortcut "none"), which reads
+                  sign(sumpool(x)) over stride x stride windows of the
+                  incoming stream x; None for every other shortcut
     """
 
     weights_pm1: np.ndarray
@@ -178,6 +211,9 @@ class FoldedConvLayer:
     padding: str = "valid"
     pool: int = 1
     pool_sign: np.ndarray | None = None
+    residual: np.ndarray | None = None
+    shortcut: str = "none"
+    down: "FoldedConvLayer | None" = None
 
     def __post_init__(self):
         if self.padding not in PADDINGS or self.pool < 1:
@@ -187,6 +223,28 @@ class FoldedConvLayer:
                 np.shape(self.pool_sign) != (self.c_out,)
                 or not np.isin(self.pool_sign, (-1, 1)).all()):
             raise ValueError("pool_sign must be [c_out] of ±1")
+        if self.shortcut not in SHORTCUTS:
+            raise ValueError(f"shortcut {self.shortcut!r} not in "
+                             f"{SHORTCUTS}")
+        if self.residual is None:
+            if self.shortcut != "none" or self.down is not None:
+                raise ValueError("a shortcut needs a residual layer")
+            return
+        if (np.shape(self.residual) != (self.c_out,)
+                or (np.asarray(self.residual) < 1).any()):
+            raise ValueError("residual must be [c_out] integers >= 1")
+        if self.pool_sign is not None:
+            raise ValueError("a residual layer's pool sums: no pool_sign")
+        if (self.shortcut == "down") != (self.down is not None):
+            raise ValueError('down= goes with shortcut="down" alone')
+        d = self.down
+        if d is not None and (
+                d.residual is None or d.shortcut != "none" or d.k != 1
+                or d.stride != 1 or d.pool != 1
+                or (d.c_in, d.c_out) != (self.c_in, self.c_out)):
+            raise ValueError("down must be a 1x1 stride-1 residual conv "
+                             f"{self.c_in} -> {self.c_out} with no "
+                             "shortcut or pool")
 
     @property
     def pool_or(self) -> np.ndarray:
@@ -282,6 +340,7 @@ def cnn_forward(params: Params, x01: jax.Array, cfg: CNNConfig, *,
     output layer (training criterion only; deployment replaces them with
     Algorithm-1 votes) and BN-stat-updated params when `train=True`.
     """
+    _plain_chain(cfg)
     b = x01.shape[0]
     h = cfg.encoding.encode_image_pm1(
         jnp.asarray(x01).reshape(b, cfg.side, cfg.side, cfg.channels)
@@ -315,6 +374,13 @@ def cnn_forward(params: Params, x01: jax.Array, cfg: CNNConfig, *,
         if i < n_fc - 1:
             h = sign_ste(y)
     return y, {"conv": new_conv, "fc": new_fc}
+
+
+def _plain_chain(cfg: CNNConfig) -> None:
+    if any(spec.residual for spec in cfg.conv):
+        raise ValueError("training and folding cover plain conv chains; "
+                         "residual nets deploy from folded layers "
+                         "(random_folded_cnn)")
 
 
 def cnn_loss(params: Params, x01, labels, cfg: CNNConfig):
@@ -361,6 +427,7 @@ def fold_cnn(params: Params, cfg: CNNConfig) -> list:
     pooled value); the first FC layer's n_in is `cfg.flat_features` in
     NHWC flatten order, matching the training-time reshape bit for bit.
     """
+    _plain_chain(cfg)
     folded: list = []
     for layer, spec in zip(params["conv"], cfg.conv):
         w = np.asarray(jnp.sign(layer["w"]))
@@ -469,7 +536,8 @@ def cnn_inference_cost(cfg: CNNConfig, n_output_passes: int = 33):
 
     Each conv layer maps its filters onto a CAM tile plan
     (`mapping.plan_layer` with row width k*k*c_in + bias cells) and is
-    searched once per conv output position (before any pool); FC layers
+    searched once per conv output position (before any pool), and so is
+    a downsample shortcut's 1 x 1 conv; FC layers
     query once; the output
     layer sweeps `n_output_passes` thresholds.  This is what the serving
     registry reports as the silicon-equivalent throughput for CNN
@@ -485,6 +553,10 @@ def cnn_inference_cost(cfg: CNNConfig, n_output_passes: int = 33):
             spec.c_out, spec.k * spec.k * c_in, cfg.bias_cells
         ))
         queries.append(spec.conv_side(s_in) ** 2)
+        if spec.shortcut == "down":
+            plans.append(mapping.plan_layer(spec.c_out, c_in,
+                                            cfg.bias_cells))
+            queries.append(queries[-1])
     sizes = cfg.fc_sizes
     for n_in, n_out in zip(sizes[:-1], sizes[1:]):
         plans.append(mapping.plan_layer(n_out, n_in, cfg.bias_cells))
@@ -499,10 +571,11 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
 
     The shape-and-semantics twin of the benchmarks' `random_folded` MLP
     helper: random ±1 filters/weights with valid dead-zone-free
-    constants (and, for pooled layers, random ±1 pool polarities, drawn
-    after everything else so unpooled nets keep their draws), for
-    bit-exactness tests and throughput benchmarks that don't need a
-    trained model.
+    constants (and, for pooled layers, random ±1 pool polarities; for
+    residual layers, magnitudes m in 1..4 and each downsample's 1 x 1
+    conv: all drawn after everything else so plain unpooled nets keep
+    their draws), for bit-exactness tests and throughput benchmarks that
+    don't need a trained model.
     """
     rng = np.random.default_rng(seed)
     folded: list = []
@@ -536,8 +609,22 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
             c=c,
         ))
     for i, spec in enumerate(cfg.conv):
-        if spec.pool > 1:
+        if spec.pool > 1 and not spec.residual:
             folded[i] = dataclasses.replace(
                 folded[i], pool_sign=rng.choice([-1, 1], spec.c_out)
                 .astype(np.int8))
+    for i, spec in enumerate(cfg.conv):
+        if not spec.residual:
+            continue
+        down = None
+        if spec.shortcut == "down":
+            c_in = folded[i].c_in
+            down = FoldedConvLayer(
+                weights_pm1=rng.choice([-1, 1], (spec.c_out, 1, 1, c_in))
+                .astype(np.int8),
+                c=rng.integers(-cmax, cmax + 1, spec.c_out),
+                residual=rng.integers(1, 5, spec.c_out))
+        folded[i] = dataclasses.replace(
+            folded[i], residual=rng.integers(1, 5, spec.c_out),
+            shortcut=spec.shortcut, down=down)
     return folded

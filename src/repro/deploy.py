@@ -32,6 +32,8 @@ On-disk layout::
     <dir>/step_00000000/        ckpt.save output — manifest.json + one
                                 .npy per leaf: packed uint32 weight
                                 words + int32 C_j constants per layer
+                                (and its downsample conv's, `down_w`,
+                                `down_c`, for a residual layer)
 
 The weight files hold `pack_bits`-packed rows (32 weights per uint32
 word, little-endian) — 32x smaller than the ±1 int8 form and exactly
@@ -85,6 +87,16 @@ def _unpack_rows(words: np.ndarray, shape: Sequence[int]) -> np.ndarray:
     n_bits = int(np.prod(shape[1:]))
     bits = _np_unpack_bits(np.asarray(words), n_bits)
     return (bits.astype(np.int8) * 2 - 1).reshape(shape)
+
+
+def _ints(a) -> Optional[list]:
+    """A per-channel integer array as JSON (None stays None)."""
+    return None if a is None else [int(v) for v in a]
+
+
+def _array(v, dtype) -> Optional[np.ndarray]:
+    """Inverse of `_ints`."""
+    return None if v is None else np.asarray(v, dtype)
 
 
 @dataclasses.dataclass
@@ -184,25 +196,32 @@ class Deployment:
         tree = {"layers": []}
         layers_meta = []
         for layer in self.folded:
+            leaves = {"w": _pack_rows(layer.weights_pm1),
+                      "c": np.asarray(layer.c, np.int32)}
             if isinstance(layer, FoldedConvLayer):
+                d = layer.down
                 layers_meta.append({
                     "kind": "conv",
                     "shape": list(layer.weights_pm1.shape),
                     "stride": int(layer.stride),
                     "padding": layer.padding,
                     "pool": int(layer.pool),
-                    "pool_sign": (None if layer.pool_sign is None else
-                                  [int(v) for v in layer.pool_sign]),
+                    "pool_sign": _ints(layer.pool_sign),
+                    "residual": _ints(layer.residual),
+                    "shortcut": layer.shortcut,
+                    "down": None if d is None else {
+                        "shape": list(d.weights_pm1.shape),
+                        "residual": _ints(d.residual)},
                 })
+                if d is not None:
+                    leaves.update(down_w=_pack_rows(d.weights_pm1),
+                                  down_c=np.asarray(d.c, np.int32))
             else:
                 layers_meta.append({
                     "kind": "fc",
                     "shape": list(layer.weights_pm1.shape),
                 })
-            tree["layers"].append({
-                "w": _pack_rows(layer.weights_pm1),
-                "c": np.asarray(layer.c, np.int32),
-            })
+            tree["layers"].append(leaves)
         ckpt.save(root, step=0, tree=tree)
         manifest = {
             "schema": SCHEMA,
@@ -253,30 +272,40 @@ class Deployment:
                 f"unsupported deployment schema {mf.get('schema')!r} "
                 f"(expected {SCHEMA})"
             )
+
+        def leaves(shape, prefix=""):
+            n_rows, n_bits = int(shape[0]), int(np.prod(shape[1:]))
+            return {
+                prefix + "w": jax.ShapeDtypeStruct(
+                    (n_rows, binarize.packed_width(n_bits)), np.uint32),
+                prefix + "c": jax.ShapeDtypeStruct((n_rows,), np.int32),
+            }
+
         template = {"layers": []}
         for lm in mf["layers"]:
-            shape = lm["shape"]
-            n_rows = int(shape[0])
-            n_bits = int(np.prod(shape[1:]))
-            template["layers"].append({
-                "w": jax.ShapeDtypeStruct(
-                    (n_rows, binarize.packed_width(n_bits)), np.uint32
-                ),
-                "c": jax.ShapeDtypeStruct((n_rows,), np.int32),
-            })
+            entry = leaves(lm["shape"])
+            if lm.get("down"):
+                entry.update(leaves(lm["down"]["shape"], "down_"))
+            template["layers"].append(entry)
         tree, _step = ckpt.restore(root, None, template)
         folded = []
         for lm, leaf in zip(mf["layers"], tree["layers"]):
             w = _unpack_rows(np.asarray(leaf["w"]), lm["shape"])
             c = np.asarray(leaf["c"], np.int64)
             if lm["kind"] == "conv":
-                sign = lm.get("pool_sign")
+                dm = lm.get("down")
                 folded.append(FoldedConvLayer(
                     weights_pm1=w, c=c, stride=int(lm["stride"]),
                     padding=lm.get("padding", "valid"),
                     pool=int(lm.get("pool", 1)),
-                    pool_sign=(None if sign is None
-                               else np.asarray(sign, np.int8)),
+                    pool_sign=_array(lm.get("pool_sign"), np.int8),
+                    residual=_array(lm.get("residual"), np.int64),
+                    shortcut=lm.get("shortcut", "none"),
+                    down=None if dm is None else FoldedConvLayer(
+                        weights_pm1=_unpack_rows(
+                            np.asarray(leaf["down_w"]), dm["shape"]),
+                        c=np.asarray(leaf["down_c"], np.int64),
+                        residual=_array(dm["residual"], np.int64)),
                 ))
             else:
                 folded.append(FoldedLayer(weights_pm1=w, c=c))

@@ -21,15 +21,24 @@ int32 sums:
                       pass unpacks a bit-packed predicate; a pooled
                       layer keeps `where(v >= 0, ...)`, whose packed
                       predicate its pool reads at 1/8 of the bytes
+    per residual
+    conv layer:       the int32 residual stream x (`stream_layer`):
+                      x' = conv(sign(x)) + C + the shortcut (x, or the
+                      downsample conv of sign(sumpool(x))), each conv's
+                      int8 filters scaled by its BN scale a = s * m; a
+                      pool sums x'; the next layer reads sign(x')
+                      (`sign_pm1`)
     flatten:          NHWC, the order of the first FC layer's rows
     per FC layer:     ±1 int8 matmul, int32 sums, + C_j, sign
     head:             HD_j = (n_feat - h . w_j) / 2 + the bias cells'
                       constant distance (`fused_mlp.split_head`)
 
 The thresholds' vote is the pipeline's, shared with every spec.  Every
-product is ±1 (0 at a pad) and every sum is far below 2^31, so the
-int32 results are exact and equal the unpacked float oracle
-(`kernels.ref.conv_votes_ref`) bit for bit.
+product is ±1 (0 at a pad), or ±m for a residual layer, whose int8
+filters carry the BN scale a = s * m (|m| <= 127), and every sum and
+stream value is far below 2^31, so the int32 results are exact and
+equal the unpacked float oracle (`kernels.ref.conv_votes_ref`) bit for
+bit.
 
 Why the MXU and not the packed VPU: at the CIFAR-10 ConvNet's shapes a
 chip timing put a packed XNOR-popcount conv on the VPU 2.2-4.8x behind
@@ -62,13 +71,20 @@ class ConvMeta:
     c_in: int  # input channels
     stride: int
     pads: tuple[int, int]  # (low, high) zero padding per spatial axis
-    pool: int  # max-pool window and stride (1: none)
+    pool: int  # max-pool (sum-pool for a residual layer) window and stride
     out_side: int  # side after the pool
     c_out: int  # output channels
+    conv_side: int  # side before the pool
+    residual: bool = False  # writes the int32 residual stream
+    shortcut: str = "none"  # convnet.SHORTCUTS
 
 
 def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
-    """Static ConvMeta chain for a conv stack on `side` x `side` input."""
+    """Static ConvMeta chain for a conv stack on `side` x `side` input.
+
+    A shortcut must find a stream of its layer's output shape: "identity"
+    the stream itself, "down" its stride x stride sum-pool.
+    """
     metas = []
     s = side
     for i, layer in enumerate(conv_layers):
@@ -76,17 +92,38 @@ def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
             raise ValueError(f"conv layer {i} takes {layer.c_in} channels, "
                              f"layer {i - 1} gives {metas[-1].c_out}")
         try:
-            _, out = conv_out_side(s, layer.k, layer.stride, layer.padding,
-                                   layer.pool)
+            conv, out = conv_out_side(s, layer.k, layer.stride,
+                                      layer.padding, layer.pool)
         except ValueError as e:
             raise ValueError(f"feature side {s}: {e} (layer {i})") from None
+        if layer.shortcut != "none":
+            if not (metas and metas[-1].residual):
+                raise ValueError(f"conv layer {i}'s {layer.shortcut} "
+                                 "shortcut has no residual stream to read")
+            if layer.shortcut == "identity":
+                fits = (s, layer.c_in) == (conv, layer.c_out)
+            else:  # the down conv maps c_in to c_out
+                fits = s // layer.stride == conv
+            if not fits:
+                raise ValueError(
+                    f"conv layer {i}: a {layer.shortcut} shortcut of the "
+                    f"{s}x{s}x{layer.c_in} stream does not give its "
+                    f"{conv}x{conv}x{layer.c_out} output")
         metas.append(ConvMeta(
             c_in=layer.c_in, stride=layer.stride,
             pads=conv_pads(s, layer.k, layer.stride, layer.padding),
             pool=layer.pool, out_side=out, c_out=layer.c_out,
+            conv_side=conv, residual=layer.residual is not None,
+            shortcut=layer.shortcut,
         ))
         s = out
     return tuple(metas)
+
+
+def stream_bytes_per_row(metas: Sequence[ConvMeta]) -> int:
+    """Bytes of int32 residual stream the residual layers write a row,
+    each at its conv output's shape (before any pool)."""
+    return 4 * sum(m.conv_side ** 2 * m.c_out for m in metas if m.residual)
 
 
 def pm1_int8(w) -> np.ndarray:
@@ -96,11 +133,19 @@ def pm1_int8(w) -> np.ndarray:
 
 def conv_operands(layer) -> tuple:
     """(HWIO ±1 int8 filters [k, k, c_in, c_out], C int32 [c_out],
-    pool sign int8 [c_out]) of one `FoldedConvLayer`."""
+    pool sign int8 [c_out]) of one plain `FoldedConvLayer`; of a residual
+    one (HWIO int8 filters scaled by its magnitudes m, so a = s * m per
+    channel, C int32 [c_out], the downsample's (filters, C) or ())."""
     w = np.transpose(pm1_int8(layer.weights_pm1), (1, 2, 3, 0))
-    s = np.where(layer.pool_or, 1, -1).astype(np.int8)
-    return (jnp.asarray(w), jnp.asarray(layer.c, jnp.int32),
-            jnp.asarray(s))
+    c = jnp.asarray(layer.c, jnp.int32)
+    if layer.residual is None:
+        s = np.where(layer.pool_or, 1, -1).astype(np.int8)
+        return jnp.asarray(w), c, jnp.asarray(s)
+    m = np.asarray(layer.residual, np.int64)
+    if m.max() > 127:
+        raise ValueError("residual magnitudes above 127 leave int8")
+    down = () if layer.down is None else conv_operands(layer.down)[:2]
+    return jnp.asarray((w * m).astype(np.int8)), c, down
 
 
 def fc_operands(layer) -> tuple:
@@ -162,6 +207,31 @@ def conv_layer(h, w, c, s, m: ConvMeta):
                                      win, win, "VALID")
 
 
+def _sum_pool(x, p: int):
+    win = (1, p, p, 1)
+    return jax.lax.reduce_window(x, jnp.int32(0), jax.lax.add, win, win,
+                                 "VALID")
+
+
+def stream_layer(h, x, w, c, down, m: ConvMeta):
+    """One residual conv layer: ±1 int8 maps h and the int32 stream x
+    (None before the first) -> the stream after it."""
+    y = jax.lax.conv_general_dilated(
+        h, w, (m.stride, m.stride), (m.pads, m.pads),
+        dimension_numbers=DIMS, preferred_element_type=jnp.int32,
+    ) + c
+    if m.shortcut == "identity":
+        y = y + x
+    elif m.shortcut == "down":
+        wd, cd = down
+        xd = sign_pm1(_sum_pool(x, m.stride))
+        y = y + jax.lax.conv_general_dilated(
+            xd, wd, (1, 1), "VALID", dimension_numbers=DIMS,
+            preferred_element_type=jnp.int32,
+        ) + cd
+    return y if m.pool == 1 else _sum_pool(y, m.pool)
+
+
 def fc_layer(h, w, c):
     """One FC hidden layer on ±1 int8 rows -> ±1 int8."""
     return sign_pm1(jnp.dot(h, w, preferred_element_type=jnp.int32) + c)
@@ -177,8 +247,13 @@ def net_hd(x01, operands, metas: Sequence[ConvMeta], side: int,
     """
     conv, fc, (head_w, offset) = operands
     h = encode_input(x01, side, channels, enc)
-    for (w, c, s), m in zip(conv, metas):
-        h = conv_layer(h, w, c, s, m)
+    x = None  # the int32 residual stream, once a residual layer starts it
+    for ops, m in zip(conv, metas):
+        if m.residual:
+            x = stream_layer(h, x, *ops, m)
+            h = sign_pm1(x)
+        else:
+            h = conv_layer(h, *ops, m)
     h = h.reshape(h.shape[0], -1)
     for w, c in fc:
         h = fc_layer(h, w, c)

@@ -73,17 +73,47 @@ def conv_layer_ref(h, layer) -> jax.Array:
                      1.0, -1.0)
 
 
+def _sum_pool_ref(x, p: int):
+    win = (1, p, p, 1)
+    return jax.lax.reduce_window(x, 0.0, jax.lax.add, win, win, "VALID")
+
+
+def stream_layer_ref(h, x, layer) -> jax.Array:
+    """One residual `FoldedConvLayer` in float32: the stream after it.
+
+    m * conv(h) + C, with m the layer's BN magnitudes and the rows (which
+    hold the sign s) as given, plus the shortcut: the stream x itself, or
+    the downsample conv of sign(sumpool(x)) over stride x stride
+    windows; a pool sums the result.  Every value is an integer far
+    below 2^24, so float32 holds it exactly.
+    """
+    def scaled(h, l, stride, padding):
+        y = binary_conv2d_ref(h, l.weights_pm1, stride, padding)
+        return (y * jnp.asarray(l.residual, jnp.float32)
+                + jnp.asarray(l.c, jnp.float32))
+
+    y = scaled(h, layer, layer.stride, layer.padding)
+    if layer.shortcut == "identity":
+        y = y + x
+    elif layer.shortcut == "down":
+        xd = jnp.where(_sum_pool_ref(x, layer.stride) >= 0, 1.0, -1.0)
+        y = y + scaled(xd, layer.down, 1, "valid")
+    return y if layer.pool == 1 else _sum_pool_ref(y, layer.pool)
+
+
 def conv_votes_ref(folded, head, x01, encoding, side: int,
                    channels: int = 1) -> jax.Array:
     """Unpacked end-to-end-binary CNN oracle: raw pixels -> vote counts.
 
     The ground truth for `kernels/fused_conv.py` and the conv pipeline:
     encode [0,1] pixels [B, side*side*channels] (HWC) through the binary
-    input layer, run every FoldedConvLayer with `conv_layer_ref` in ±1
-    floats, flatten NHWC, run the folded FC hidden layers as
-    sign(Wx + C), and vote the head with `ensemble.votes_fused`.
-    Bit-exactness of the deployed path against this oracle is asserted
-    in tests/test_conv.py and tests/test_conv_cifar.py.
+    input layer, run every FoldedConvLayer in ±1 floats
+    (`conv_layer_ref`; a residual one with `stream_layer_ref`, the next
+    layer reading the sign of its stream), flatten NHWC, run the folded
+    FC hidden layers as sign(Wx + C), and vote the head with
+    `ensemble.votes_fused`.  Bit-exactness of the deployed path against
+    this oracle is asserted in tests/test_conv.py,
+    tests/test_conv_cifar.py and tests/test_conv_resnet.py.
     """
     from repro.core.convnet import FoldedConvLayer
     from repro.core.ensemble import votes_fused
@@ -92,9 +122,12 @@ def conv_votes_ref(folded, head, x01, encoding, side: int,
     h = encoding.encode_image_pm1(
         jnp.asarray(x01).reshape(b, side, side, channels)
     )
-    flat = None
+    flat, x = None, None
     for layer in folded[:-1]:
-        if isinstance(layer, FoldedConvLayer):
+        if isinstance(layer, FoldedConvLayer) and layer.residual is not None:
+            x = stream_layer_ref(h, x, layer)
+            h = jnp.where(x >= 0, 1.0, -1.0)
+        elif isinstance(layer, FoldedConvLayer):
             h = conv_layer_ref(h, layer)
         else:
             if flat is None:

@@ -58,7 +58,9 @@ coverage is direct (tests/test_fused_mlp.py, interpret mode).
 
 Convolutional graphs: `folded` may start with a prefix of
 `convnet.FoldedConvLayer` (a deployed end-to-end-binary CNN, e.g.
-`convnet.fold_cnn` output).  The pipeline then takes RAW [0,1] pixels
+`convnet.fold_cnn` output; plain layers, or residual layers that carry
+an int32 stream with identity and downsample shortcuts, as Bi-Real
+Net's binary ResNet).  The pipeline then takes RAW [0,1] pixels
 [B, side*side*channels] (HWC), and each vote program takes them as they
 are staged: it runs the binary input layer (`image_encoding`,
 thermometer by default) to ±1 int8 maps itself, then the whole net,
@@ -96,10 +98,12 @@ the other inputs `picbnn.stage` (host arrays only), `picbnn.pack`,
 each the dispatch of one program; each `picbnn.pack` adds one to the
 counter `pack.device_calls`.  Each vote dispatch adds the weight bytes
 its program reads from HBM, as the program lays them out (once per
-call), to the counter `kernel.weight_bytes`, and the rows it answers to
-`kernel.rows`.  The programs carry fixed names: `picbnn_pack`, and one
-`InferenceSpec.program_name` per spec (`picbnn_votes_off`, ...), so a
-device trace names them `jit_picbnn_*`.
+call), to the counter `kernel.weight_bytes`, the rows it answers to
+`kernel.rows`, and the int32 residual stream its program writes over
+the bucket's rows to `kernel.stream_bytes` (0 without residual layers,
+`fused_conv.stream_bytes_per_row`).  The programs carry fixed names:
+`picbnn_pack`, and one `InferenceSpec.program_name` per spec
+(`picbnn_votes_off`, ...), so a device trace names them `jit_picbnn_*`.
 """
 
 from __future__ import annotations
@@ -218,6 +222,8 @@ class CompiledPipeline:
     # programs hold theirs as constants), and the bytes a call reads
     weight_operands: tuple = ()
     weight_bytes: int = 0
+    # int32 residual stream a program writes per (bucket) row
+    stream_bytes_per_row: int = 0
     _programs: dict = dataclasses.field(default_factory=dict, repr=False)
     _calls: Iterator[int] = dataclasses.field(
         default_factory=itertools.count, repr=False)  # span `call=` ids
@@ -330,6 +336,8 @@ class CompiledPipeline:
                 out = prog(x_packed, ops=ops)
         obs.count("kernel.weight_bytes", self.weight_bytes)
         obs.count("kernel.rows", b)
+        obs.count("kernel.stream_bytes",
+                  self.stream_bytes_per_row * x_packed.shape[0])
         return self._trim(out, b, spec.batch_axis)
 
     # ------------------------------------------------------------------
@@ -579,6 +587,7 @@ def compile_pipeline(
 
     host_pack = None
     operands: tuple = ()
+    stream_bytes = 0
     weight_bytes = sum(int(a.size) * a.dtype.itemsize
                        for a in (*layer_ws, *layer_cs, head_rows))
     if conv_layers:
@@ -609,6 +618,7 @@ def compile_pipeline(
                 head.bias_cells),
         )
         weight_bytes = fused_conv.weight_bytes(operands)
+        stream_bytes = fused_conv.stream_bytes_per_row(conv_metas)
         pack = None  # the vote program encodes the staged pixels
     elif hidden:
         pack, host_pack = binarize.pack_pm1, binarize.pack_pm1_host
@@ -770,4 +780,5 @@ def compile_pipeline(
         max_bucket=max_bucket,
         weight_operands=operands,
         weight_bytes=weight_bytes,
+        stream_bytes_per_row=stream_bytes,
     )

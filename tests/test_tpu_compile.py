@@ -11,7 +11,10 @@ benchmark's batch, must compile to int8 convolutions.  In the CIFAR-10
 program each unpooled conv layer's ±1 int8 map must come out of the
 conv's own output fusion, with no loop fusion that unpacks a bit-packed
 predicate into it, and each pooled layer's conv must still hand its pool
-the packed predicate.  This catches
+the packed predicate.  In Bi-Real-18's program at the same batch every
+convolution must take int8 and give int32 sums, and every int32 map it
+writes must come out of a conv's output fusion, most with their sign
+beside them.  This catches
 what interpret mode cannot — unsupported lowerings, unaligned slices,
 scoped-VMEM overruns, programs past the chip's memory — before any chip
 time is spent.
@@ -29,8 +32,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.paper_cnn import (CIFAR10_CONVNET, HG_CNN, MNIST_CNN,
-                                     deploy_cnn)
+from repro.configs.paper_cnn import (BIREAL18_CIFAR10, CIFAR10_CONVNET,
+                                     HG_CNN, MNIST_CNN, deploy_cnn)
 from repro.configs.paper_mlp import HG_MLP, MNIST_MLP
 from repro.core import binarize, convnet
 from repro.core.convnet import CNNConfig
@@ -55,6 +58,7 @@ UNPOOLED_MAPS = ("s8[1024,32,32,128]", "s8[1024,16,16,256]",
                  "s8[1024,8,8,512]")
 POOLED_PREDICATES = ("u32[1024,32,128]", "u16[1024,16,256]",
                      "u8[1024,8,512]")
+DEFINES = re.compile(r"\s*(?:ROOT )?%(\S+) = (\w+)\[")  # (name, dtype)
 FUSION = re.compile(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* fusion\(.*"
                     r"kind=(\w+), calls=%([\w.\-]+)")
 
@@ -112,14 +116,15 @@ def _mlp_call(cfg, shape):
     return call, args
 
 
-def _conv_program(cfg, shape, noisy):
+def _conv_program(cfg, shape, noisy, batch=None):
     """The vote program of a CNN deployment and its argument shapes."""
     dep = deploy_cnn(cfg, convnet.random_folded_cnn(cfg),
                      noise=SILICON if noisy else None)
     pipe = dep.pipeline()
     spec = InferenceSpec(noise="batch") if noisy else InferenceSpec()
     prog = pipe.program(spec)
-    batch = CIFAR_BATCH if cfg is CIFAR10_CONVNET else BATCH
+    if batch is None:
+        batch = CIFAR_BATCH if cfg is CIFAR10_CONVNET else BATCH
     x = shape((batch, pipe.n_in), jnp.float32)  # raw pixels, encoded inside
     ops = jax.tree_util.tree_map(lambda a: shape(a.shape, a.dtype),
                                  pipe.weight_operands)
@@ -181,3 +186,56 @@ def test_cifar_unpooled_convs_write_their_maps_from_the_conv(one_chip):
 
     for out in UNPOOLED_MAPS + POOLED_PREDICATES:
         assert all(conv_fusions_making(out)), out
+
+
+def _fusion_outputs(entry):
+    """[(kind, called computation, [(dtype, dims)])] of each top-level
+    fusion, a multi-output fusion's outputs in order."""
+    out = []
+    for line in entry:
+        head, _, rest = line.partition(" fusion(")
+        m = re.search(r"kind=(\w+), calls=%([\w.\-]+)", rest)
+        if not rest or not m:
+            continue
+        types = re.findall(r"(\w+)\[([\d,]*)\]", head.partition(" = ")[2])
+        out.append((m.group(1), m.group(2), types))
+    return out
+
+
+def test_bireal18_convs_are_int8_and_its_stream_leaves_the_conv_fusions(
+        one_chip):
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    prog, args, ops = _conv_program(BIREAL18_CIFAR10, shape, noisy=False,
+                                    batch=CIFAR_BATCH)
+    comps = _computations(prog.lower(*args, ops=ops).compile().as_text())
+    convs = 0
+    for lines in comps.values():
+        dtype = dict(m.groups() for m in map(DEFINES.match, lines) if m)
+        for l in lines:
+            m = re.search(r"= (\w+)\[.* convolution\(%(\S+), %(\S+)\)", l)
+            if m:
+                convs += 1
+                assert m.group(1) == "s32", l
+                assert dtype[m.group(2)] == dtype[m.group(3)] == "s8", l
+    assert convs == 17 + 3 + 1  # the 3x3 convs, the downsamples, the head
+    both, alone = 0, []
+    for kind, calls, types in _fusion_outputs(comps["ENTRY"]):
+        maps = [dims for t, dims in types
+                if t == "s32" and dims.count(",") == 3]
+        if not maps:
+            continue
+        # no loop fusion adds, copies or relays out an int32 map
+        assert kind == "kOutput" and any(
+            " convolution(" in l for l in comps[calls]), (kind, types)
+        if ("s8", maps[0]) in types:
+            both += 1  # the stream and its sign, from the conv's fusion
+        else:
+            alone.append(maps[0])
+    # all but four convs write the stream and its int8 sign together; the
+    # three stride-2 convs write their sums for the downsample's fusion to
+    # add, and the last conv its stream for the global pool
+    assert both == 16
+    assert sorted(alone) == sorted(["1024,16,16,128", "1024,8,8,256",
+                                    "1024,4,4,512", "1024,4,4,512"])
