@@ -22,12 +22,16 @@ int32 sums:
                       layer keeps `where(v >= 0, ...)`, whose packed
                       predicate its pool reads at 1/8 of the bytes
     per residual
-    conv layer:       the int32 residual stream x (`stream_layer`):
+    conv layer:       the residual stream x (`stream_layer`):
                       x' = conv(sign(x)) + C + the shortcut (x, or the
                       downsample conv of sign(sumpool(x))), each conv's
                       int8 filters scaled by its BN scale a = s * m; a
-                      pool sums x'; the next layer reads sign(x')
-                      (`sign_pm1`)
+                      pool sums x'; all of it in int32.  The next layer
+                      reads sign(x') (`sign_pm1`, taken from the int32
+                      value), and x' is stored at the layer's
+                      `ConvMeta.stream_dtype`: int16 where the bound
+                      `stream_bounds` proves from the deployed operands
+                      fits, else int32; every read widens it to int32
     flatten:          NHWC, the order of the first FC layer's rows
     per FC layer:     ±1 int8 matmul, int32 sums, + C_j, sign
     head:             HD_j = (n_feat - h . w_j) / 2 + the bias cells'
@@ -38,7 +42,9 @@ product is ±1 (0 at a pad), or ±m for a residual layer, whose int8
 filters carry the BN scale a = s * m (|m| <= 127), and every sum and
 stream value is far below 2^31, so the int32 results are exact and
 equal the unpacked float oracle (`kernels.ref.conv_votes_ref`) bit for
-bit.
+bit.  A stream stored as int16 holds every value exactly too: its
+layer's bound (`stream_bounds`) is at most 32,767, so the narrowing
+drops no bit, and it halves the bytes of the HBM-bound stream.
 
 Why the MXU and not the packed VPU: at the CIFAR-10 ConvNet's shapes a
 chip timing put a packed XNOR-popcount conv on the VPU 2.2-4.8x behind
@@ -66,7 +72,9 @@ DIMS = ("NHWC", "HWIO", "NHWC")
 
 @dataclasses.dataclass(frozen=True)
 class ConvMeta:
-    """Static shape info for one conv layer (square feature maps)."""
+    """Static shape info for one conv layer (square feature maps), and for
+    a residual layer the type its stream is stored at, proven from the
+    deployed operands by `conv_metas_for`."""
 
     c_in: int  # input channels
     stride: int
@@ -75,8 +83,50 @@ class ConvMeta:
     out_side: int  # side after the pool
     c_out: int  # output channels
     conv_side: int  # side before the pool
-    residual: bool = False  # writes the int32 residual stream
+    residual: bool = False  # writes the residual stream
     shortcut: str = "none"  # convnet.SHORTCUTS
+    # the stored stream's type (a residual layer; None for a plain one):
+    # int16 where `stream_bounds` proves |x| <= 32,767, else int32
+    stream_dtype: np.dtype | None = None
+
+
+def _term(layer) -> int:
+    """The most one residual conv adds to a stream value: the maximum
+    over output channels of sum|w * m| + |C|, k^2 * c_in * m_o + |c_o|
+    for ±1 filters (a pad adds 0)."""
+    m = np.asarray(layer.residual, np.int64)
+    return int(np.max(layer.n_bits * m + np.abs(np.asarray(layer.c,
+                                                           np.int64))))
+
+
+def stream_bounds(conv_layers: Sequence) -> tuple[int, ...]:
+    """Per conv layer, the bound on |x| of the residual stream it stores
+    into (0 for a plain layer), proven from the deployed operands.
+
+    A stream starts at shortcut "none" or "down" (which reads only signs
+    of the old stream) with the layer's term, plus the downsample conv's
+    term for "down"; each "identity" layer adds its term, and a pool of
+    p x p sums multiplies the bound by p^2.  A stream is one tensor from
+    start to restart, stored at one type, so every layer of it takes the
+    largest bound it reaches.  Each term is one maximum over its layer's
+    output channels, not a per-channel chain, so the bound, and the
+    program compiled from it, is the same for every draw of a
+    configuration whose extremes of m and C are drawn.
+    """
+    bounds, b = [], 0
+    for layer in conv_layers:
+        if layer.residual is None:
+            b = 0
+        elif layer.shortcut == "identity":
+            b = (b + _term(layer)) * layer.pool ** 2
+        else:
+            down = 0 if layer.down is None else _term(layer.down)
+            b = (_term(layer) + down) * layer.pool ** 2
+        bounds.append(b)
+    for i in reversed(range(len(bounds) - 1)):
+        if conv_layers[i + 1].shortcut == "identity":
+            bounds[i] = max(bounds[i], bounds[i + 1])
+    return tuple(bounds)
 
 
 def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
@@ -87,6 +137,7 @@ def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
     """
     metas = []
     s = side
+    bounds = stream_bounds(conv_layers)
     for i, layer in enumerate(conv_layers):
         if metas and layer.c_in != metas[-1].c_out:
             raise ValueError(f"conv layer {i} takes {layer.c_in} channels, "
@@ -109,21 +160,33 @@ def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
                     f"conv layer {i}: a {layer.shortcut} shortcut of the "
                     f"{s}x{s}x{layer.c_in} stream does not give its "
                     f"{conv}x{conv}x{layer.c_out} output")
+        residual = layer.residual is not None
+        stream = np.int16 if bounds[i] <= np.iinfo(np.int16).max \
+            else np.int32
         metas.append(ConvMeta(
             c_in=layer.c_in, stride=layer.stride,
             pads=conv_pads(s, layer.k, layer.stride, layer.padding),
             pool=layer.pool, out_side=out, c_out=layer.c_out,
-            conv_side=conv, residual=layer.residual is not None,
-            shortcut=layer.shortcut,
+            conv_side=conv, residual=residual, shortcut=layer.shortcut,
+            stream_dtype=np.dtype(stream) if residual else None,
         ))
         s = out
     return tuple(metas)
 
 
 def stream_bytes_per_row(metas: Sequence[ConvMeta]) -> int:
-    """Bytes of int32 residual stream the residual layers write a row,
-    each at its conv output's shape (before any pool)."""
+    """Bytes of residual stream the residual layers write a row at 4 B
+    an element, the width its values are proven against, each at its
+    conv output's shape (before any pool)."""
     return 4 * sum(m.conv_side ** 2 * m.c_out for m in metas if m.residual)
+
+
+def stream_stored_bytes_per_row(metas: Sequence[ConvMeta]) -> int:
+    """Bytes of residual stream the residual layers store a row, each at
+    its own `stream_dtype` and its conv output's shape (before any
+    pool)."""
+    return sum(m.stream_dtype.itemsize * m.conv_side ** 2 * m.c_out
+               for m in metas if m.residual)
 
 
 def pm1_int8(w) -> np.ndarray:
@@ -208,20 +271,23 @@ def conv_layer(h, w, c, s, m: ConvMeta):
 
 
 def _sum_pool(x, p: int):
+    """p x p sums of a stream, in int32 whatever width x is stored at
+    (four int16 values can pass 32,767)."""
     win = (1, p, p, 1)
-    return jax.lax.reduce_window(x, jnp.int32(0), jax.lax.add, win, win,
-                                 "VALID")
+    return jax.lax.reduce_window(x.astype(jnp.int32), jnp.int32(0),
+                                 jax.lax.add, win, win, "VALID")
 
 
 def stream_layer(h, x, w, c, down, m: ConvMeta):
-    """One residual conv layer: ±1 int8 maps h and the int32 stream x
-    (None before the first) -> the stream after it."""
+    """One residual conv layer: ±1 int8 maps h and the stored stream x
+    (None before the first) -> the stream after it in int32, which the
+    caller signs and stores at `m.stream_dtype`."""
     y = jax.lax.conv_general_dilated(
         h, w, (m.stride, m.stride), (m.pads, m.pads),
         dimension_numbers=DIMS, preferred_element_type=jnp.int32,
     ) + c
     if m.shortcut == "identity":
-        y = y + x
+        y = y + x.astype(jnp.int32)
     elif m.shortcut == "down":
         wd, cd = down
         xd = sign_pm1(_sum_pool(x, m.stride))
@@ -247,11 +313,16 @@ def net_hd(x01, operands, metas: Sequence[ConvMeta], side: int,
     """
     conv, fc, (head_w, offset) = operands
     h = encode_input(x01, side, channels, enc)
-    x = None  # the int32 residual stream, once a residual layer starts it
+    x = None  # the stored residual stream, once a residual layer starts it
     for ops, m in zip(conv, metas):
         if m.residual:
-            x = stream_layer(h, x, *ops, m)
-            h = sign_pm1(x)
+            y = stream_layer(h, x, *ops, m)
+            h = sign_pm1(y)  # from the int32 value: `sign_pm1` is int32's
+            x = y.astype(m.stream_dtype)
+            if m.stream_dtype != np.int32:
+                # XLA would sink the narrowing into the reading fusion and
+                # store the int32 value; the barrier stores the int16 one
+                x = jax.lax.optimization_barrier(x)
         else:
             h = conv_layer(h, *ops, m)
     h = h.reshape(h.shape[0], -1)

@@ -99,9 +99,15 @@ each the dispatch of one program; each `picbnn.pack` adds one to the
 counter `pack.device_calls`.  Each vote dispatch adds the weight bytes
 its program reads from HBM, as the program lays them out (once per
 call), to the counter `kernel.weight_bytes`, the rows it answers to
-`kernel.rows`, and the int32 residual stream its program writes over
-the bucket's rows to `kernel.stream_bytes` (0 without residual layers,
-`fused_conv.stream_bytes_per_row`).  The programs carry fixed names:
+`kernel.rows`, the residual stream its program writes over the
+bucket's rows, as elements at 4 B, the width its values are proven
+against, to `kernel.stream_bytes` (0 without residual layers,
+`fused_conv.stream_bytes_per_row`), and the bytes the program stores
+for that stream over the bucket's rows, at each layer's own width
+(int16 or int32, `ConvMeta.stream_dtype`), to
+`kernel.stream_stored_bytes` (0 without residual layers,
+`fused_conv.stream_stored_bytes_per_row`).  The programs carry fixed
+names:
 `picbnn_pack`, and one `InferenceSpec.program_name` per spec
 (`picbnn_votes_off`, ...), so a device trace names them `jit_picbnn_*`.
 """
@@ -222,8 +228,10 @@ class CompiledPipeline:
     # programs hold theirs as constants), and the bytes a call reads
     weight_operands: tuple = ()
     weight_bytes: int = 0
-    # int32 residual stream a program writes per (bucket) row
+    # residual stream a program writes per (bucket) row: its elements at
+    # 4 B, and the bytes it stores at each layer's own width
     stream_bytes_per_row: int = 0
+    stream_stored_bytes_per_row: int = 0
     _programs: dict = dataclasses.field(default_factory=dict, repr=False)
     _calls: Iterator[int] = dataclasses.field(
         default_factory=itertools.count, repr=False)  # span `call=` ids
@@ -338,6 +346,8 @@ class CompiledPipeline:
         obs.count("kernel.rows", b)
         obs.count("kernel.stream_bytes",
                   self.stream_bytes_per_row * x_packed.shape[0])
+        obs.count("kernel.stream_stored_bytes",
+                  self.stream_stored_bytes_per_row * x_packed.shape[0])
         return self._trim(out, b, spec.batch_axis)
 
     # ------------------------------------------------------------------
@@ -587,7 +597,7 @@ def compile_pipeline(
 
     host_pack = None
     operands: tuple = ()
-    stream_bytes = 0
+    stream_bytes = stream_stored_bytes = 0
     weight_bytes = sum(int(a.size) * a.dtype.itemsize
                        for a in (*layer_ws, *layer_cs, head_rows))
     if conv_layers:
@@ -619,6 +629,8 @@ def compile_pipeline(
         )
         weight_bytes = fused_conv.weight_bytes(operands)
         stream_bytes = fused_conv.stream_bytes_per_row(conv_metas)
+        stream_stored_bytes = fused_conv.stream_stored_bytes_per_row(
+            conv_metas)
         pack = None  # the vote program encodes the staged pixels
     elif hidden:
         pack, host_pack = binarize.pack_pm1, binarize.pack_pm1_host
@@ -781,4 +793,5 @@ def compile_pipeline(
         weight_operands=operands,
         weight_bytes=weight_bytes,
         stream_bytes_per_row=stream_bytes,
+        stream_stored_bytes_per_row=stream_stored_bytes,
     )

@@ -9,9 +9,16 @@ on a net that leaves the stream for a plain pooled conv and an FC layer;
 a saved deployment keeps every residual field; a stack whose shortcut
 finds no stream of its shape is refused; and each vote dispatch counts
 the stream its program writes.
+
+The stream is stored as int16 where the bound proven from the deployed
+operands fits (`fused_conv.stream_bounds`), else int32: at published
+widths the stem and stages 1-2 are int16 and stages 3-4 int32, larger
+magnitudes keep int32, and nets that drive a stream to exactly its bound,
+or sum-pool an int16 stream past 32,767, still give the oracle's votes.
 """
 
 import dataclasses
+import functools
 
 import jax
 import numpy as np
@@ -232,3 +239,104 @@ def test_training_refuses_a_residual_config():
         convnet.fold_cnn({}, cfg)
     with pytest.raises(ValueError, match="plain conv chains"):
         convnet.cnn_forward({}, np.zeros((1, cfg.n_in), np.float32), cfg)
+
+
+def _at_extremes(layer, m: int, c: int):
+    """`layer` (and its downsample conv) with every magnitude m and one
+    channel's C at -c, so its term is exactly k^2 * c_in * m + c."""
+    cs = np.asarray(layer.c, np.int64).copy()
+    cs[0] = -c
+    down = None if layer.down is None else _at_extremes(layer.down, m, c)
+    return dataclasses.replace(layer, residual=np.full(layer.c_out, m),
+                               c=cs, down=down)
+
+
+STAGE = {64: 0, 128: 1, 256: 2, 512: 3}  # Bi-Real-18's stage of a width
+STREAM_CASES = {
+    # m <= 4, |C| <= 40: stem + stage 1 904 + 4 * 2,344; stage 2 2,344 +
+    # 296 + 3 * 4,648; stage 3 4,648 + 552 + 3 * 9,256; stage 4 9,256 +
+    # 1,064 + 3 * 18,472, times the global pool's 16 sums
+    "published": ((4, 4, 4, 4), (10_280, 16_584, 32_968, 16 * 65_736),
+                  ("int16", "int16", "int32", "int32"),
+                  (327_680 + 131_072) * 2 + 98_304 * 4),
+    # m up to 8 in stage 2: 4,648 + 552 + 3 * 9,256 leaves int16
+    "m8-stage2": ((4, 8, 4, 4), (10_280, 32_968, 32_968, 16 * 65_736),
+                  ("int16", "int32", "int32", "int32"),
+                  327_680 * 2 + (131_072 + 98_304) * 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STREAM_CASES))
+def test_the_stream_is_stored_at_the_width_its_proven_bound_allows(case):
+    m, bounds, types, stored = STREAM_CASES[case]
+    cfg = BIREAL18_CIFAR10
+    drawn = convnet.random_folded_cnn(cfg, seed=1)[:len(cfg.conv)]
+    layers = [_at_extremes(l, m[STAGE[l.c_out]], 40) for l in drawn]
+    stage = [STAGE[l.c_out] for l in layers]
+    got = fused_conv.stream_bounds(layers)
+    metas = fused_conv.conv_metas_for(layers, cfg.side)
+    for i in range(4):
+        assert {b for b, s in zip(got, stage) if s == i} == {bounds[i]}
+        assert {str(mt.stream_dtype) for mt, s in zip(metas, stage)
+                if s == i} == {types[i]}
+    assert fused_conv.stream_stored_bytes_per_row(metas) == stored
+    assert fused_conv.stream_bytes_per_row(metas) == 4 * 557_056
+    if case == "published":  # a draw of m in 1..4, |C| <= 24: same types
+        assert [mt.stream_dtype for mt in fused_conv.conv_metas_for(
+            drawn, cfg.side)] == [mt.stream_dtype for mt in metas]
+
+
+# a stem and an identity layer whose stream's interior reaches its bound,
+# then a stride-2 downsample layer whose 4 x 4 x 16 map the head reads
+EDGE = CNNConfig(
+    side=8, channels=1, encoding=InputEncoding("thermometer", 4),
+    conv=(ConvSpec(3, 16, 1, "same", residual=True),
+          ConvSpec(3, 16, 1, "same", residual=True, shortcut="identity"),
+          ConvSpec(3, 16, 2, "same", residual=True, shortcut="down")),
+    hidden=(), n_classes=10)
+
+
+def _edge_layers(bound: int, polarity: int):
+    """EDGE's conv layers at m = 127, the most an int8 filter holds.  On
+    all-+1 input the stem's rows are all `polarity` and the identity
+    layer's all +1, every C of the sign `polarity`, so the stream's
+    interior is polarity * bound, the bound `stream_bounds` proves.  The
+    downsample's 3x3 rows sum to 0 over each tap and its 1x1 rows are all
+    +1, so each of its outputs is 16 * sign(the 2x2 sum of the stream):
+    a sum of four values of up to 32,767 that an int16 sum would wrap."""
+    full = functools.partial(np.full, dtype=np.int64)
+    m = 127
+    t_stem = 9 * 4 * m + 40
+    c_ident = bound - t_stem - 9 * 16 * m
+    stem = FoldedConvLayer(
+        weights_pm1=np.full((16, 3, 3, 4), polarity, np.int8),
+        c=full(16, polarity * 40), padding="same", residual=full(16, m))
+    ident = FoldedConvLayer(
+        weights_pm1=np.ones((16, 3, 3, 16), np.int8),
+        c=full(16, polarity * c_ident), padding="same",
+        residual=full(16, m), shortcut="identity")
+    balanced = np.broadcast_to(np.resize(np.int8([1, -1]), 16),
+                               (16, 3, 3, 16)).copy()
+    down = FoldedConvLayer(weights_pm1=np.ones((16, 1, 1, 16), np.int8),
+                           c=full(16, 0), residual=full(16, 1))
+    last = FoldedConvLayer(
+        weights_pm1=balanced, c=full(16, 0), stride=2, padding="same",
+        residual=full(16, 1), shortcut="down", down=down)
+    return [stem, ident, last]
+
+
+@pytest.mark.parametrize("polarity", [1, -1], ids=["pos", "neg"])
+@pytest.mark.parametrize("bound, stored", [(32_767, "int16"),
+                                           (32_768, "int32")])
+def test_a_stream_at_its_bound_and_its_downsample_equal_the_oracle(
+        bound, stored, polarity):
+    convs = _edge_layers(bound, polarity)
+    assert fused_conv.stream_bounds(convs)[:2] == (bound, bound)
+    metas = fused_conv.conv_metas_for(convs, EDGE.side)
+    assert [str(m.stream_dtype) for m in metas] == [stored, stored, "int16"]
+    folded = convs + convnet.random_folded_cnn(EDGE, seed=5)[3:]
+    dep = deploy_cnn(EDGE, folded, min_bucket=8)
+    x = np.ones((8, EDGE.n_in), np.float32)  # every thermometer bit fires
+    want = _oracle(EDGE, folded, dep.pipeline().head, x)
+    np.testing.assert_array_equal(np.asarray(dep.run(x, InferenceSpec())),
+                                  want)

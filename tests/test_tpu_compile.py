@@ -11,10 +11,12 @@ benchmark's batch, must compile to int8 convolutions.  In the CIFAR-10
 program each unpooled conv layer's ±1 int8 map must come out of the
 conv's own output fusion, with no loop fusion that unpacks a bit-packed
 predicate into it, and each pooled layer's conv must still hand its pool
-the packed predicate.  In Bi-Real-18's program at the same batch every
-convolution must take int8 and give int32 sums, and every int32 map it
-writes must come out of a conv's output fusion, most with their sign
-beside them.  This catches
+the packed predicate, and no map of it is int16.  In Bi-Real-18's
+program at the same batch every convolution must take int8 and give
+int32 sums, and every stream map it writes must come out of a conv's
+output fusion, most with their sign beside them: int16 in the stem and
+stages 1-2, whose proven bound fits, int32 in stages 3-4, and no other
+instruction converts, copies or relays out a stream map.  This catches
 what interpret mode cannot — unsupported lowerings, unaligned slices,
 scoped-VMEM overruns, programs past the chip's memory — before any chip
 time is spent.
@@ -186,6 +188,7 @@ def test_cifar_unpooled_convs_write_their_maps_from_the_conv(one_chip):
 
     for out in UNPOOLED_MAPS + POOLED_PREDICATES:
         assert all(conv_fusions_making(out)), out
+    assert not any("s16[" in l for lines in comps.values() for l in lines)
 
 
 def _fusion_outputs(entry):
@@ -220,22 +223,31 @@ def test_bireal18_convs_are_int8_and_its_stream_leaves_the_conv_fusions(
                 assert m.group(1) == "s32", l
                 assert dtype[m.group(2)] == dtype[m.group(3)] == "s8", l
     assert convs == 17 + 3 + 1  # the 3x3 convs, the downsamples, the head
-    both, alone = 0, []
+    both, alone = [], []
     for kind, calls, types in _fusion_outputs(comps["ENTRY"]):
-        maps = [dims for t, dims in types
-                if t == "s32" and dims.count(",") == 3]
+        maps = [(t, dims) for t, dims in types
+                if t in ("s16", "s32") and dims.count(",") == 3]
         if not maps:
             continue
-        # no loop fusion adds, copies or relays out an int32 map
+        # no loop fusion adds, converts, copies or relays out a stream map
         assert kind == "kOutput" and any(
             " convolution(" in l for l in comps[calls]), (kind, types)
-        if ("s8", maps[0]) in types:
-            both += 1  # the stream and its sign, from the conv's fusion
+        if ("s8", maps[0][1]) in types:
+            both.append(maps[0])  # the stream and its sign, from the conv
         else:
             alone.append(maps[0])
-    # all but four convs write the stream and its int8 sign together; the
-    # three stride-2 convs write their sums for the downsample's fusion to
-    # add, and the last conv its stream for the global pool
-    assert both == 16
-    assert sorted(alone) == sorted(["1024,16,16,128", "1024,8,8,256",
-                                    "1024,4,4,512", "1024,4,4,512"])
+    # all but four convs write the stream and its int8 sign together, as
+    # int16 in the stem and stages 1-2 (bounds of at most 10,280 and
+    # 16,584) and int32 in stages 3-4 (over 32,767); the three stride-2 convs write
+    # their int32 sums for the downsample's fusion to add, and the last
+    # conv its int32 stream for the global pool
+    assert sorted(both) == sorted(
+        [("s16", "1024,32,32,64")] * 5 + [("s16", "1024,16,16,128")] * 4
+        + [("s32", "1024,8,8,256")] * 4 + [("s32", "1024,4,4,512")] * 3)
+    assert sorted(alone) == sorted(
+        ("s32", dims) for dims in ["1024,16,16,128", "1024,8,8,256",
+                                   "1024,4,4,512", "1024,4,4,512"])
+    # outside the fusions nothing converts, copies or relays out a map
+    for l in comps["ENTRY"]:
+        if re.search(r"s(16|32)\[1024,\d+,\d+,\d+\]", l):
+            assert " fusion(" in l or " get-tuple-element(" in l, l
