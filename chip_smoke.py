@@ -5,16 +5,18 @@
 
 Drives the main path through the entry points a user calls —
 `deploy_mlp` / `deploy_cnn` -> `Deployment.pipeline()` -> `run(x, spec)`
--> `PicBnnServer` — at the full widths of the repo's five deployments
-(`configs/paper_mlp.py`, `configs/paper_cnn.py`; Bi-Real-18 carries the
-int32 residual stream), with weights made from `--seed`.  Each phase
-prints one line and checks its results exactly:
+-> `PicBnnServer` — at the full widths of the repo's six deployments
+(`configs/paper_mlp.py`, `configs/paper_cnn.py`; Bi-Real-18 and
+ReActNet-A carry the residual stream), with weights made from
+`--seed`.  Each phase prints one line and checks its results exactly:
 
   * the fused Pallas kernel (MLPs) and the int8 MXU program (CNNs)
     against the float32 oracles, evaluated on
     the host CPU backend (`bnn.folded_forward_exact` +
     `ensemble.votes_fused` for the MLPs, `kernels.ref.conv_votes_ref`
-    for the CNNs), on 1, 64 and 257 rows;
+    for the CNNs), on 1, 64 and 257 rows (1 and 64 for ReActNet-A at
+    224x224, whose float32 oracle on the host holds 205 MB a stream
+    tensor at 64 rows);
   * batch-noise kernel votes against the last pass of the cumulative
     batch-noise spec (the XLA twin) given the same key;
   * every served result against a direct `run` of the same rows/keys.
@@ -37,6 +39,8 @@ from pathlib import Path
 import numpy as np
 
 ROWS = (1, 64, 257)  # one row, a full min bucket, a multi-block grid
+# rows of the deployments whose host oracle would not hold 257
+ORACLE_ROWS = {"reactnet_a_imagenet": (1, 64)}
 
 
 def _require_tpu():
@@ -52,12 +56,14 @@ def _require_tpu():
 
 
 def _deployments():
-    from repro.configs.paper_cnn import BIREAL18_CIFAR10, HG_CNN, MNIST_CNN
+    from repro.configs.paper_cnn import (BIREAL18_CIFAR10, HG_CNN,
+                                         MNIST_CNN, REACTNET_A_IMAGENET)
     from repro.configs.paper_mlp import HG_MLP, MNIST_MLP
 
     return (("mnist_mlp", MNIST_MLP), ("hg_mlp", HG_MLP),
             ("mnist_cnn", MNIST_CNN), ("hg_cnn", HG_CNN),
-            ("bireal18_cifar10", BIREAL18_CIFAR10))
+            ("bireal18_cifar10", BIREAL18_CIFAR10),
+            ("reactnet_a_imagenet", REACTNET_A_IMAGENET))
 
 
 def _folded(cfg, seed: int):
@@ -175,12 +181,13 @@ def pipelines_phase(seed: int) -> None:
         folded = _folded(cfg, seed)
         pipe = _deploy(cfg, folded).pipeline()
         noisy = _deploy(cfg, folded, noise=SILICON).pipeline()
-        x = _inputs(cfg, max(ROWS), seed + 1)
+        rows = ORACLE_ROWS.get(name, ROWS)
+        x = _inputs(cfg, max(rows), seed + 1)
         _assert_kernel(pipe, spec_off, x[:64])
         _assert_kernel(noisy, spec_batch, x[:64], key=key)
         want = _oracle(cfg, folded, pipe.head, x)
         compile_s, noise_s, n_exact, n_noise = {}, {}, 0, 0
-        for n in ROWS:
+        for n in rows:
             with _CompileClock() as clock:
                 got = jax.block_until_ready(pipe.run(x[:n], spec_off))
             compile_s[n] = clock.seconds

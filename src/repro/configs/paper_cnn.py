@@ -40,6 +40,21 @@ same as the paper MLPs.
   Every conv's BN is integer, a = s * m per channel plus C (DESIGN.md
   §10).
 
+  ReActNet-A on ImageNet (224x224x3, 1000 classes; Liu et al. 2020,
+  arXiv:2003.03488 Sec. 3.1-3.2, on MobileNetV1's layout, Howard et al.
+  2017, arXiv:1704.04861 Table 1):
+      thermometer-8 per RGB channel -> 3x3/2 x32 stem at 112^2, which
+      starts the int32 residual stream -> 13 blocks of a binary 3x3 conv
+      c -> c and a binary 1x1 conv c -> c', each with a shortcut of its
+      own, output widths 64, 128, 128, 256, 256, 512 x 6, 1024, 1024;
+      blocks 2, 4, 6 and 12 have stride 2 (112 -> 56 -> 28 -> 14 -> 7),
+      their 3x3's shortcut the 2x2 floor-average ("avg"); a 1x1 that
+      doubles the width is two c -> c convs concatenated, one c -> 2c
+      conv here, its shortcut the stream twice ("dup"); every conv but
+      the stem reads sign(x - alpha) (RSign) and applies RPReLU after
+      its add -> sign of the 7x7 sum-pool, 1024 bits -> CAM head (1000
+      rows)
+
 `build_cnn_pipeline` is the one-call deployment path used by the
 benchmarks, the serving registry, and the tests.
 """
@@ -112,6 +127,46 @@ BIREAL18_CIFAR10 = CNNConfig(
     conv=bireal_convs((64, 128, 256, 512), 4, 4),
     hidden=(),
     n_classes=10,
+    bias_cells=64,
+)
+
+
+def reactnet_convs(stem: int, blocks: Sequence[tuple[int, int]],
+                   global_pool: int) -> tuple[ConvSpec, ...]:
+    """ReActNet's binary MobileNet as a flat residual conv stack.
+
+    A 3x3/2 stem of `stem` channels starts the stream; each block
+    (c_out, stride) then has a 3x3 conv of the incoming width c (shortcut
+    "identity", or "avg" at stride 2) and a 1x1 conv to c_out (shortcut
+    "identity", or "dup" where c_out = 2c), both with RSign and RPReLU.
+    The last conv sum-pools its `global_pool` x `global_pool` map.
+    """
+    convs = [ConvSpec(3, stem, 2, "same", residual=True)]
+    c = stem
+    react = dict(residual=True, react=True)
+    for c_out, stride in blocks:
+        convs.append(ConvSpec(3, c, stride, "same",
+                              shortcut="avg" if stride == 2 else "identity",
+                              **react))
+        convs.append(ConvSpec(1, c_out, 1, "same",
+                              shortcut="dup" if c_out == 2 * c
+                              else "identity", **react))
+        c = c_out
+    convs[-1] = dataclasses.replace(convs[-1], pool=global_pool)
+    return tuple(convs)
+
+
+#: MobileNetV1's 13 blocks (output width, stride), as ReActNet-A keeps them
+MOBILENET_V1_BLOCKS = ((64, 1), (128, 2), (128, 1), (256, 2), (256, 1),
+                       (512, 2), *[(512, 1)] * 5, (1024, 2), (1024, 1))
+
+REACTNET_A_IMAGENET = CNNConfig(
+    side=224,
+    channels=3,
+    encoding=InputEncoding("thermometer", 8),
+    conv=reactnet_convs(32, MOBILENET_V1_BLOCKS, 7),
+    hidden=(),
+    n_classes=1000,
     bias_cells=64,
 )
 

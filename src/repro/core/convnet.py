@@ -35,10 +35,18 @@ writes an int32 residual stream x instead of a ±1 map,
 with s = ±1 the sign of the folded BN scale (in the rows, as above) and
 m >= 1 its integer magnitude (`FoldedConvLayer.residual`).  The
 shortcut is "none" (the layer starts the stream: a stem), "identity"
-(x itself) or "down" (Bi-Real's downsample, a * conv1x1(sign(
-sumpool_stride(x))) + C, `FoldedConvLayer.down`).  A residual layer's
-pool sums the stream (a global pool at the last layer), and whatever
-reads the stream, the next conv or the FC stage, reads its sign.
+(x itself), "down" (Bi-Real's downsample, a * conv1x1(sign(
+sumpool_stride(x))) + C, `FoldedConvLayer.down`), "avg" (ReActNet's
+AvgPool 2x2 of a stride-2 block, floor(sumpool_2x2(x) / 4)) or "dup"
+(ReActNet's reduction block, the stream concatenated with itself on
+channels, for a c -> 2c conv).  A residual layer's pool sums the stream
+(a global pool at the last layer), and whatever reads the stream, the
+next conv or the FC stage, reads its sign.
+
+ReActNet (Liu et al. 2020) moves the sign and the stream per channel
+with integer parameters: a residual layer's `alpha` makes it read
+sign(x - alpha) (RSign, one threshold per input channel), and its
+`rprelu` bends its output after the shortcut's add (RPReLU, `RPReLU`).
 Training (`cnn_forward`, `fold_cnn`) covers the plain chain only.
 """
 
@@ -59,8 +67,13 @@ Params = dict[str, Any]
 
 PADDINGS = ("valid", "same")
 #: what a residual layer adds onto its conv: nothing (it starts the
-#: stream), the stream itself, or Bi-Real's downsample of it
-SHORTCUTS = ("none", "identity", "down")
+#: stream), the stream itself, Bi-Real's downsample of it, its 2x2
+#: floor-average (a stride-2 layer) or the stream twice on channels (a
+#: layer of twice the width)
+SHORTCUTS = ("none", "identity", "down", "avg", "dup")
+#: the shortcuts that carry the incoming stream on: each keeps its
+#: values, so the stream goes on as one tensor
+CARRIED = ("identity", "avg", "dup")
 
 
 def conv_pads(side: int, k: int, stride: int, padding: str) -> tuple:
@@ -93,7 +106,9 @@ class ConvSpec:
     """One binary conv layer: k x k window, c_out filters, stride,
     "valid" or "same" zero padding, then a pool x pool / pool max-pool
     (pool 1: none).  A `residual` layer writes the int32 residual stream
-    (module doc) with its `shortcut` added, and its pool sums."""
+    (module doc) with its `shortcut` added, and its pool sums; a `react`
+    layer (ReActNet) reads the stream through RSign thresholds and
+    applies RPReLU after the add (`FoldedConvLayer.alpha`, `.rprelu`)."""
 
     k: int
     c_out: int
@@ -102,12 +117,15 @@ class ConvSpec:
     pool: int = 1
     residual: bool = False
     shortcut: str = "none"
+    react: bool = False
 
     def __post_init__(self):
         if (self.k < 1 or self.c_out < 1 or self.stride < 1
                 or self.pool < 1 or self.padding not in PADDINGS
                 or self.shortcut not in SHORTCUTS
-                or (self.shortcut != "none" and not self.residual)):
+                or (self.shortcut == "avg" and self.stride != 2)
+                or ((self.shortcut != "none" or self.react)
+                    and not self.residual)):
             raise ValueError(f"bad ConvSpec {self}")
 
     def conv_side(self, side: int) -> int:
@@ -175,6 +193,34 @@ class CNNConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RPReLU:
+    """ReActNet's RPReLU in integers, per output channel o:
+
+        f(y) = y - gamma_o + zeta_o                     y >= gamma_o
+               floor(q_o * (y - gamma_o) / 8) + zeta_o  otherwise
+
+    a PReLU of slope beta = q / 8 (q in 0..8) between two learnt shifts.
+    gamma, q, zeta: [c_out] integers.  With 0 <= beta <= 1, |f(y)| <=
+    |y| + |gamma| + |zeta|."""
+
+    gamma: np.ndarray
+    q: np.ndarray
+    zeta: np.ndarray
+
+    def __post_init__(self):
+        shapes = {np.shape(a) for a in (self.gamma, self.q, self.zeta)}
+        if len(shapes) != 1 or len(shapes.pop()) != 1:
+            raise ValueError("RPReLU's gamma, q, zeta must be [c_out] each")
+        if not ((0 <= np.asarray(self.q)) & (np.asarray(self.q) <= 8)).all():
+            raise ValueError("RPReLU's slope numerator q must be in 0..8")
+
+    @property
+    def c_out(self) -> int:
+        """Output channels it bends."""
+        return len(self.q)
+
+
+@dataclasses.dataclass(frozen=True)
 class FoldedConvLayer:
     """Deployment form of one binary conv layer (Eq. 3 per channel).
 
@@ -197,12 +243,20 @@ class FoldedConvLayer:
                   layer writes the residual stream: m * dot + C + the
                   shortcut, dot taken with the rows (which hold s), and
                   its pool sums the stream; no pool_sign.
-    shortcut    : "none", "identity" or "down" (`SHORTCUTS`; only
-                  "none" without `residual`)
+    shortcut    : `SHORTCUTS` (only "none" without `residual`):
+                  "none", "identity", "down", "avg" (stride 2, c_out =
+                  c_in: floor(sumpool_2x2(x) / 4)) or "dup" (c_out =
+                  2 * c_in: concat(x, x) on channels)
     down        : the "down" shortcut's 1 x 1 residual conv (c_in ->
                   c_out, stride 1, shortcut "none"), which reads
                   sign(sumpool(x)) over stride x stride windows of the
                   incoming stream x; None for every other shortcut
+    alpha       : [c_in] integer RSign thresholds of a residual layer
+                  that reads a stream (None: 0): its conv reads
+                  sign(x - alpha), +1 where x >= alpha
+    rprelu      : `RPReLU` of a residual layer (None: the identity),
+                  applied per channel after the shortcut's add and
+                  before the pool
     """
 
     weights_pm1: np.ndarray
@@ -214,6 +268,8 @@ class FoldedConvLayer:
     residual: np.ndarray | None = None
     shortcut: str = "none"
     down: "FoldedConvLayer | None" = None
+    alpha: np.ndarray | None = None
+    rprelu: RPReLU | None = None
 
     def __post_init__(self):
         if self.padding not in PADDINGS or self.pool < 1:
@@ -227,9 +283,21 @@ class FoldedConvLayer:
             raise ValueError(f"shortcut {self.shortcut!r} not in "
                              f"{SHORTCUTS}")
         if self.residual is None:
-            if self.shortcut != "none" or self.down is not None:
-                raise ValueError("a shortcut needs a residual layer")
+            if (self.shortcut != "none" or self.down is not None
+                    or self.alpha is not None or self.rprelu is not None):
+                raise ValueError("a shortcut, RSign or RPReLU needs a "
+                                 "residual layer")
             return
+        if self.alpha is not None and np.shape(self.alpha) != (self.c_in,):
+            raise ValueError("alpha must be [c_in] integers")
+        if self.rprelu is not None and self.rprelu.c_out != self.c_out:
+            raise ValueError("rprelu must be [c_out] per channel")
+        if self.shortcut == "avg" and (self.stride != 2
+                                       or self.c_out != self.c_in):
+            raise ValueError('shortcut "avg" needs stride 2 and c_out == '
+                             'c_in')
+        if self.shortcut == "dup" and self.c_out != 2 * self.c_in:
+            raise ValueError('shortcut "dup" needs c_out == 2 * c_in')
         if (np.shape(self.residual) != (self.c_out,)
                 or (np.asarray(self.residual) < 1).any()):
             raise ValueError("residual must be [c_out] integers >= 1")
@@ -573,9 +641,11 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
     helper: random ±1 filters/weights with valid dead-zone-free
     constants (and, for pooled layers, random ±1 pool polarities; for
     residual layers, magnitudes m in 1..4 and each downsample's 1 x 1
-    conv: all drawn after everything else so plain unpooled nets keep
-    their draws), for bit-exactness tests and throughput benchmarks that
-    don't need a trained model.
+    conv, then for `react` layers RSign thresholds and RPReLU shifts in
+    [-cmax, cmax] with q in 0..8: all drawn after everything else so
+    plain unpooled nets and earlier residual nets keep their draws), for
+    bit-exactness tests and throughput benchmarks that don't need a
+    trained model.
     """
     rng = np.random.default_rng(seed)
     folded: list = []
@@ -627,4 +697,12 @@ def random_folded_cnn(cfg: CNNConfig, seed: int = 0, cmax: int = 24) -> list:
         folded[i] = dataclasses.replace(
             folded[i], residual=rng.integers(1, 5, spec.c_out),
             shortcut=spec.shortcut, down=down)
+    for i, spec in enumerate(cfg.conv):
+        if spec.react:
+            folded[i] = dataclasses.replace(
+                folded[i], alpha=rng.integers(-cmax, cmax + 1, folded[i].c_in),
+                rprelu=RPReLU(
+                    gamma=rng.integers(-cmax, cmax + 1, spec.c_out),
+                    q=rng.integers(0, 9, spec.c_out),
+                    zeta=rng.integers(-cmax, cmax + 1, spec.c_out)))
     return folded

@@ -27,7 +27,10 @@ models straight from disk.
 On-disk layout::
 
     <dir>/deployment.json       declarative config (schema
-                                picbnn-deployment/v1): layer topology,
+                                picbnn-deployment/v1): layer topology
+                                with each conv layer's per-channel
+                                integers (pool signs, magnitudes, RSign
+                                alpha, RPReLU gamma/q/zeta),
                                 ensemble/noise/encoding/compile options
     <dir>/step_00000000/        ckpt.save output — manifest.json + one
                                 .npy per leaf: packed uint32 weight
@@ -212,6 +215,10 @@ class Deployment:
                     "down": None if d is None else {
                         "shape": list(d.weights_pm1.shape),
                         "residual": _ints(d.residual)},
+                    "alpha": _ints(layer.alpha),
+                    "rprelu": None if layer.rprelu is None else {
+                        k: _ints(getattr(layer.rprelu, k))
+                        for k in ("gamma", "q", "zeta")},
                 })
                 if d is not None:
                     leaves.update(down_w=_pack_rows(d.weights_pm1),
@@ -293,7 +300,7 @@ class Deployment:
             w = _unpack_rows(np.asarray(leaf["w"]), lm["shape"])
             c = np.asarray(leaf["c"], np.int64)
             if lm["kind"] == "conv":
-                dm = lm.get("down")
+                dm, rp = lm.get("down"), lm.get("rprelu")
                 folded.append(FoldedConvLayer(
                     weights_pm1=w, c=c, stride=int(lm["stride"]),
                     padding=lm.get("padding", "valid"),
@@ -306,6 +313,9 @@ class Deployment:
                             np.asarray(leaf["down_w"]), dm["shape"]),
                         c=np.asarray(leaf["down_c"], np.int64),
                         residual=_array(dm["residual"], np.int64)),
+                    alpha=_array(lm.get("alpha"), np.int64),
+                    rprelu=None if rp is None else convnet.RPReLU(
+                        **{k: _array(v, np.int64) for k, v in rp.items()}),
                 ))
             else:
                 folded.append(FoldedLayer(weights_pm1=w, c=c))

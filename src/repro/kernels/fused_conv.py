@@ -23,12 +23,17 @@ int32 sums:
                       predicate its pool reads at 1/8 of the bytes
     per residual
     conv layer:       the residual stream x (`stream_layer`):
-                      x' = conv(sign(x)) + C + the shortcut (x, or the
-                      downsample conv of sign(sumpool(x))), each conv's
-                      int8 filters scaled by its BN scale a = s * m; a
-                      pool sums x'; all of it in int32.  The next layer
-                      reads sign(x') (`sign_pm1`, taken from the int32
-                      value), and x' is stored at the layer's
+                      x' = conv(sign(x)) + C + the shortcut (x, the
+                      downsample conv of sign(sumpool(x)), the floor-
+                      average floor(sumpool_2x2(x) / 4), or
+                      concat(x, x) on channels), each conv's int8
+                      filters scaled by its BN scale a = s * m; then
+                      the layer's RPReLU, (q * (x' - gamma)) >> 3 below
+                      gamma, an exact floor; a pool sums x'; all of it
+                      in int32.  The next layer reads sign(x')
+                      (`sign_pm1`, taken from the int32 value), or with
+                      RSign thresholds alpha it reads sign(x' - alpha)
+                      itself, and x' is stored at the layer's
                       `ConvMeta.stream_dtype`: int16 where the bound
                       `stream_bounds` proves from the deployed operands
                       fits, else int32; every read widens it to int32
@@ -64,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import binarize
-from repro.core.convnet import conv_out_side, conv_pads
+from repro.core.convnet import CARRIED, conv_out_side, conv_pads
 from repro.kernels import fused_mlp
 
 DIMS = ("NHWC", "HWIO", "NHWC")
@@ -73,8 +78,10 @@ DIMS = ("NHWC", "HWIO", "NHWC")
 @dataclasses.dataclass(frozen=True)
 class ConvMeta:
     """Static shape info for one conv layer (square feature maps), and for
-    a residual layer the type its stream is stored at, proven from the
-    deployed operands by `conv_metas_for`."""
+    a residual layer its shortcut and the type its stream is stored at,
+    proven from the deployed operands by `conv_metas_for`.  A "dup"
+    layer's c_out is twice its c_in; RSign and RPReLU are operands
+    (`conv_operands`), not shape."""
 
     c_in: int  # input channels
     stride: int
@@ -93,10 +100,13 @@ class ConvMeta:
 def _term(layer) -> int:
     """The most one residual conv adds to a stream value: the maximum
     over output channels of sum|w * m| + |C|, k^2 * c_in * m_o + |c_o|
-    for ±1 filters (a pad adds 0)."""
-    m = np.asarray(layer.residual, np.int64)
-    return int(np.max(layer.n_bits * m + np.abs(np.asarray(layer.c,
-                                                           np.int64))))
+    for ±1 filters (a pad adds 0), plus |gamma_o| + |zeta_o| of an
+    RPReLU (with 0 <= beta <= 1 it moves a value by at most those)."""
+    t = (layer.n_bits * np.asarray(layer.residual, np.int64)
+         + np.abs(np.asarray(layer.c, np.int64)))
+    if layer.rprelu is not None:
+        t = t + np.abs(layer.rprelu.gamma) + np.abs(layer.rprelu.zeta)
+    return int(np.max(t))
 
 
 def stream_bounds(conv_layers: Sequence) -> tuple[int, ...]:
@@ -105,26 +115,30 @@ def stream_bounds(conv_layers: Sequence) -> tuple[int, ...]:
 
     A stream starts at shortcut "none" or "down" (which reads only signs
     of the old stream) with the layer's term, plus the downsample conv's
-    term for "down"; each "identity" layer adds its term, and a pool of
-    p x p sums multiplies the bound by p^2.  A stream is one tensor from
-    start to restart, stored at one type, so every layer of it takes the
-    largest bound it reaches.  Each term is one maximum over its layer's
-    output channels, not a per-channel chain, so the bound, and the
-    program compiled from it, is the same for every draw of a
-    configuration whose extremes of m and C are drawn.
+    term for "down"; each layer whose shortcut carries the stream on
+    (`convnet.CARRIED`: "identity", "avg", whose floor-average of four
+    values stays within their bound, and "dup", which copies them) adds
+    its term, and a pool of p x p sums multiplies the bound by p^2.  An
+    RSign threshold moves only signs, not the stream.  A stream is one
+    tensor from start to restart, stored at one type, so every layer of
+    it takes the largest bound it reaches.  Each term is one maximum
+    over its layer's output channels, not a per-channel chain, so the
+    bound, and the program compiled from it, is the same for every draw
+    of a configuration whose extremes of m, C and RPReLU's shifts are
+    drawn.
     """
     bounds, b = [], 0
     for layer in conv_layers:
         if layer.residual is None:
             b = 0
-        elif layer.shortcut == "identity":
+        elif layer.shortcut in CARRIED:
             b = (b + _term(layer)) * layer.pool ** 2
         else:
             down = 0 if layer.down is None else _term(layer.down)
             b = (_term(layer) + down) * layer.pool ** 2
         bounds.append(b)
     for i in reversed(range(len(bounds) - 1)):
-        if conv_layers[i + 1].shortcut == "identity":
+        if conv_layers[i + 1].shortcut in CARRIED:
             bounds[i] = max(bounds[i], bounds[i + 1])
     return tuple(bounds)
 
@@ -133,7 +147,9 @@ def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
     """Static ConvMeta chain for a conv stack on `side` x `side` input.
 
     A shortcut must find a stream of its layer's output shape: "identity"
-    the stream itself, "down" its stride x stride sum-pool.
+    the stream itself, "down" its stride x stride sum-pool, "avg" its
+    2 x 2 floor-average (the side even), "dup" the stream twice on
+    channels.  A layer with RSign thresholds must read a stream.
     """
     metas = []
     s = side
@@ -147,12 +163,19 @@ def conv_metas_for(conv_layers: Sequence, side: int) -> tuple[ConvMeta, ...]:
                                       layer.padding, layer.pool)
         except ValueError as e:
             raise ValueError(f"feature side {s}: {e} (layer {i})") from None
+        if layer.alpha is not None and not (metas and metas[-1].residual):
+            raise ValueError(f"conv layer {i}'s RSign thresholds have no "
+                             "residual stream to read")
         if layer.shortcut != "none":
             if not (metas and metas[-1].residual):
                 raise ValueError(f"conv layer {i}'s {layer.shortcut} "
                                  "shortcut has no residual stream to read")
             if layer.shortcut == "identity":
                 fits = (s, layer.c_in) == (conv, layer.c_out)
+            elif layer.shortcut == "avg":  # c_out == c_in, stride 2
+                fits = s % 2 == 0 and s // 2 == conv
+            elif layer.shortcut == "dup":  # c_out == 2 * c_in
+                fits = s == conv
             else:  # the down conv maps c_in to c_out
                 fits = s // layer.stride == conv
             if not fits:
@@ -198,7 +221,9 @@ def conv_operands(layer) -> tuple:
     """(HWIO ±1 int8 filters [k, k, c_in, c_out], C int32 [c_out],
     pool sign int8 [c_out]) of one plain `FoldedConvLayer`; of a residual
     one (HWIO int8 filters scaled by its magnitudes m, so a = s * m per
-    channel, C int32 [c_out], the downsample's (filters, C) or ())."""
+    channel, C int32 [c_out], the downsample's (filters, C) or (), RSign
+    alpha int32 [c_in] or None, RPReLU's (gamma, q, zeta) int32 [c_out]
+    each or None)."""
     w = np.transpose(pm1_int8(layer.weights_pm1), (1, 2, 3, 0))
     c = jnp.asarray(layer.c, jnp.int32)
     if layer.residual is None:
@@ -208,7 +233,12 @@ def conv_operands(layer) -> tuple:
     if m.max() > 127:
         raise ValueError("residual magnitudes above 127 leave int8")
     down = () if layer.down is None else conv_operands(layer.down)[:2]
-    return jnp.asarray((w * m).astype(np.int8)), c, down
+    alpha = (None if layer.alpha is None
+             else jnp.asarray(layer.alpha, jnp.int32))
+    rp = layer.rprelu
+    rprelu = None if rp is None else tuple(
+        jnp.asarray(a, jnp.int32) for a in (rp.gamma, rp.q, rp.zeta))
+    return jnp.asarray((w * m).astype(np.int8)), c, down, alpha, rprelu
 
 
 def fc_operands(layer) -> tuple:
@@ -278,16 +308,24 @@ def _sum_pool(x, p: int):
                                  jax.lax.add, win, win, "VALID")
 
 
-def stream_layer(h, x, w, c, down, m: ConvMeta):
+def stream_layer(h, x, w, c, down, alpha, rprelu, m: ConvMeta):
     """One residual conv layer: ±1 int8 maps h and the stored stream x
     (None before the first) -> the stream after it in int32, which the
-    caller signs and stores at `m.stream_dtype`."""
+    caller signs and stores at `m.stream_dtype`.  With RSign thresholds
+    `alpha` the layer reads sign(x - alpha) in place of h."""
+    if alpha is not None:
+        h = sign_pm1(x.astype(jnp.int32) - alpha)
     y = jax.lax.conv_general_dilated(
         h, w, (m.stride, m.stride), (m.pads, m.pads),
         dimension_numbers=DIMS, preferred_element_type=jnp.int32,
     ) + c
     if m.shortcut == "identity":
         y = y + x.astype(jnp.int32)
+    elif m.shortcut == "avg":  # an arithmetic shift: the exact floor
+        y = y + (_sum_pool(x, 2) >> 2)
+    elif m.shortcut == "dup":
+        x = x.astype(jnp.int32)
+        y = y + jnp.concatenate([x, x], axis=-1)
     elif m.shortcut == "down":
         wd, cd = down
         xd = sign_pm1(_sum_pool(x, m.stride))
@@ -295,6 +333,10 @@ def stream_layer(h, x, w, c, down, m: ConvMeta):
             xd, wd, (1, 1), "VALID", dimension_numbers=DIMS,
             preferred_element_type=jnp.int32,
         ) + cd
+    if rprelu is not None:
+        gamma, q, zeta = rprelu
+        d = y - gamma
+        y = jnp.where(d >= 0, d, (q * d) >> 3) + zeta  # >> 3: exact floor
     return y if m.pool == 1 else _sum_pool(y, m.pool)
 
 

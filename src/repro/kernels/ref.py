@@ -82,22 +82,36 @@ def stream_layer_ref(h, x, layer) -> jax.Array:
     """One residual `FoldedConvLayer` in float32: the stream after it.
 
     m * conv(h) + C, with m the layer's BN magnitudes and the rows (which
-    hold the sign s) as given, plus the shortcut: the stream x itself, or
-    the downsample conv of sign(sumpool(x)) over stride x stride
-    windows; a pool sums the result.  Every value is an integer far
-    below 2^24, so float32 holds it exactly.
+    hold the sign s) as given, h = sign(x - alpha) for a layer with RSign
+    thresholds, plus the shortcut: the stream x itself, the downsample
+    conv of sign(sumpool(x)) over stride x stride windows, the floor of
+    the 2 x 2 average of x, or x twice on channels; then RPReLU, y -
+    gamma + zeta at or above gamma and floor(q / 8 * (y - gamma)) + zeta
+    below it; a pool sums the result.  Every value is an integer far
+    below 2^24, so float32 holds it, each quarter and eighth, exactly.
     """
     def scaled(h, l, stride, padding):
         y = binary_conv2d_ref(h, l.weights_pm1, stride, padding)
         return (y * jnp.asarray(l.residual, jnp.float32)
                 + jnp.asarray(l.c, jnp.float32))
 
+    if layer.alpha is not None:
+        h = jnp.where(x >= jnp.asarray(layer.alpha, jnp.float32), 1.0, -1.0)
     y = scaled(h, layer, layer.stride, layer.padding)
     if layer.shortcut == "identity":
         y = y + x
+    elif layer.shortcut == "avg":
+        y = y + jnp.floor(_sum_pool_ref(x, 2) / 4)
+    elif layer.shortcut == "dup":
+        y = y + jnp.concatenate([x, x], axis=-1)
     elif layer.shortcut == "down":
         xd = jnp.where(_sum_pool_ref(x, layer.stride) >= 0, 1.0, -1.0)
         y = y + scaled(xd, layer.down, 1, "valid")
+    if layer.rprelu is not None:
+        gamma, q, zeta = (jnp.asarray(a, jnp.float32) for a in (
+            layer.rprelu.gamma, layer.rprelu.q, layer.rprelu.zeta))
+        d = y - gamma
+        y = jnp.where(d >= 0, d, jnp.floor(q / 8 * d)) + zeta
     return y if layer.pool == 1 else _sum_pool_ref(y, layer.pool)
 
 
@@ -113,7 +127,8 @@ def conv_votes_ref(folded, head, x01, encoding, side: int,
     FC hidden layers as sign(Wx + C), and vote the head with
     `ensemble.votes_fused`.  Bit-exactness of the deployed path against
     this oracle is asserted in tests/test_conv.py,
-    tests/test_conv_cifar.py and tests/test_conv_resnet.py.
+    tests/test_conv_cifar.py, tests/test_conv_resnet.py and
+    tests/test_conv_reactnet.py.
     """
     from repro.core.convnet import FoldedConvLayer
     from repro.core.ensemble import votes_fused

@@ -60,7 +60,8 @@ Convolutional graphs: `folded` may start with a prefix of
 `convnet.FoldedConvLayer` (a deployed end-to-end-binary CNN, e.g.
 `convnet.fold_cnn` output; plain layers, or residual layers that carry
 an int32 stream with identity and downsample shortcuts, as Bi-Real
-Net's binary ResNet).  The pipeline then takes RAW [0,1] pixels
+Net's binary ResNet, or with RSign, RPReLU and the floor-average and
+duplicating shortcuts, as ReActNet).  The pipeline then takes RAW [0,1] pixels
 [B, side*side*channels] (HWC), and each vote program takes them as they
 are staged: it runs the binary input layer (`image_encoding`,
 thermometer by default) to ±1 int8 maps itself, then the whole net,
@@ -106,7 +107,10 @@ against, to `kernel.stream_bytes` (0 without residual layers,
 for that stream over the bucket's rows, at each layer's own width
 (int16 or int32, `ConvMeta.stream_dtype`), to
 `kernel.stream_stored_bytes` (0 without residual layers,
-`fused_conv.stream_stored_bytes_per_row`).  The programs carry fixed
+`fused_conv.stream_stored_bytes_per_row`).  Both count every layer that
+writes the stream, each at its conv output's shape: a stem, every
+residual conv (both sub-blocks of a ReActNet block), a "dup" layer at
+its full doubled width.  The programs carry fixed
 names:
 `picbnn_pack`, and one `InferenceSpec.program_name` per spec
 (`picbnn_votes_off`, ...), so a device trace names them `jit_picbnn_*`.

@@ -5,7 +5,10 @@ server) must give the unpacked oracle's votes exactly
 (`kernels.ref.stream_layer_ref`: m * conv + C + shortcut in float32, the
 next layer reading the stream's sign) on reduced Bi-Real ResNets with
 identity and downsample shortcuts, stride 2 and a global sum-pool, and
-on a net that leaves the stream for a plain pooled conv and an FC layer;
+on a net that leaves the stream for a plain pooled conv and an FC layer,
+and on a reduced ReActNet (RSign, RPReLU, "avg" and "dup" shortcuts;
+tests/test_conv_reactnet.py holds the rest of its cases); every residual
+field, those of ReActNet among them, moves the votes alike;
 a saved deployment keeps every residual field; a stack whose shortcut
 finds no stream of its shape is refused; and each vote dispatch counts
 the stream its program writes.
@@ -26,7 +29,8 @@ import pytest
 
 from repro import obs
 from repro.configs.paper_cnn import (BIREAL18_CIFAR10, CIFAR10_CONVNET,
-                                     bireal_convs, deploy_cnn)
+                                     bireal_convs, deploy_cnn,
+                                     reactnet_convs)
 from repro.core import convnet
 from repro.core.binarize import InputEncoding
 from repro.core.convnet import CNNConfig, ConvSpec, FoldedConvLayer
@@ -51,7 +55,17 @@ NETS = {
               ConvSpec(3, 32, 2, "same", residual=True, shortcut="down"),
               ConvSpec(3, 32, 1, "same", 2)),
         hidden=(24,), n_classes=5),
+    # ReActNet-A's pattern cut to 16x16x3 and 8/16/16/32 channels: a
+    # stride-2 stem, a block whose 1x1 doubles the width ("dup"), one of
+    # identities, and a stride-2 block ("avg") whose 1x1 doubles it again
+    "reactnet-16": CNNConfig(
+        side=16, channels=3, encoding=ENC,
+        conv=reactnet_convs(8, ((16, 1), (16, 1), (32, 2)), 4), hidden=(),
+        n_classes=10),
 }
+SHORTCUTS = {"bireal-16": {"none", "identity", "down"},
+             "mixed-8": {"none", "identity", "down"},
+             "reactnet-16": {"none", "identity", "avg", "dup"}}
 
 
 def _images(cfg, n, seed):
@@ -75,8 +89,8 @@ def _deployment(name, seed, **kw):
 def test_pipeline_votes_equal_oracle(name, seed):
     cfg, folded, dep = _deployment(name, seed)
     convs = folded[:len(cfg.conv)]
-    assert {l.shortcut for l in convs if l.residual is not None} == {
-        "none", "identity", "down"}
+    assert {l.shortcut for l in convs
+            if l.residual is not None} == SHORTCUTS[name]
     x = _images(cfg, 13, seed=seed % 97)
     want = _oracle(cfg, folded, dep.pipeline().head, x)
     got = np.asarray(dep.run(x, InferenceSpec()))
@@ -84,26 +98,63 @@ def test_pipeline_votes_equal_oracle(name, seed):
     assert len(np.unique(got)) > 2  # the comparison has something to miss
 
 
-def test_every_residual_field_moves_the_votes():
-    """Raise half of a layer's magnitudes, or negate a downsample's
-    filters: the oracle and the deployed path change alike."""
-    cfg, folded, dep = _deployment("bireal-16", 4)
+def _first(shortcut):
+    return lambda folded: next(j for j, l in enumerate(folded)
+                               if getattr(l, "shortcut", None) == shortcut)
+
+
+def _rprelu(layer, **kw):
+    return dataclasses.replace(
+        layer, rprelu=dataclasses.replace(layer.rprelu, **kw))
+
+
+# (net, the layer changed, the change): each must move the votes
+FIELD_CHANGES = {
+    # half of a downsample layer's magnitudes raised to 4
+    "magnitudes": ("bireal-16", _first("down"), lambda l: dataclasses.replace(
+        l, residual=np.where(np.arange(l.c_out) < l.c_out // 2, 4,
+                             l.residual))),
+    # a downsample's filters negated
+    "down-filters": ("bireal-16", _first("down"),
+                     lambda l: dataclasses.replace(l, down=dataclasses.replace(
+                         l.down, weights_pm1=-l.down.weights_pm1))),
+    # RSign thresholds, RPReLU's shifts and slope of the "dup" 1x1
+    "alpha": ("reactnet-16", _first("dup"),
+              lambda l: dataclasses.replace(l, alpha=l.alpha + 60)),
+    "gamma": ("reactnet-16", _first("dup"),
+              lambda l: _rprelu(l, gamma=l.rprelu.gamma + 60)),
+    "q": ("reactnet-16", _first("dup"),
+          lambda l: _rprelu(l, q=8 - l.rprelu.q)),
+    "zeta": ("reactnet-16", _first("dup"),
+             lambda l: _rprelu(l, zeta=l.rprelu.zeta - 60)),
+    # the shortcuts "avg" and "dup" dropped: the layer restarts the stream
+    "avg": ("reactnet-16", _first("avg"),
+            lambda l: dataclasses.replace(l, shortcut="none")),
+    "dup": ("reactnet-16", _first("dup"),
+            lambda l: dataclasses.replace(l, shortcut="none")),
+    # a 1x1 residual conv on the main path, its filters negated
+    "k1-filters": ("reactnet-16", _first("dup"),
+                   lambda l: dataclasses.replace(
+                       l, weights_pm1=-l.weights_pm1)),
+}
+
+
+@pytest.mark.parametrize("change", sorted(FIELD_CHANGES))
+def test_every_residual_field_moves_the_votes(change):
+    """Change one field of one layer: the oracle and the deployed path
+    change alike."""
+    name, pick, alter = FIELD_CHANGES[change]
+    cfg, folded, dep = _deployment(name, 4)
     x = _images(cfg, 24, seed=5)
     base = np.asarray(dep.run(x, InferenceSpec()))
-    i = next(j for j, l in enumerate(folded) if l.shortcut == "down")
-    m = folded[i].residual.copy()
-    m[: len(m) // 2] = 4
-    down = dataclasses.replace(folded[i].down,
-                               weights_pm1=-folded[i].down.weights_pm1)
-    for layer in (dataclasses.replace(folded[i], residual=m),
-                  dataclasses.replace(folded[i], down=down)):
-        changed = list(folded)
-        changed[i] = layer
-        d = deploy_cnn(cfg, changed, min_bucket=8)
-        got = np.asarray(d.run(x, InferenceSpec()))
-        np.testing.assert_array_equal(
-            got, _oracle(cfg, changed, d.pipeline().head, x))
-        assert (got != base).any()
+    i = pick(folded)
+    changed = list(folded)
+    changed[i] = alter(folded[i])
+    d = deploy_cnn(cfg, changed, min_bucket=8)
+    got = np.asarray(d.run(x, InferenceSpec()))
+    np.testing.assert_array_equal(
+        got, _oracle(cfg, changed, d.pipeline().head, x))
+    assert (got != base).any()
 
 
 def test_deployment_round_trip_keeps_the_residual_fields(tmp_path):

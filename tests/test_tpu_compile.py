@@ -16,7 +16,14 @@ program at the same batch every convolution must take int8 and give
 int32 sums, and every stream map it writes must come out of a conv's
 output fusion, most with their sign beside them: int16 in the stem and
 stages 1-2, whose proven bound fits, int32 in stages 3-4, and no other
-instruction converts, copies or relays out a stream map.  This catches
+instruction converts, copies or relays out a stream map.  In
+ReActNet-A's program at the benchmark's batch of 256 every convolution
+takes int8 and gives int32 sums, every stream map is int32, and the
+fusions land as the compile shows them: RSign, RPReLU and a "dup"
+shortcut in the conv fusion that writes the stream, an "avg" shortcut
+with its RPReLU in a fusion of its own after the stride-2 conv's sums,
+and three loop fusions that re-read a stream map to make a sign map
+(PERF.md §5).  This catches
 what interpret mode cannot — unsupported lowerings, unaligned slices,
 scoped-VMEM overruns, programs past the chip's memory — before any chip
 time is spent.
@@ -35,7 +42,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.paper_cnn import (BIREAL18_CIFAR10, CIFAR10_CONVNET,
-                                     HG_CNN, MNIST_CNN, deploy_cnn)
+                                     HG_CNN, MNIST_CNN, REACTNET_A_IMAGENET,
+                                     deploy_cnn)
 from repro.configs.paper_mlp import HG_MLP, MNIST_MLP
 from repro.core import binarize, convnet
 from repro.core.convnet import CNNConfig
@@ -250,4 +258,86 @@ def test_bireal18_convs_are_int8_and_its_stream_leaves_the_conv_fusions(
     # outside the fusions nothing converts, copies or relays out a map
     for l in comps["ENTRY"]:
         if re.search(r"s(16|32)\[1024,\d+,\d+,\d+\]", l):
+            assert " fusion(" in l or " get-tuple-element(" in l, l
+
+
+def _calls_op(comps, calls, op):
+    """Whether computation `calls`, or one it calls, holds an `op`."""
+    todo, seen = [calls], set()
+    while todo:
+        name = todo.pop()
+        seen.add(name)
+        for l in comps[name]:
+            if f" {op}(" in l:
+                return True
+            todo += [c for c in re.findall(r"calls=%([\w.\-]+)", l)
+                     if c not in seen]
+    return False
+
+
+def test_reactnet_a_fusions_as_the_compile_lands_them(one_chip):
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    prog, args, ops = _conv_program(REACTNET_A_IMAGENET, shape,
+                                    noisy=False, batch=BATCH)
+    comps = _computations(prog.lower(*args, ops=ops).compile().as_text())
+    convs = 0
+    for lines in comps.values():
+        dtype = dict(m.groups() for m in map(DEFINES.match, lines) if m)
+        for l in lines:
+            m = re.search(r"= (\w+)\[.* convolution\(%(\S+), %(\S+)\)", l)
+            if m:
+                convs += 1
+                assert m.group(1) == "s32", l
+                assert dtype[m.group(2)] == dtype[m.group(3)] == "s8", l
+    assert convs == 27 + 1  # the stem and 26 sub-blocks, the head
+    assert not any("s16[" in l for lines in comps.values() for l in lines)
+    both, sums, avg, packed, unpack = [], [], [], [], []
+    for kind, calls, types in _fusion_outputs(comps["ENTRY"]):
+        maps = [(t, d) for t, d in types if d.count(",") >= 2]
+        if not maps or maps == [("s8", "256,1,1,1024")]:
+            continue  # the global pool's sign
+        conv = _calls_op(comps, calls, "convolution")
+        if kind == "kLoop":
+            unpack.append(maps[0])
+        elif not conv:
+            assert _calls_op(comps, calls, "reduce-window"), types
+            avg.append(maps)
+        elif len(maps) == 1:
+            sums.append(maps[0])
+        elif maps[0][0] == "s8":
+            # the stream and the next layer's sign(x - alpha) together;
+            # every conv after the stem (the first) selects RPReLU's
+            # branch here
+            assert maps[1][0] == "s32", types
+            assert _calls_op(comps, calls, "select") == bool(both), types
+            both.append(maps[1][1])
+        else:
+            packed.append(maps)
+    n = "256,"
+    # 20 conv fusions write the int32 stream with its int8 sign map: the
+    # stem, all six of the 112^2 and 56^2 ones but the stride-2 conv's,
+    # and the "dup" 1x1s, whose concatenated shortcut lands in them
+    assert sorted(both) == sorted(n + d for d in (
+        ["112,112,32"] * 2 + ["112,112,64"] + ["56,56,128"] * 3
+        + ["28,28,256"] * 2 + ["14,14,512"] * 10 + ["7,7,1024"] * 2))
+    # each stride-2 3x3 writes its sums alone, and a fusion of its own
+    # adds the 2x2 floor-average of the stream it re-reads, with RPReLU
+    stride2 = ["56,56,64", "28,28,128", "14,14,256", "7,7,512"]
+    assert sorted(sums) == sorted(("s32", n + d) for d in stride2)
+    assert sorted(avg) == sorted([("s8", n + d), ("s32", n + d)]
+                                 for d in stride2)
+    # the 1x1s before a stride-2 block and before the global pool write
+    # RPReLU's predicate bit-packed beside the stream before it, and a
+    # loop fusion re-reads both to make the next sign map
+    assert packed == [[("u32", n + "28,256"), ("s32", n + "28,28,256")],
+                      [("u16", n + "14,512"), ("s32", n + "14,14,512")],
+                      [("u8", n + "7,1024"), ("s32", n + "7,7,1024")]]
+    assert unpack == [("s8", n + "28,28,256"), ("s8", n + "14,14,512"),
+                      ("pred", n + "7,7,1024")]
+    # outside the fusions nothing concatenates, converts, copies or
+    # relays out a map
+    for l in comps["ENTRY"]:
+        if re.search(r"s32\[256,\d+,\d+,\d+\]", l):
             assert " fusion(" in l or " get-tuple-element(" in l, l
